@@ -3,7 +3,7 @@
 Usage:
     python benchmarks/check_hygiene.py
 
-Three classes of generated files must never be committed:
+These classes of generated files must never be committed:
 
 * compiled Python bytecode (``*.pyc`` / ``__pycache__`` directories);
 * benchmark outputs under ``artifacts/`` (``BENCH_*.json`` land there on
@@ -11,7 +11,10 @@ Three classes of generated files must never be committed:
   which this gate deliberately does not match);
 * Chrome-tracing timelines (``*.trace.json`` anywhere — serve runs emit
   them next to the bench JSON and they are upload-artifact material, not
-  repo material).
+  repo material);
+* the JAX persistent compilation cache (``.jax_cache/``, written by the
+  entry points through ``repro.compile_cache``) and hypothesis's example
+  database (``.hypothesis/``, written by the property tests).
 
 Violations print one ``::error file=...`` annotation per path so the CI
 run summary links straight to the offending file.
@@ -41,6 +44,10 @@ RULES: tuple[tuple[str, re.Pattern], ...] = (
      re.compile(r"^artifacts/.*\.json$")),
     ("Chrome-tracing timeline",
      re.compile(r"\.trace\.json$")),
+    ("JAX compilation cache",
+     re.compile(r"^\.jax_cache/")),
+    ("hypothesis example database",
+     re.compile(r"^\.hypothesis/")),
 )
 
 #: Every artifact class RULES polices must also be git-ignored, so the
@@ -52,6 +59,8 @@ REQUIRED_IGNORES: tuple[str, ...] = (
     "artifacts/BENCH_*.json",
     "artifacts/STATIC_*.json",
     "*.trace.json",
+    ".jax_cache/",
+    ".hypothesis/",
 )
 
 

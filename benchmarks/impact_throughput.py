@@ -565,5 +565,7 @@ if __name__ == "__main__":
     ap.add_argument("--json-dir", default=None,
                     help="where BENCH_*.json land (default: artifacts/)")
     args = ap.parse_args()
+    from repro.compile_cache import use_compilation_cache
+    use_compilation_cache()
     print("name,us_per_call,derived")
     main(quick=args.quick, json_dir=args.json_dir)

@@ -18,8 +18,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from . import _compat
-
 Array = jax.Array
 
 BLOCK_B = 128
@@ -68,7 +66,7 @@ def class_sum(clauses: Array, weights: Array, *, block_b: int = BLOCK_B,
         out_specs=pl.BlockSpec((block_b, block_m), lambda b, m, n: (b, m)),
         out_shape=jax.ShapeDtypeStruct((B, M), jnp.int32),
         scratch_shapes=[pltpu.VMEM((block_b, block_m), jnp.int32)],
-        compiler_params=_compat.CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
     )(clauses, weights)

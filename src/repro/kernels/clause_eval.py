@@ -26,8 +26,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from . import _compat
-
 Array = jax.Array
 
 # MXU-aligned default tiling: int8 min tile on TPU is (32, 128); we use
@@ -95,7 +93,7 @@ def clause_eval(literals: Array, include: Array, nonempty: Array, *,
         out_specs=pl.BlockSpec((block_b, block_n), lambda b, n, k: (b, n)),
         out_shape=jax.ShapeDtypeStruct((B, N), out_dtype),
         scratch_shapes=[pltpu.VMEM((block_b, block_n), jnp.int32)],
-        compiler_params=_compat.CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
     )(literals, include, nonempty)

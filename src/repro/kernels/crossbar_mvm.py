@@ -18,8 +18,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from . import _compat
-
 Array = jax.Array
 
 BLOCK_B = 128
@@ -37,9 +35,12 @@ def _mvm_kernel(drive_ref, g_ref, out_ref, acc_ref, *, n_k: int,
 
     g = g_ref[...]
     i_cell = g * v_read * jnp.where(g < cutoff, nonlin, 1.0)
+    # HIGHEST: Mosaic's default contracts f32 in bf16 passes (~1e-3
+    # relative on the cell currents); the analog sums need f32.
     acc_ref[...] += jax.lax.dot_general(
         drive_ref[...], i_cell,
         (((1,), (0,)), ((), ())),
+        precision=jax.lax.Precision.HIGHEST,
         preferred_element_type=jnp.float32,
     )
 
@@ -77,7 +78,7 @@ def crossbar_mvm(drive: Array, g: Array, *, v_read: float = 2.0,
         out_specs=pl.BlockSpec((block_b, block_n), lambda b, n, k: (b, n)),
         out_shape=jax.ShapeDtypeStruct((B, N), jnp.float32),
         scratch_shapes=[pltpu.VMEM((block_b, block_n), jnp.float32)],
-        compiler_params=_compat.CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
     )(drive, g)

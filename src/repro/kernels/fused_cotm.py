@@ -29,8 +29,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from . import _compat
-
 Array = jax.Array
 
 BLOCK_B = 128
@@ -94,7 +92,7 @@ def fused_cotm(literals: Array, include: Array, nonempty: Array,
         out_specs=pl.BlockSpec((block_b, M), lambda b, n: (b, 0)),
         out_shape=jax.ShapeDtypeStruct((B, M), jnp.int32),
         scratch_shapes=[pltpu.VMEM((block_b, M), jnp.int32)],
-        compiler_params=_compat.CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary")),
         interpret=interpret,
     )(literals, include, nonempty, weights)

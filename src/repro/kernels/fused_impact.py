@@ -47,12 +47,22 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from . import _compat
-
 Array = jax.Array
 
 BLOCK_B = 128
 BLOCK_N = 256
+
+
+def _dot_f32(a, b):
+    """``a @ b`` on the MXU with f32 contraction.  Mosaic's default
+    contracts f32 operands in reduced-precision (bf16) passes, which on
+    a TPU moves the cell currents -- and so the class currents, the CSA
+    margin and the energy meters -- by ~1e-3 relative; HIGHEST keeps the
+    digital twin at the f32 precision its parity tolerances assume."""
+    return jax.lax.dot_general(
+        a, b, (((1,), (0,)), ((), ())),
+        precision=jax.lax.Precision.HIGHEST,
+        preferred_element_type=jnp.float32)
 
 
 def _fused_impact_kernel(drive_ref, ccur_ref, ne_ref, wcur_ref, out_ref,
@@ -67,17 +77,9 @@ def _fused_impact_kernel(drive_ref, ccur_ref, ne_ref, wcur_ref, out_ref,
     bn = ne_ref.shape[1]
     fired = jnp.broadcast_to(ne_ref[...] != 0, (bb, bn))
     for r in range(n_r):                       # static unroll over row shards
-        i_col = jax.lax.dot_general(
-            drive_ref[r], ccur_ref[r],
-            (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32,
-        )
+        i_col = _dot_f32(drive_ref[r], ccur_ref[r])
         fired = fired & (i_col < thresh)       # CSA + digital AND, in VMEM
-    acc_ref[...] += jax.lax.dot_general(
-        fired.astype(jnp.float32), wcur_ref[...],
-        (((1,), (0,)), ((), ())),
-        preferred_element_type=jnp.float32,
-    )
+    acc_ref[...] += _dot_f32(fired.astype(jnp.float32), wcur_ref[...])
 
     @pl.when(n == n_n - 1)
     def _epilogue():
@@ -116,7 +118,7 @@ def fused_impact(drive: Array, ccur: Array, nonempty: Array, wcur: Array, *,
         out_specs=pl.BlockSpec((block_b, M), lambda b, n: (b, 0)),
         out_shape=jax.ShapeDtypeStruct((B, M), jnp.float32),
         scratch_shapes=[pltpu.VMEM((block_b, M), jnp.float32)],
-        compiler_params=_compat.CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary")),
         interpret=interpret,
     )(drive, ccur, nonempty, wcur)
@@ -155,11 +157,7 @@ def _fused_impact_metered_kernel(drive_ref, ccur_ref, ne_ref, wcur_ref,
     fired = jnp.broadcast_to(ne_ref[...] != 0, (bb, bn))
     i_chunk = jnp.zeros((bb, 1), jnp.float32)
     for r in range(n_r):                       # static unroll over row shards
-        i_col = jax.lax.dot_general(
-            drive_ref[r], ccur_ref[r],
-            (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32,
-        )
+        i_col = _dot_f32(drive_ref[r], ccur_ref[r])
         fired = fired & (i_col < thresh)       # CSA + digital AND, in VMEM
         i_chunk += i_col.sum(axis=1, keepdims=True)
     # Every meter lane accumulates the same per-lane clause current (a
@@ -167,11 +165,7 @@ def _fused_impact_metered_kernel(drive_ref, ccur_ref, ne_ref, wcur_ref,
     # picks METER_LANE_CLAUSE.  Padded rows/columns carry 0 A by the
     # wrapper's neutral padding, so they add exactly zero here.
     macc_ref[...] += i_chunk
-    acc_ref[...] += jax.lax.dot_general(
-        fired.astype(jnp.float32), wcur_ref[...],
-        (((1,), (0,)), ((), ())),
-        preferred_element_type=jnp.float32,
-    )
+    acc_ref[...] += _dot_f32(fired.astype(jnp.float32), wcur_ref[...])
 
     @pl.when(n == n_n - 1)
     def _epilogue():
@@ -227,7 +221,7 @@ def fused_impact_metered(drive: Array, ccur: Array, nonempty: Array,
         ],
         scratch_shapes=[pltpu.VMEM((block_b, M), jnp.float32),
                         pltpu.VMEM((block_b, METER_LANES), jnp.float32)],
-        compiler_params=_compat.CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary")),
         interpret=interpret,
     )(drive, ccur, nonempty, wcur)
@@ -268,11 +262,7 @@ def _packed_column_current(drive_ref, pbits_ref, r, i_lcs, i_hcs):
     i_col = None
     for j in range(_PLANES):                            # static bitplane unroll
         cur = _dequant_plane(codes32, j, i_lcs, i_hcs)
-        part = jax.lax.dot_general(
-            drive_ref[r, j], cur,
-            (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32,
-        )
+        part = _dot_f32(drive_ref[r, j], cur)
         i_col = part if i_col is None else i_col + part
     return i_col
 
@@ -294,11 +284,7 @@ def _fused_impact_packed_kernel(drive_ref, pbits_ref, lvl_ref, ne_ref,
     for r in range(n_r):                       # static unroll over row shards
         i_col = _packed_column_current(drive_ref, pbits_ref, r, i_lcs, i_hcs)
         fired = fired & (i_col < thresh)       # CSA + digital AND, in VMEM
-    acc_ref[...] += jax.lax.dot_general(
-        fired.astype(jnp.float32), wcur_ref[...],
-        (((1,), (0,)), ((), ())),
-        preferred_element_type=jnp.float32,
-    )
+    acc_ref[...] += _dot_f32(fired.astype(jnp.float32), wcur_ref[...])
 
     @pl.when(n == n_n - 1)
     def _epilogue():
@@ -354,7 +340,7 @@ def fused_impact_packed(drive: Array, pbits: Array, levels: Array,
         out_specs=pl.BlockSpec((block_b, M), lambda b, n: (b, 0)),
         out_shape=jax.ShapeDtypeStruct((B, M), jnp.float32),
         scratch_shapes=[pltpu.VMEM((block_b, M), jnp.float32)],
-        compiler_params=_compat.CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary")),
         interpret=interpret,
     )(drive, pbits, levels, nonempty, wcur)
@@ -444,7 +430,7 @@ def ta_feedback(litT: Array, sel: Array, match: Array, fired2: Array,
         ],
         out_specs=pl.BlockSpec((block_k, block_n), lambda k, n: (k, n)),
         out_shape=jax.ShapeDtypeStruct((K, N), jnp.int32),
-        compiler_params=_compat.CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary")),
         interpret=interpret,
     )(litT, sel, match, fired2, hi, lo, excl)
@@ -475,11 +461,7 @@ def _fused_impact_packed_metered_kernel(drive_ref, pbits_ref, lvl_ref,
         fired = fired & (i_col < thresh)       # CSA + digital AND, in VMEM
         i_chunk += i_col.sum(axis=1, keepdims=True)
     macc_ref[...] += i_chunk
-    acc_ref[...] += jax.lax.dot_general(
-        fired.astype(jnp.float32), wcur_ref[...],
-        (((1,), (0,)), ((), ())),
-        preferred_element_type=jnp.float32,
-    )
+    acc_ref[...] += _dot_f32(fired.astype(jnp.float32), wcur_ref[...])
 
     @pl.when(n == n_n - 1)
     def _epilogue():
@@ -523,7 +505,7 @@ def fused_impact_packed_metered(drive: Array, pbits: Array, levels: Array,
         ],
         scratch_shapes=[pltpu.VMEM((block_b, M), jnp.float32),
                         pltpu.VMEM((block_b, METER_LANES), jnp.float32)],
-        compiler_params=_compat.CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary")),
         interpret=interpret,
     )(drive, pbits, levels, nonempty, wcur)

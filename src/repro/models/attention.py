@@ -18,7 +18,6 @@ from typing import Any
 import jax
 import jax.numpy as jnp
 
-from .. import compat
 from .base import P, ShardCtx, dense, rms_norm
 from .config import ModelConfig
 from .rope import apply_rope, mrope_angles, rope_angles
@@ -278,7 +277,7 @@ def decode_attention(q: Array, k_cache: Array, v_cache: Array,
         for n in dp:
             dp_size *= mesh.shape[n]
         bspec = dp if (dp and B % dp_size == 0) else None
-        out = compat.shard_map(
+        out = jax.shard_map(
             lambda qq, kk, vv, ln: _attn(qq, kk, vv, ln, axis="model"),
             mesh=mesh,
             in_specs=(PS(bspec, None, None, "model"),

@@ -19,7 +19,6 @@ import math
 import jax
 import jax.numpy as jnp
 
-from .. import compat
 from .base import ACTIVATIONS, P, ShardCtx, dense
 from .config import ModelConfig, MoEConfig
 
@@ -198,7 +197,7 @@ def _routed_ep(p: dict, x: Array, cfg: ModelConfig,
             aux = jax.lax.pmean(aux, dp)
         return out, aux
 
-    fn = compat.shard_map(
+    fn = jax.shard_map(
         local_moe, mesh=mesh,
         in_specs=(P(dp if dp else None, None, None), P(None, None),
                   P("model", None, None), P("model", None, None),

@@ -57,7 +57,6 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
-from .. import compat
 from ..kernels import ops, ref
 from .rules import crossbar_rules
 
@@ -318,7 +317,7 @@ def fused_impact_shmap(literals: Array, clause_i: Array | None,
 
     out_specs = ((P(bspec, None),) if not meter
                  else (P(bspec, None), P(bspec), P(bspec)))
-    fn = compat.shard_map(
+    fn = jax.shard_map(
         local_fn, mesh=mesh,
         in_specs=(drive_spec,
                   P(rspec, None, None, None),

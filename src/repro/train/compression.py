@@ -45,8 +45,6 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from .. import compat
-
 Array = jax.Array
 
 
@@ -189,7 +187,7 @@ def int8_psum(v: Array, axis_name: str) -> Array:
     The leading dimension of the flattened tensor is padded to the axis
     size for the all_to_all phase.
     """
-    n = compat.axis_size(axis_name)
+    n = jax.lax.axis_size(axis_name)
     shape = v.shape
     flat = v.reshape(-1)
     pad = (-flat.size) % n

@@ -115,12 +115,19 @@ class OnlineTrainer:
             self._clause_var = DeviceVariation.none((R * tr, C * tc))
             self._class_var = DeviceVariation.none((S * sr, m))
 
-        #: f64 running meter: every update's write bill accumulates here;
-        #: the per-update ``records`` entries must sum to it exactly.
-        self.write_energy_j: float = 0.0
         self.records: list[dict[str, Any]] = []
         self.reports: list[EnergyReport] = []
         self._step = 0
+
+    @property
+    def write_energy_j(self) -> float:
+        """f64 write meter: the per-update bills in ``records`` summed
+        with the same ``sum`` that ``aggregate_reports`` applies to
+        ``reports``, so meter, per-record total and aggregated report lane
+        are equal by construction.  (A separate ``+=`` running total
+        rounds differently: ``sum`` compensates float additions on
+        Python >= 3.12.)"""
+        return float(sum(r["write_energy_j"] for r in self.records))
 
     # -- helpers ------------------------------------------------------------
     def _unipolar_padded(self, weights: Array) -> Array:
@@ -272,7 +279,6 @@ class OnlineTrainer:
             n_weight_cells=int(changed.sum()),
         )
         self.records.append(record)
-        self.write_energy_j += e_write
         self.reports.append(EnergyReport(
             read_energy_j=e_read, clause_energy_j=e_read,
             class_energy_j=0.0,

@@ -227,7 +227,7 @@ def test_fingerprint_counts_ops_not_module_attributes():
 def test_f64_widened_toy_executable_is_flagged():
     """A REAL lowered artifact with injected f64 widening (x64 mode), not
     just a crafted string, must trip the precision scan."""
-    with jax.experimental.enable_x64():
+    with jax.enable_x64(True):
         lowered = jax.jit(
             lambda x: jnp.asarray(x, jnp.float64) * 2.0,
         ).lower(jax.ShapeDtypeStruct((8,), jnp.float32))

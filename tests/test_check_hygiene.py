@@ -26,6 +26,8 @@ def test_clean_paths_pass():
         "artifacts/README",                 # not .json
         "docs/trace.json.md",               # not *.trace.json
         "src/repro/impact/artifacts_helper.py",  # 'artifacts/' only at root
+        "src/repro/compile_cache.py",       # the helper, not the cache
+        "tests/_hypothesis_compat.py",      # the shim, not the database
     ]
     assert check_hygiene.find_violations(clean) == []
 
@@ -39,6 +41,8 @@ def test_generated_paths_flagged():
         "artifacts/nested/BENCH_serve.json",
         "artifacts/SERVE_continuous.trace.json",
         "somewhere/else/SERVE_flush.trace.json",
+        ".jax_cache/jit_predict-0123abcd-cache",
+        ".hypothesis/examples/0a1b2c/3d4e5f",
     ]
     got = check_hygiene.find_violations(bad)
     assert [p for p, _ in got] == bad
@@ -46,6 +50,9 @@ def test_generated_paths_flagged():
     assert "bytecode" in labels["src/repro/kernels/ops.pyc"]
     assert "artifact" in labels["artifacts/BENCH_throughput.json"]
     assert "tracing" in labels["somewhere/else/SERVE_flush.trace.json"]
+    assert "compilation cache" in labels[
+        ".jax_cache/jit_predict-0123abcd-cache"]
+    assert "hypothesis" in labels[".hypothesis/examples/0a1b2c/3d4e5f"]
 
 
 def test_gitignore_gaps():
@@ -57,6 +64,9 @@ def test_gitignore_gaps():
         full + ["# noise", "", "  *.tmp  "]) == []
     missing_traces = [p for p in full if p != "*.trace.json"]
     assert check_hygiene.gitignore_gaps(missing_traces) == ["*.trace.json"]
+    for generated in (".jax_cache/", ".hypothesis/"):
+        assert check_hygiene.gitignore_gaps(
+            [p for p in full if p != generated]) == [generated]
     assert check_hygiene.gitignore_gaps(["# *.trace.json"]) == full
 
 

@@ -136,8 +136,7 @@ SCRIPT = textwrap.dedent("""
     import jax, jax.numpy as jnp, numpy as np
     import functools
     from jax.sharding import Mesh, PartitionSpec as P
-    from repro.compat import shard_map as _shard_map
-    shard_map = functools.partial(_shard_map, check_vma=False)
+    shard_map = functools.partial(jax.shard_map, check_vma=False)
     from repro.train.compression import int8_psum, compressed_grad_allreduce
 
     mesh = Mesh(np.asarray(jax.devices()).reshape(8), ("data",))

@@ -1,0 +1,189 @@
+"""Compile the served path's Pallas kernels for a TPU v5e at paper dims.
+
+Interpret mode (how every other test runs the kernels on the CPU) cannot
+see what the TPU's kernel compiler (Mosaic) refuses: unaligned blocks,
+unsupported dtypes or vector ops, or more VMEM than a kernel may use.
+Here each kernel is lowered with ``interpret=False`` and compiled for a
+*described* v5e chip — libtpu compiles for it without one attached — at
+the operand shapes the MNIST paper configuration (K=1568 literals,
+n=500 clauses, m=10 classes on the default 2048x512 tile) produces after
+the backends' neutral padding.  Nothing runs, so these tests say nothing
+about results or times.
+
+Only one process at a time may load libtpu, and the test workers import
+every test file: so the topology is described inside a module fixture
+(never at import), and all of these compiles live in this one file.
+"""
+import jax
+import jax.numpy as jnp
+import pytest
+from jax.sharding import SingleDeviceSharding
+
+from repro.analysis import vmem
+from repro.impact.yflash import I_CSA_THRESHOLD
+from repro.kernels import backends, packing
+
+# Paper MNIST configuration on the default tile geometry
+# (IMPACTConfig: 2048 tile rows, 512 tile columns, 2048 class rows).
+K, N_CLAUSES, M = 1568, 500, 10
+TILE_ROWS, TILE_COLS, CLASS_ROWS = 2048, 512, 2048
+
+
+@pytest.fixture(scope="module")
+def topo():
+    from jax.experimental import topologies
+    from jax.experimental.compilation_cache import compilation_cache
+
+    # A compile for a described chip can be written to the persistent
+    # cache but not read back without one: keep the cache out of it.
+    cache_was_on = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    with pytest.MonkeyPatch.context() as mp:
+        mp.setenv("TPU_LOG_DIR", "disabled")
+        try:
+            desc = topologies.get_topology_desc(platform="tpu",
+                                                topology_name="v5e:2x2")
+        except Exception as e:  # no libtpu, or it is held elsewhere
+            jax.config.update("jax_enable_compilation_cache", cache_was_on)
+            pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+    yield desc
+    jax.config.update("jax_enable_compilation_cache", cache_was_on)
+
+
+@pytest.fixture(scope="module")
+def shape(topo):
+    one_chip = SingleDeviceSharding(topo.devices[0])
+    return lambda dims, dtype: jax.ShapeDtypeStruct(dims, dtype,
+                                                    sharding=one_chip)
+
+
+def _compile_for_chip(fn, *args):
+    """AOT-compile ``fn`` for the described chip; -> compiled text.
+    Raises whatever the TPU compiler raises."""
+    compiled = jax.jit(fn).lower(*args).compile()
+    text = compiled.as_text()
+    assert "tpu_custom_call" in text, "no Mosaic kernel in the executable"
+    return text
+
+
+def _system_operands(shape, batch):
+    """The session's operands at paper dims, as shapes on one chip."""
+    return (shape((batch, K), jnp.int8),
+            shape((1, 1, TILE_ROWS, TILE_COLS), jnp.float32),
+            shape((TILE_COLS,), jnp.bool_),
+            shape((1, CLASS_ROWS, M), jnp.float32))
+
+
+@pytest.mark.parametrize("batch", [128, 512])
+@pytest.mark.parametrize("metered", [False, True],
+                         ids=["fused_impact", "fused_impact_metered"])
+def test_fused_impact_compiles_for_v5e(shape, batch, metered):
+    lit, clause_i, nonempty, class_i = _system_operands(shape, batch)
+    bk = backends.get_backend("pallas")
+    # The kernel operands the backend hands Mosaic at these dims: the
+    # clause-column axis unifies to N = max(512, 2048) = 2048.
+    ops_ = jax.eval_shape(
+        lambda *a: bk._fused_impact_operands(*a, block_b=128,
+                                             block_n=256)[:4],
+        lit, clause_i, nonempty, class_i)
+    assert [o.shape for o in ops_] == [(1, batch, 2048), (1, 2048, 2048),
+                                       (1, 2048), (2048, 128)]
+    assert ops_[2].dtype == jnp.int8
+    entry = bk.fused_impact_metered if metered else bk.fused_impact
+    _compile_for_chip(
+        lambda *a: entry(*a, thresh=I_CSA_THRESHOLD, interpret=False),
+        lit, clause_i, nonempty, class_i)
+    ws = vmem.fused_working_set(R=1, tr=TILE_ROWS, n_clause=TILE_COLS,
+                                class_rows=CLASS_ROWS, M=M, metered=metered)
+    assert ws.total_bytes <= vmem.DEFAULT_VMEM_BUDGET_BYTES, ws
+
+
+@pytest.mark.parametrize("batch", [128, 512])
+@pytest.mark.parametrize("metered", [False, True],
+                         ids=["fused_impact_packed",
+                              "fused_impact_packed_metered"])
+def test_fused_impact_packed_compiles_for_v5e(shape, batch, metered):
+    lit, _, nonempty, class_i = _system_operands(shape, batch)
+    tr4 = packing.packed_rows(TILE_ROWS)
+    packed = packing.PackedClause(
+        bits=shape((1, 1, tr4, TILE_COLS), jnp.uint8),
+        levels=shape((2,), jnp.float32))
+    bk = backends.get_backend("pallas-packed")
+    ops_ = jax.eval_shape(
+        lambda *a: bk._fused_impact_packed_operands(
+            *a, tr=TILE_ROWS, block_b=128, block_n=256)[:5],
+        lit, packed, nonempty, class_i)
+    assert [o.shape for o in ops_] == [(1, 4, batch, 512), (1, 512, 2048),
+                                       (1, 128), (1, 2048), (2048, 128)]
+    entry = (bk.fused_impact_packed_metered if metered
+             else bk.fused_impact_packed)
+    _compile_for_chip(
+        lambda *a: entry(*a, thresh=I_CSA_THRESHOLD, tr=TILE_ROWS,
+                         interpret=False),
+        lit, packed, nonempty, class_i)
+    ws = vmem.packed_working_set(R=1, tr4=tr4, n_clause=TILE_COLS,
+                                 class_rows=CLASS_ROWS, M=M, metered=metered)
+    assert ws.total_bytes <= vmem.DEFAULT_VMEM_BUDGET_BYTES, ws
+
+
+def test_ta_feedback_compiles_for_v5e(shape):
+    """The online trainer's update kernel, at paper dims and an update
+    batch of 64 rows (128 doubled feedback rows)."""
+    b2 = 128
+    row = lambda dt: shape((b2, N_CLAUSES), dt)
+    cell = lambda dt: shape((K, N_CLAUSES), dt)
+    bk = backends.get_backend("pallas")
+    _compile_for_chip(
+        lambda *a: bk.ta_feedback(*a, interpret=False),
+        shape((b2, K), jnp.int8), row(jnp.bool_), row(jnp.bool_),
+        row(jnp.bool_), cell(jnp.int32), cell(jnp.int32), cell(jnp.bool_))
+    ws = vmem.ta_feedback_working_set(K=K, n_clause=N_CLAUSES, batch2=b2)
+    assert ws.total_bytes <= vmem.DEFAULT_VMEM_BUDGET_BYTES, ws
+
+
+# crossbar_mvm runs the staged stages (single device and the co-resident
+# grid) and every per-device stage of the sharded grid.
+MVM_STAGES = {
+    "clause_stage": (TILE_ROWS, TILE_COLS),       # one clause row-shard
+    "class_stage": (CLASS_ROWS, M),               # one class row-shard
+    "clause_shard_r2": (784, TILE_COLS),          # R=2 split, per device
+    "class_shard_s2": (250, M),                   # S=2 split, per device
+}
+
+
+@pytest.mark.parametrize("stage", list(MVM_STAGES))
+def test_crossbar_mvm_compiles_for_v5e(shape, stage):
+    rows, cols = MVM_STAGES[stage]
+    bk = backends.get_backend("pallas")
+    _compile_for_chip(
+        lambda d, g: bk.crossbar_mvm(d, g, v_read=1.0, cutoff=0.0,
+                                     interpret=False),
+        shape((128, rows), jnp.float32), shape((rows, cols), jnp.float32))
+    ws = vmem.mvm_working_set(k_rows=rows)
+    assert ws.total_bytes <= vmem.DEFAULT_VMEM_BUDGET_BYTES, ws
+
+
+@pytest.mark.parametrize("metered", [False, True],
+                         ids=["sharded", "sharded_metered"])
+def test_sharded_grid_compiles_for_v5e_2x2(topo, metered):
+    """The README's sharded crossbar grid on all four chips of the
+    described host: the R=2 / S=2 split of the paper configuration
+    (IMPACTConfig(max_tile_rows=784, max_class_rows=250)) on a
+    (data=2, model=2) mesh.  Each device runs ``crossbar_mvm``; the
+    digital AND and the class-shard add are all-reduces over "model"."""
+    import numpy as np
+    from jax.sharding import Mesh, NamedSharding, PartitionSpec
+    from repro.sharding.crossbar import fused_impact_shmap
+
+    mesh = Mesh(np.asarray(topo.devices).reshape(2, 2), ("data", "model"))
+    rep = NamedSharding(mesh, PartitionSpec())
+    shape = lambda dims, dtype: jax.ShapeDtypeStruct(dims, dtype,
+                                                     sharding=rep)
+    text = _compile_for_chip(
+        lambda *a: fused_impact_shmap(*a, thresh=I_CSA_THRESHOLD, mesh=mesh,
+                                      impl="pallas", interpret=False,
+                                      meter=metered),
+        shape((512, K), jnp.int8), shape((2, 1, 784, TILE_COLS), jnp.float32),
+        shape((TILE_COLS,), jnp.bool_), shape((2, 250, M), jnp.float32))
+    assert "all-reduce" in text, "no cross-device combine in the executable"
