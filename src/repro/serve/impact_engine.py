@@ -145,9 +145,6 @@ class BatchStats:
     samples_per_s: float
     cold: bool = False    # first sweep of this shape: includes jit compile
     occupancy: float = 0.0
-    p50_s: float = 0.0    # end-to-end request-latency percentiles of the
-    p95_s: float = 0.0    # requests completed by this step
-    p99_s: float = 0.0
 
 
 @dataclasses.dataclass
@@ -180,9 +177,11 @@ class IMPACTEngine:
     ignored.
 
     ``trace`` (a ``serve.tracing.Tracer``) records the scheduler
-    timeline as Chrome-tracing spans: per-step ``admission`` / ``sweep``
-    / ``release`` / ``billing`` regions on the scheduler track (lane ids
-    and occupancy as span args) and the ``queued`` -> ``admitted`` ->
+    timeline as Chrome-tracing spans: per continuous-mode ``step``, the
+    ``admission`` / ``upload`` / ``sweep`` (``dispatch`` -> ``ready`` ->
+    ``fetch``) / ``billing`` / ``release`` regions on the scheduler track
+    (lane ids and occupancy as span args), mirrored as profiler
+    annotations while they run, and the ``queued`` -> ``admitted`` ->
     ``sweep`` -> ``billed`` lifecycle on one track per request, cut from
     the same clock readings the ``RequestRecord`` ledger stores.  The
     tracer is re-clocked onto the engine's clock so an injected virtual

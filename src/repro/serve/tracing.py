@@ -2,15 +2,15 @@
 
 The CI perf gates see *aggregates* (samples/s, p95); diagnosing a tail
 regression needs the *timeline* those aggregates summarize.  This module
-is a zero-dependency (stdlib ``json`` only) emitter of the Chrome Trace
-Event Format — the JSON *array* flavour that ``chrome://tracing`` and
-Perfetto load directly — so one serving run can be opened as a flame
-graph: a ``scheduler`` track with per-step ``admission`` / ``sweep`` /
-``release`` / ``billing`` spans, and one track per request with its
-``queued`` -> ``admitted`` -> ``sweep`` -> ``billed`` lifecycle, cut
-from the same ``RequestRecord`` / ``BatchStats`` timestamps the latency
-ledger reports (so span durations reconcile with the ledger by
-construction).
+emits the Chrome Trace Event Format — the JSON *array* flavour that
+``chrome://tracing`` and Perfetto load directly — so one serving run can
+be opened as a flame graph: a ``scheduler`` track with per-step
+``step`` spans holding ``admission`` / ``upload`` / ``sweep`` (itself
+``dispatch`` -> ``ready`` -> ``fetch``) / ``billing`` / ``release``,
+and one track per request with its ``queued`` -> ``admitted`` ->
+``sweep`` -> ``billed`` lifecycle, cut from the same ``RequestRecord`` /
+``BatchStats`` timestamps the latency ledger reports (so span durations
+reconcile with the ledger by construction).
 
 Design notes:
 
@@ -21,7 +21,15 @@ Design notes:
 * **B/E duration events.**  Spans are emitted as balanced
   begin/end pairs per track (``ph: "B"``/``"E"``), which Perfetto nests
   by timestamp; ``instant`` marks zero-width occurrences (e.g. a shed
-  request) and ``counter`` emits occupancy-style counter tracks.
+  request).
+* **Live spans are mirrored onto the profiler's clock.**  ``begin`` /
+  ``end`` (and ``region``) are called as the code they time runs, and
+  each also opens / closes a ``jax.profiler.TraceAnnotation`` of the
+  same name on the calling thread: a profile taken meanwhile
+  (TensorBoard, Perfetto) shows the scheduler's stages on the host line
+  beside the device ops, on the profiler's own clock.  ``span`` records
+  past timestamps and is not mirrored.  With no profiler running an
+  annotation costs about a microsecond.
 * **Per-request spans are emitted at completion** from the record's
   timestamps, never half-open across scheduler steps — a written trace
   always balances, even if the engine still holds queued work.
@@ -45,6 +53,8 @@ import dataclasses
 import json
 import time
 from typing import Any, Callable, Iterator
+
+from jax.profiler import TraceAnnotation
 
 PID_ENGINE = 0
 PID_REQUESTS = 1
@@ -70,6 +80,7 @@ class Tracer:
         self.events: list[dict[str, Any]] = []
         self._named: set[tuple[int, int | None]] = set()
         self._pid_names: dict[int, str] = {}
+        self._mirrors: list[TraceAnnotation] = []
 
     def __len__(self) -> int:
         return len(self.events)
@@ -100,28 +111,38 @@ class Tracer:
                                     tid=tid, args=dict(name=name)))
 
     # -- span primitives ----------------------------------------------------
-    def begin(self, name: str, *, ts: float | None = None, tid: int = 0,
-              pid: int = PID_ENGINE, args: dict | None = None) -> None:
-        self._ensure_named(pid, tid)
-        ev = dict(name=name, ph="B", ts=self.clock() if ts is None else ts,
+    def _emit(self, ph: str, name: str, ts: float | None, tid: int,
+              pid: int, args: dict | None) -> None:
+        if ph == "B":
+            self._ensure_named(pid, tid)
+        ev = dict(name=name, ph=ph, ts=self.clock() if ts is None else ts,
                   pid=pid, tid=tid, cat=self.cat)
         if args:
             ev["args"] = args
         self.events.append(ev)
+
+    def begin(self, name: str, *, ts: float | None = None, tid: int = 0,
+              pid: int = PID_ENGINE, args: dict | None = None) -> None:
+        """Open a live span (``ts`` defaults to now) and its mirror
+        annotation on the calling thread."""
+        self._emit("B", name, ts, tid, pid, args)
+        mirror = TraceAnnotation(name)
+        mirror.__enter__()
+        self._mirrors.append(mirror)
 
     def end(self, name: str, *, ts: float | None = None, tid: int = 0,
             pid: int = PID_ENGINE, args: dict | None = None) -> None:
-        ev = dict(name=name, ph="E", ts=self.clock() if ts is None else ts,
-                  pid=pid, tid=tid, cat=self.cat)
-        if args:
-            ev["args"] = args
-        self.events.append(ev)
+        """Close the innermost live span and its mirror annotation."""
+        if self._mirrors:
+            self._mirrors.pop().__exit__(None, None, None)
+        self._emit("E", name, ts, tid, pid, args)
 
     def span(self, name: str, t_begin: float, t_end: float, *, tid: int = 0,
              pid: int = PID_ENGINE, args: dict | None = None) -> None:
-        """One closed [t_begin, t_end] span as a balanced B/E pair."""
-        self.begin(name, ts=t_begin, tid=tid, pid=pid, args=args)
-        self.end(name, ts=t_end, tid=tid, pid=pid)
+        """One closed [t_begin, t_end] span as a balanced B/E pair (past
+        timestamps: not mirrored)."""
+        self._emit("B", name, t_begin, tid, pid, args)
+        self._emit("E", name, t_end, tid, pid, None)
 
     def instant(self, name: str, *, ts: float | None = None, tid: int = 0,
                 pid: int = PID_ENGINE, args: dict | None = None) -> None:
@@ -132,14 +153,6 @@ class Tracer:
         if args:
             ev["args"] = args
         self.events.append(ev)
-
-    def counter(self, name: str, value: float, *,
-                ts: float | None = None, pid: int = PID_ENGINE) -> None:
-        """Counter track (e.g. slot-table occupancy over time)."""
-        self._ensure_named(pid, 0)
-        self.events.append(dict(
-            name=name, ph="C", ts=self.clock() if ts is None else ts,
-            pid=pid, tid=0, cat=self.cat, args={name: float(value)}))
 
     @contextlib.contextmanager
     def region(self, name: str, *, tid: int = 0, pid: int = PID_ENGINE,

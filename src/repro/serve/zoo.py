@@ -380,9 +380,11 @@ class ModelZoo:
         sweep, then any due standby sweeps.  Returns completed
         ``(rid, tenant-local prediction)`` pairs; ``force`` fires below
         the SLO thresholds (tail drain)."""
-        out = self._step_resident(force)
-        out += self._step_standby(force)
-        return out
+        tr = self.trace
+        if tr is None:
+            return self._step_resident(force) + self._step_standby(force)
+        with tr.region("step"):
+            return self._step_resident(force) + self._step_standby(force)
 
     def _step_resident(self, force: bool) -> list[tuple[int, int]]:
         now = self.clock()
@@ -408,15 +410,20 @@ class ModelZoo:
         if not (force or self._should_fire(now, occ)):
             return []
         lanes = list(self.table.occupied())
-        out = self.execute_batch(jnp.asarray(self._lane_lits),
-                                 self.table.valid_mask(), self.capacity,
-                                 lanes)
+        tr = self.trace
+        if tr is not None:
+            tr.begin("upload")
+        lits = jnp.asarray(self._lane_lits)
+        valid = self.table.valid_mask()
+        if tr is not None:
+            tr.end("upload")
+        out = self.execute_batch(lits, valid, self.capacity, lanes)
         t_rel = self.clock()
         for i, _ in lanes:
             self.table.release(i)
             self._lane_lits[i] = 1
-        if self.trace is not None:
-            self.trace.span("release", t_rel, self.clock(), args=dict(
+        if tr is not None:
+            tr.span("release", t_rel, self.clock(), args=dict(
                 lanes=[i for i, _ in lanes],
                 occupancy=self.table.occupancy))
         return out
@@ -482,29 +489,42 @@ class ModelZoo:
         """One crossbar sweep + all per-step accounting (ledgers, energy
         billing, tenant-threaded trace spans)."""
         occupancy = len(lanes) / shape
+        tr = self.trace
         t0 = self.clock()
-        if self.trace is not None:
+        # The sweep span is dispatch -> ready -> fetch, cut at shared
+        # clock readings so the three tile it exactly.
+        if tr is not None:
             args = dict(shape=shape, n_valid=len(lanes),
                         occupancy=occupancy, cold=cold,
                         lanes=[i for i, _ in lanes])
             if standby:
                 args["standby_tenant"] = lanes[0][1].tenant.tid
-            self.trace.begin("sweep", ts=t0, args=args)
+            tr.begin("sweep", ts=t0, args=args)
+            tr.begin("dispatch", ts=t0)
         if model_ids is not None:
             res = session.infer_step(lits, valid, model_ids=model_ids)
         else:
             res = session.infer_step(lits, valid)
-        preds = np.asarray(jax.block_until_ready(res.predictions))
+        if tr is not None:
+            t = self.clock()
+            tr.end("dispatch", ts=t)
+            tr.begin("ready", ts=t)
+        jax.block_until_ready(res.predictions)
+        if tr is not None:
+            t = self.clock()
+            tr.end("ready", ts=t)
+            tr.begin("fetch", ts=t)
+        preds = np.asarray(res.predictions)
         # float64 before the per-request clause+class add so the request
         # bills sum to the (float64) batch meter, not to f32 rounding.
         e_cl = np.asarray(res.e_clause_lanes, np.float64)
         e_cs = np.asarray(res.e_class_lanes, np.float64)
         t1 = self.clock()
         dt = t1 - t0
-        if self.trace is not None:
-            self.trace.end("sweep", ts=t1)
-            self.trace.begin("billing", ts=t1,
-                             args=dict(n_requests=len(lanes)))
+        if tr is not None:
+            tr.end("fetch", ts=t1)
+            tr.end("sweep", ts=t1)
+            tr.begin("billing", ts=t1, args=dict(n_requests=len(lanes)))
         recs = [RequestRecord(
             rid=lane.req.rid, arrived=lane.req.arrived,
             admitted=lane.admitted, completed=t1, pred=int(preds[i]),
@@ -513,13 +533,10 @@ class ModelZoo:
         self.request_records.extend(recs)
         for _, lane in lanes:
             lane.tenant.completed += 1
-        pct = latency_percentiles([r.latency_s for r in recs])
         self.batch_stats.append(BatchStats(
             bucket=shape, n_valid=len(recs), latency_s=dt,
             samples_per_s=len(recs) / max(dt, 1e-9), cold=cold,
-            occupancy=occupancy,
-            p50_s=pct.get("p50_s", 0.0), p95_s=pct.get("p95_s", 0.0),
-            p99_s=pct.get("p99_s", 0.0)))
+            occupancy=occupancy))
         if standby:
             self.standby_sweeps += 1
         else:
@@ -527,11 +544,11 @@ class ModelZoo:
         if session.meters_energy:
             self.reports.append(session.system.step_report(e_cl, e_cs,
                                                            len(recs)))
-        if self.trace is not None:
+        if tr is not None:
             t2 = self.clock()
-            self.trace.end("billing", ts=t2)
+            tr.end("billing", ts=t2)
             for (i, lane), r in zip(lanes, recs):
-                self.trace.request_spans(
+                tr.request_spans(
                     rid=r.rid, arrived=r.arrived, admitted=r.admitted,
                     sweep_start=t0, sweep_end=t1, billed=t2, lane=i,
                     shape=shape, pid=self._pid_for(lane.tenant),

@@ -289,8 +289,6 @@ def test_request_latency_percentiles_in_stats(small_system):
     assert lat["n"] == 24
     assert 0 < lat["p50_s"] <= lat["p95_s"] <= lat["p99_s"] <= lat["max_s"]
     assert stats["queue_wait"]["n"] == 24
-    # per-step percentiles ride on BatchStats too
-    assert all(s.p95_s >= s.p50_s > 0 for s in eng.batch_stats)
 
 
 def test_latency_percentiles_helper():

@@ -150,6 +150,102 @@ def test_engine_burst_trace_is_valid_and_reconciles(small_system):
         assert lat_us / 1e6 == pytest.approx(rec.latency_s, abs=1e-6)
 
 
+#: The scheduler cycle's live spans: each sweep has one of each.
+SWEEP_STAGES = ("upload", "dispatch", "ready", "fetch")
+
+
+def _live(tr, name):
+    """[(begin_s, end_s)] of scheduler-track spans ``name``, on the
+    engine's clock (raw, unrebased seconds)."""
+    return [(b, e) for _, _, b, e, _ in
+            _spans(tr.events, pid=PID_ENGINE, tid=0, name=name)]
+
+
+def _inside(inner, outer):
+    return any(a <= inner[0] and inner[1] <= b for a, b in outer)
+
+
+def _traced_burst(system, lits, tr, n=20):
+    """Serve ``n`` rows through a capacity-8 engine, one ``step()`` at a
+    time, after one idle step; returns (engine, step() calls)."""
+    eng = IMPACTEngine(system.compile(spec(capacity=8)), trace=tr)
+    assert eng.step() == []                    # idle step: no sweep
+    calls = 1
+    for row in lits[:n]:
+        eng.submit(row)
+    done = {}
+    while len(done) < n:
+        done.update(eng.step(force=True))
+        calls += 1
+    return eng, calls
+
+
+def test_scheduler_cycle_spans_tile_and_nest(small_system):
+    """Every ``step()`` call is one ``step`` span; every sweep has one
+    ``upload``, ``dispatch``, ``ready`` and ``fetch``; dispatch, ready
+    and fetch tile ``sweep`` (which still equals BatchStats.latency_s),
+    and every scheduler span lies inside a ``step``."""
+    system, lits = small_system
+    tr = Tracer()
+    eng, calls = _traced_burst(system, lits, tr)
+    validate_events(tr.to_json())
+    assert tr._mirrors == []                   # every annotation closed
+    steps = _live(tr, "step")
+    sweeps = _live(tr, "sweep")
+    assert len(steps) == calls
+    assert len(sweeps) == len(eng.batch_stats) >= 3
+    for (b, e), st in zip(sweeps, eng.batch_stats):
+        assert e - b == st.latency_s           # same clock readings
+    stages = {name: _live(tr, name) for name in SWEEP_STAGES}
+    for name, items in stages.items():
+        assert len(items) == len(sweeps), name
+    for i, (b, e) in enumerate(sweeps):
+        d, r, f = (stages[n][i] for n in ("dispatch", "ready", "fetch"))
+        assert (d[0], d[1], r[1], f[1]) == (b, r[0], f[0], e)
+        assert stages["upload"][i][1] <= b
+    for name in ("admission", "upload", "sweep", "billing", "release"):
+        for span in _live(tr, name):
+            assert _inside(span, steps), name
+    for name in ("dispatch", "ready", "fetch"):
+        for span in stages[name]:
+            assert _inside(span, sweeps), name
+
+
+def test_detached_tracer_records_nothing(small_system):
+    """With ``trace=None`` the scheduler records no span: a tracer that
+    was attached and then detached gains no event from a burst."""
+    system, lits = small_system
+    tr = Tracer()
+    eng = IMPACTEngine(system.compile(spec(capacity=8)), trace=tr)
+    eng.trace = None
+    n_meta = len(tr)
+    eng.run(lits[:12])
+    assert eng.trace is None and len(eng.batch_stats) >= 2
+    assert len(tr) == n_meta and tr._mirrors == []
+
+
+def test_scheduler_spans_reach_the_profiler_host_plane(small_system,
+                                                       tmp_path):
+    """Live scheduler spans are mirrored as profiler annotations: a
+    profile of a traced burst holds ``step``, ``sweep`` and ``fetch`` on
+    its host plane; past-timestamp request spans are not mirrored."""
+    system, lits = small_system
+    opts = jax.profiler.ProfileOptions()
+    opts.python_tracer_level = 0        # Python frames would name `step`
+    jax.profiler.start_trace(str(tmp_path), profiler_options=opts)
+    try:
+        _traced_burst(system, lits, Tracer(), n=12)
+    finally:
+        jax.profiler.stop_trace()
+    (path,) = tmp_path.glob("**/*.xplane.pb")
+    profile = jax.profiler.ProfileData.from_file(str(path))
+    names = {ev.name for plane in profile.planes
+             if plane.name.startswith("/host:")
+             for line in plane.lines for ev in line.events}
+    assert {"step", "sweep", "fetch"} <= names
+    assert "queued" not in names
+
+
 def test_flush_trace_carries_bucket_shape(small_system):
     """Flush-mode sweeps run at bucketed shapes; the trace must say
     which bucket each sweep was padded to."""
