@@ -22,7 +22,11 @@ that ``IMPACTSystem.compile(spec)`` resolves ONCE into an immutable
   (``session.trace_count``) pin in tests;
 * results come back as a unified ``InferenceResult`` (predictions,
   scores, optional ``EnergyReport`` / per-lane energies) instead of
-  per-entry-point tuple shapes.
+  per-entry-point tuple shapes;
+* what the host needs of a call crosses to it in ONE device->host
+  transfer (``InferenceSession.fetch`` for ``infer_step``, the joules
+  vector inside ``infer_with_report``), counted by
+  ``session.fetch_count`` as compiles are by ``trace_count``.
 
 The legacy per-call kwargs (``impl=``, ``mesh=``, ``meter=``,
 ``meter_energy=``) keep working through thin shims on ``IMPACTSystem``
@@ -262,12 +266,23 @@ class InferenceResult:
     class currents; ``report`` is the batch-level ``EnergyReport`` from
     ``infer_with_report``; the per-lane energies (J) ride ``infer_step``
     so a serving scheduler can bill each request individually.
+
+    ``host_buffer`` (set by ``infer_step`` only) is the single-transfer
+    host view of the three per-lane fields: one (3, B) float32 device
+    array, rows predictions / clause joules / class joules, emitted by
+    the same executable beside them.  ``InferenceSession.fetch`` copies
+    it to the host in one transfer; the separate fields stay device
+    arrays for callers that keep results on the device.  It is no
+    ``__init__`` argument, so a result rebuilt from other fields
+    (``dataclasses.replace``) carries none and cannot disagree with them.
     """
     predictions: Array
     scores: Array | None = None
     report: EnergyReport | None = None
     e_clause_lanes: Array | None = None
     e_class_lanes: Array | None = None
+    host_buffer: Array | None = dataclasses.field(
+        default=None, init=False, repr=False, compare=False)
 
 
 class InferenceSession:
@@ -278,6 +293,11 @@ class InferenceSession:
     spec resolution (backend lookup, mesh/shard-plan placement, metering
     mode) happens here, once; the entry points only look up an
     executable and run it.
+
+    Device->host traffic is one transfer per call: ``fetch`` brings an
+    ``infer_step`` result's predictions and per-lane joules over as one
+    buffer, and ``infer_with_report`` fetches its two joule figures as
+    one vector.  ``fetch_count`` counts these transfers.
     """
 
     def __init__(self, system, spec: RuntimeSpec):
@@ -315,6 +335,10 @@ class InferenceSession:
         self._exes: dict[tuple[str, int], Any] = {}
         self._irs: dict[tuple[str, int], str] = {}
         self._traces: collections.Counter = collections.Counter()
+        self._fetches = 0
+        # All-valid masks of ``infer_with_report(valid=None)``, made on
+        # the device once per batch size.
+        self._all_valid: dict[int, Array] = {}
         # Programming-time compilation: the serving sweep and any
         # declared predict shapes are executables before the first
         # request arrives.
@@ -338,6 +362,13 @@ class InferenceSession:
         (== number of compiles).  Frozen after warmup: the retrace-guard
         tests assert this does not move across serving."""
         return int(sum(self._traces.values()))
+
+    @property
+    def fetch_count(self) -> int:
+        """Device->host transfers made by ``fetch`` and
+        ``infer_with_report``: one per call on the results this session
+        made."""
+        return self._fetches
 
     def compiled_shapes(self, entry: str | None = None) -> list[tuple]:
         return sorted(k for k in self._exes
@@ -441,11 +472,32 @@ class InferenceSession:
         mids = self._model_ids(model_ids, lits.shape[0])
         exe = self._exe("infer_step", lits.shape[0])
         if mids is None:
-            preds, e_cl, e_cs = exe(lits, v, *self._operands())
+            preds, e_cl, e_cs, host = exe(lits, v, *self._operands())
         else:
-            preds, e_cl, e_cs = exe(lits, v, mids, *self._operands())
-        return InferenceResult(predictions=preds, e_clause_lanes=e_cl,
-                               e_class_lanes=e_cs)
+            preds, e_cl, e_cs, host = exe(lits, v, mids, *self._operands())
+        res = InferenceResult(predictions=preds, e_clause_lanes=e_cl,
+                              e_class_lanes=e_cs)
+        object.__setattr__(res, "host_buffer", host)
+        return res
+
+    def fetch(self, result: InferenceResult,
+              ) -> tuple[np.ndarray, np.ndarray, np.ndarray]:
+        """An ``infer_step`` result on the host: ``(predictions int32,
+        clause joules float64, class joules float64)`` per lane, equal to
+        ``np.asarray`` of the three fields.  One device->host transfer of
+        ``host_buffer``; a result without one (rebuilt from other
+        fields) costs a transfer per field.  The joules are float64
+        before any caller sums them, so request bills add up to the
+        float64 batch meter and not to float32 rounding."""
+        if result.host_buffer is not None:
+            lanes = self._to_host(result.host_buffer)
+        else:
+            lanes = [self._to_host(x) for x in (result.predictions,
+                                                result.e_clause_lanes,
+                                                result.e_class_lanes)]
+        return (np.asarray(lanes[0], np.int32),
+                np.asarray(lanes[1], np.float64),
+                np.asarray(lanes[2], np.float64))
 
     def infer_with_report(self, literals, valid=None,
                           model_ids=None) -> InferenceResult:
@@ -462,20 +514,23 @@ class InferenceSession:
                 "(single-pass, serving speed) or 'staged' (the oracle)")
         lits = self._lits(literals)
         B = lits.shape[0]
-        v_np = (np.ones((B,), bool) if valid is None
-                else np.asarray(valid, bool))
+        if valid is None:
+            v = self._all_valid.get(B)
+            if v is None:
+                v = self._all_valid[B] = jnp.ones((B,), jnp.bool_)
+            n_dp = B
+        else:
+            v_np = np.asarray(valid, bool)
+            v = jnp.asarray(v_np)
+            n_dp = int(v_np.sum())
         mids = self._model_ids(model_ids, B)
         exe = self._exe("infer_with_report", B)
         if mids is None:
-            preds, i_cl_sum, i_cs_sum = exe(lits, jnp.asarray(v_np),
-                                            *self._operands())
+            preds, joules = exe(lits, v, *self._operands())
         else:
-            preds, i_cl_sum, i_cs_sum = exe(lits, jnp.asarray(v_np), mids,
-                                            *self._operands())
+            preds, joules = exe(lits, v, mids, *self._operands())
         sys_ = self.system
-        e_clause = float(V_READ * i_cl_sum * T_READ)
-        e_class = float(V_READ * i_cs_sum * T_READ)
-        n_dp = int(v_np.sum())
+        e_clause, e_class = (float(j) for j in self._to_host(joules))
         ops_xp = n_dp * (sys_.n_literals * sys_.n_clauses
                          + sys_.n_clauses * sys_.n_classes)
         report = EnergyReport(
@@ -508,6 +563,10 @@ class InferenceSession:
                    jnp.asarray(include, jnp.bool_))
 
     # -- compiled-function plumbing -----------------------------------------
+    def _to_host(self, x: Array) -> np.ndarray:
+        self._fetches += 1
+        return np.asarray(x)
+
     def _lits(self, literals) -> Array:
         return jnp.asarray(literals, LITERAL_DTYPE)
 
@@ -818,7 +877,15 @@ class InferenceSession:
 
     def _infer_step_fn(self, literals, valid, *args):
         self._traces["infer_step"] += 1
-        valid = valid.astype(bool)
+        preds, e_cl, e_cs = self._step_lanes(literals, valid.astype(bool),
+                                             *args)
+        # float32 holds every prediction -1 .. m-1 exactly.
+        host = jnp.stack([preds.astype(jnp.float32), e_cl, e_cs])
+        return preds, e_cl, e_cs, host
+
+    def _step_lanes(self, literals, valid, *args):
+        """Per-lane (predictions, clause joules, class joules) of one
+        sweep; invalid lanes predict -1 and bill zero."""
         if self.coresident is not None:
             model_ids, *operands = args
             if not self.meters_energy:
@@ -843,6 +910,16 @@ class InferenceSession:
 
     def _report_fn(self, literals, valid, *args):
         self._traces["infer_with_report"] += 1
+        preds, i_cl, i_cs = self._report_sums(literals, valid, *args)
+        # E = V_R * I * t_read, the same float32 products in the same
+        # order as the per-lane meters.  XLA folds V_READ * T_READ into
+        # one constant; V_READ is a power of two, so that is exact.
+        return preds, jnp.stack([V_READ * i_cl * T_READ,
+                                 V_READ * i_cs * T_READ])
+
+    def _report_sums(self, literals, valid, *args):
+        """(predictions, batch-summed clause current, batch-summed class
+        current) of one metered batch."""
         valid = valid.astype(bool)
         if self.coresident is not None:
             model_ids, *operands = args

@@ -490,6 +490,7 @@ class ModelZoo:
         billing, tenant-threaded trace spans)."""
         occupancy = len(lanes) / shape
         tr = self.trace
+        fetched = session.fetch_count
         t0 = self.clock()
         # The sweep span is dispatch -> ready -> fetch, cut at shared
         # clock readings so the three tile it exactly.
@@ -514,15 +515,14 @@ class ModelZoo:
             t = self.clock()
             tr.end("ready", ts=t)
             tr.begin("fetch", ts=t)
-        preds = np.asarray(res.predictions)
-        # float64 before the per-request clause+class add so the request
-        # bills sum to the (float64) batch meter, not to f32 rounding.
-        e_cl = np.asarray(res.e_clause_lanes, np.float64)
-        e_cs = np.asarray(res.e_class_lanes, np.float64)
+        preds, e_cl, e_cs = session.fetch(res)
         t1 = self.clock()
         dt = t1 - t0
         if tr is not None:
             tr.end("fetch", ts=t1)
+            # Known only now: it joins the args the sweep's begin event
+            # holds.
+            args["fetches"] = session.fetch_count - fetched
             tr.end("sweep", ts=t1)
             tr.begin("billing", ts=t1, args=dict(n_requests=len(lanes)))
         recs = [RequestRecord(

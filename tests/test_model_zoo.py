@@ -291,6 +291,36 @@ def test_zoo_trace_per_tenant_tracks():
     assert {"tenant t0", "tenant t1", "tenant t2"} <= names
 
 
+def test_zoo_sweep_spans_count_one_fetch():
+    """A resident and a standby sweep each carry ``fetches=1`` on their
+    ``sweep`` span (one device->host transfer, not one per output), and
+    ``dispatch`` + ``ready`` + ``fetch`` still tile ``sweep``."""
+    tr = Tracer(clock=time.monotonic)
+    zoo, systems = make_zoo(3, max_resident=2, trace=tr)
+    rng = np.random.default_rng(5)
+    for t, row in zip(zoo.tenants, random_rows(systems, rng)):
+        zoo.submit(t.tid, row)
+    zoo.step(force=True)
+    validate_events(tr.to_json())
+    opened, spans = {}, {}
+    for e in tr.events:
+        if e.get("pid") == 0 and e["ph"] in ("B", "E"):
+            if e["ph"] == "B":
+                opened[e["name"]] = e
+            else:
+                b = opened.pop(e["name"])
+                spans.setdefault(e["name"], []).append(
+                    (b["ts"], e["ts"], b.get("args", {})))
+    sweeps = spans["sweep"]
+    assert ["standby_tenant" in args for _, _, args in sweeps] == [False,
+                                                                    True]
+    for i, (b, e, args) in enumerate(sweeps):
+        assert args["fetches"] == 1
+        (d0, d1, _), (r0, r1, _), (f0, f1, _) = (
+            spans[n][i] for n in ("dispatch", "ready", "fetch"))
+        assert (d0, d1, r1, f1) == (b, r0, f0, e)
+
+
 # -- standby pool / rebalance -------------------------------------------------
 
 def test_zoo_standby_serving_and_promotion():

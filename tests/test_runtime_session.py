@@ -1,6 +1,7 @@
 """Compiled-session runtime: RuntimeSpec validation, backend registry
 pluggability, compile-once semantics (retrace guard), InferenceResult
-contents, and exact-parity deprecation shims for the old per-call kwargs.
+contents, one device->host transfer per call, and exact-parity
+deprecation shims for the old per-call kwargs.
 """
 import dataclasses
 
@@ -11,7 +12,8 @@ import pytest
 
 from repro.impact import (IMPACTConfig, InferenceResult, InferenceSession,
                           RuntimeSpec, SpecDeprecationWarning, Topology,
-                          build_system)
+                          build_coresident, build_system)
+from repro.impact.yflash import T_READ, V_READ
 from repro.core import CoTMConfig
 from repro.core.cotm import CoTMParams
 from repro.kernels import backends
@@ -32,6 +34,19 @@ def small_system():
                           IMPACTConfig(variability=False, finetune=False))
     lits = rng.random((40, K)) < 0.5
     return system, lits
+
+
+@pytest.fixture(scope="module")
+def coresident_system(small_system):
+    """Two copies of the small system on one grid, and a slot buffer
+    whose lanes alternate between them."""
+    system, lits = small_system
+    combined, plan = build_coresident([system, system])
+    buf = np.ones((8, combined.n_literals), np.int8)
+    mids = np.arange(8, dtype=np.int32) % 2
+    for i, sp in enumerate(plan.spans[m] for m in mids):
+        buf[i, sp.lit_lo:sp.lit_hi] = lits[i]
+    return combined, plan, buf, mids
 
 
 # -- backend registry --------------------------------------------------------
@@ -295,6 +310,111 @@ def test_metering_off_blocks_reports_and_zeros_lanes(small_system):
     np.testing.assert_array_equal(np.asarray(step.e_clause_lanes), 0.0)
     with pytest.raises(RuntimeError, match="metering"):
         sess.infer_with_report(lits[:8])
+
+
+# -- one device->host transfer per call --------------------------------------
+
+def _session_case(small_system, coresident_system, metering, packing,
+                  coresident):
+    """(session, 8-lane literal buffer, model_ids kwargs) for one case."""
+    spec = RuntimeSpec(backend="xla", metering=metering, packing=packing,
+                       capacity=8)
+    if coresident:
+        combined, plan, buf, mids = coresident_system
+        sess = combined.compile(dataclasses.replace(spec, coresident=plan))
+        return sess, buf, dict(model_ids=mids)
+    system, lits = small_system
+    return system.compile(spec), np.asarray(lits[:8], np.int8), {}
+
+
+CASES = pytest.mark.parametrize(
+    "metering,packing,coresident",
+    [(m, p, c) for m in ("staged", "fused") for p in ("none", "2bit")
+     for c in (False, True)])
+
+
+@CASES
+def test_fetch_is_one_transfer_of_the_device_fields(
+        small_system, coresident_system, metering, packing, coresident):
+    """``fetch`` returns exactly what ``np.asarray`` of the three device
+    fields gives — sentinels on invalid lanes included — in one transfer
+    counted by ``fetch_count``."""
+    sess, buf, kw = _session_case(small_system, coresident_system,
+                                  metering, packing, coresident)
+    valid = np.array([1, 1, 0, 1, 0, 1, 1, 0], bool)
+    res = sess.infer_step(buf, valid, **kw)
+    before = sess.fetch_count
+    preds, e_cl, e_cs = sess.fetch(res)
+    assert sess.fetch_count == before + 1
+    assert preds.dtype == np.int32
+    assert e_cl.dtype == e_cs.dtype == np.float64
+    np.testing.assert_array_equal(preds, np.asarray(res.predictions))
+    np.testing.assert_array_equal(
+        e_cl, np.asarray(res.e_clause_lanes, np.float64))
+    np.testing.assert_array_equal(
+        e_cs, np.asarray(res.e_class_lanes, np.float64))
+    assert (preds[~valid] == -1).all() and (preds[valid] >= 0).all()
+    assert (e_cl[~valid] == 0).all() and (e_cl[valid] > 0).all()
+
+
+def test_fetch_of_a_rebuilt_result_reads_its_fields(small_system):
+    """A result rebuilt with other fields carries no host buffer, so
+    ``fetch`` cannot serve the executable's stale view of them: it
+    copies the fields themselves, one transfer each."""
+    system, lits = small_system
+    sess = system.compile(RuntimeSpec(backend="xla", capacity=8))
+    res = sess.infer_step(np.asarray(lits[:8], np.int8), np.ones((8,), bool))
+    p = res.predictions
+    alt = dataclasses.replace(
+        res, predictions=p.at[0].set((p[0] + 1) % system.n_classes))
+    assert res.host_buffer is not None and alt.host_buffer is None
+    before = sess.fetch_count
+    preds, e_cl, e_cs = sess.fetch(alt)
+    assert sess.fetch_count == before + 3
+    np.testing.assert_array_equal(preds, np.asarray(alt.predictions))
+    assert preds[0] != np.asarray(p)[0]
+    np.testing.assert_array_equal(
+        e_cl, np.asarray(res.e_clause_lanes, np.float64))
+    np.testing.assert_array_equal(
+        e_cs, np.asarray(res.e_class_lanes, np.float64))
+
+
+@CASES
+def test_report_joules_match_the_eager_product(
+        small_system, coresident_system, metering, packing, coresident):
+    """``infer_with_report`` bills ``float(V_READ * i * T_READ)`` of the
+    executable's summed currents bit for bit, in one transfer."""
+    sess, buf, kw = _session_case(small_system, coresident_system,
+                                  metering, packing, coresident)
+    valid = np.array([1, 1, 1, 0, 1, 1, 0, 1], bool)
+    before = sess.fetch_count
+    rep = sess.infer_with_report(buf, valid, **kw)
+    assert sess.fetch_count == before + 1
+    mids = ((jnp.asarray(kw["model_ids"]),) if kw else ())
+    preds, i_cl, i_cs = jax.jit(sess._report_sums)(
+        jnp.asarray(buf), jnp.asarray(valid), *mids, *sess._operands())
+    assert rep.report.clause_energy_j == float(V_READ * i_cl * T_READ)
+    assert rep.report.class_energy_j == float(V_READ * i_cs * T_READ)
+    assert rep.report.clause_energy_j > 0
+    np.testing.assert_array_equal(np.asarray(rep.predictions),
+                                  np.asarray(preds))
+    assert isinstance(rep.predictions, jax.Array)
+
+
+def test_report_without_valid_uploads_no_mask(small_system):
+    """``infer_with_report(valid=None)`` makes its all-valid mask on the
+    device once per batch size: later calls upload nothing (device
+    literals in, so any host->device transfer would be the mask)."""
+    system, lits = small_system
+    sess = system.compile(RuntimeSpec(backend="xla", metering="fused"))
+    rows = jnp.asarray(lits[:8], jnp.int8)
+    first = sess.infer_with_report(rows).report
+    with jax.transfer_guard_host_to_device("disallow_explicit"):
+        again = sess.infer_with_report(rows).report
+        with pytest.raises(jax.errors.JaxRuntimeError,
+                           match="Disallowed host-to-device"):
+            sess.infer_with_report(rows, valid=np.ones((8,), bool))
+    assert again == first and again.datapoints == 8
 
 
 # -- deprecation shims: old kwargs forward, warn, and agree exactly ----------
