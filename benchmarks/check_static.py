@@ -113,7 +113,12 @@ def run_audit(vmem_budget: int | None,
     for tag, kw in AUDIT_SPECS:
         if vmem_budget is not None:
             kw = dict(kw, vmem_budget_bytes=vmem_budget)
-        session = system.compile(RuntimeSpec(**kw))
+        try:
+            session = system.compile(RuntimeSpec(**kw))
+        except ValueError as e:         # refused at compile (over budget)
+            print(f"  audit[{tag}]: refused at compile: {e}")
+            failures.append(f"audit[{tag}]: {e}")
+            continue
         # The online-training feedback executable rides every
         # non-co-resident session: audit it alongside the serving
         # entries (batch 8 = the doubled 2B feedback row count).

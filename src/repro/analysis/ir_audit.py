@@ -18,7 +18,8 @@ actually run*, not of the python that generated it:
   A host callback in the sweep loop would serialize every scheduler
   sweep on the python GIL.
 * **VMEM budget** — the Pallas working set priced by ``analysis.vmem``
-  must fit ``RuntimeSpec.vmem_budget_bytes`` (default 16 MiB/core).
+  must fit ``RuntimeSpec.vmem_budget_bytes`` (default 16 MiB/core); the
+  report keeps each executable's kernel plan beside it.
 
 It also fingerprints each executable (op histogram + operand bytes) so
 CI can diff the lowered artifact against a committed baseline: a jax
@@ -62,6 +63,8 @@ class AuditReport:
     fingerprints: dict[str, dict[str, Any]]      # "entry@batch" -> fingerprint
     vmem_bytes: dict[str, int]                   # "entry@batch" -> working set
     vmem_budget_bytes: int
+    plans: dict[str, dict[str, Any]] = dataclasses.field(
+        default_factory=dict)                    # "entry@batch" -> KernelPlan
 
     @property
     def ok(self) -> bool:
@@ -74,6 +77,7 @@ class AuditReport:
             "fingerprints": self.fingerprints,
             "vmem_bytes": self.vmem_bytes,
             "vmem_budget_bytes": self.vmem_budget_bytes,
+            "plans": self.plans,
         }
 
 
@@ -190,6 +194,7 @@ def audit_session(session, entry: str | None = None,
     findings: list[AuditFinding] = []
     fingerprints: dict[str, dict[str, Any]] = {}
     vmem_bytes: dict[str, int] = {}
+    plans: dict[str, dict[str, Any]] = {}
     budget = (session.spec.vmem_budget_bytes
               or vmem.DEFAULT_VMEM_BUDGET_BYTES)
 
@@ -200,14 +205,15 @@ def audit_session(session, entry: str | None = None,
         findings += scan_host_io(ir, entry=e, batch=b)
         fingerprints[tag] = fingerprint_text(ir)
 
-        ws = vmem.session_working_set(session, e, b)
-        if ws is not None:
-            vmem_bytes[tag] = ws.total_bytes
-            if ws.total_bytes > budget:
+        plan = session.kernel_plan(e, b)
+        if plan is not None:
+            plans[tag] = dataclasses.asdict(plan)
+            vmem_bytes[tag] = plan.vmem_step_bytes
+            if plan.vmem_step_bytes > budget:
                 findings.append(AuditFinding(
                     "vmem", "error", e, b,
-                    f"{ws.variant} working set {ws.total_bytes} B exceeds "
-                    f"the VMEM budget {budget} B "
+                    f"{plan.variant} working set {plan.vmem_step_bytes} B "
+                    f"exceeds the VMEM budget {budget} B "
                     f"(blocks x{vmem.PIPELINE_BUFFERS} + scratch)"))
 
         if baselines is not None:
@@ -227,7 +233,8 @@ def audit_session(session, entry: str | None = None,
                         + ("; ..." if len(deltas) > 8 else "")))
 
     return AuditReport(findings=tuple(findings), fingerprints=fingerprints,
-                       vmem_bytes=vmem_bytes, vmem_budget_bytes=budget)
+                       vmem_bytes=vmem_bytes, vmem_budget_bytes=budget,
+                       plans=plans)
 
 
 def audit_ir_text(ir_text: str, *, entry: str = "hlo",

@@ -17,6 +17,10 @@ change ``BLOCK_B``/``BLOCK_N`` there and this estimate moves with it.
 Estimates are per-core upper bounds: a sharded topology only shrinks
 per-device operands, and interpret mode has no VMEM at all, so the
 estimate is conservative in both directions that matter.
+
+``session_plan`` names the kernel an ``(InferenceSession, entry, batch)``
+executable runs, with its grid extents and its bytes per grid step: the
+plan a session records for every executable it compiles.
 """
 from __future__ import annotations
 
@@ -59,6 +63,10 @@ class WorkingSet:
     variant: str
     blocks: dict[str, int]
     scratch: dict[str, int]
+    #: Grid extent of the kernel's literal (contraction-row) axis, and of
+    #: its clause-column axis.
+    literal_chunks: int = 1
+    column_blocks: int = 1
 
     @property
     def total_bytes(self) -> int:
@@ -71,7 +79,9 @@ def fused_working_set(*, R: int, tr: int, n_clause: int, class_rows: int,
                       block_b: int | None = None,
                       block_n: int | None = None) -> WorkingSet:
     """Working set of the fused IMPACT kernel (unpacked f32 operands),
-    mirroring ``PallasBackend._fused_impact_operands`` padding."""
+    mirroring ``PallasBackend._fused_impact_operands`` padding.  A grid
+    step holds one literal row-shard, so the bytes are the same for any
+    ``R``; ``R`` is the extent of the kernel's shard axis."""
     block_b = block_b or _FUSED_BLOCK_B
     block_n = block_n or _FUSED_BLOCK_N
     N = max(n_clause, class_rows)
@@ -79,18 +89,20 @@ def fused_working_set(*, R: int, tr: int, n_clause: int, class_rows: int,
     tr_pad = max(128, _ceil_to(tr, 128))
     m_pad = _ceil_to(M, 128)
     blocks = {
-        "drive": R * block_b * tr_pad * _F32,
-        "ccur": R * tr_pad * block_n * _F32,
+        "drive": block_b * tr_pad * _F32,
+        "ccur": tr_pad * block_n * _F32,
         "nonempty": block_n * _I8,
         "wcur": block_n * m_pad * _F32,
         "out": block_b * m_pad * _F32,
     }
-    scratch = {"acc": block_b * m_pad * _F32}
+    scratch = {"acc": block_b * m_pad * _F32,
+               "fired": block_b * block_n * _F32}
     if metered:
         blocks["meter_out"] = block_b * _METER_LANES * _F32
         scratch["macc"] = block_b * _METER_LANES * _F32
     return WorkingSet("fused_impact_metered" if metered else "fused_impact",
-                      blocks, scratch)
+                      blocks, scratch, literal_chunks=R,
+                      column_blocks=_ceil_to(N, block_n) // block_n)
 
 
 def packed_working_set(*, R: int, tr4: int, n_clause: int, class_rows: int,
@@ -99,7 +111,8 @@ def packed_working_set(*, R: int, tr4: int, n_clause: int, class_rows: int,
                        block_n: int | None = None) -> WorkingSet:
     """Working set of the bitplane-packed fused kernel, mirroring
     ``PackedPallasBackend._fused_impact_packed_operands`` padding.
-    ``tr4`` is the packed per-shard row count (4 cells/byte)."""
+    ``tr4`` is the packed per-shard row count (4 cells/byte).  This kernel
+    holds all ``R`` row-shards in one block, so its bytes grow with R."""
     block_b = block_b or _FUSED_BLOCK_B
     block_n = block_n or _FUSED_BLOCK_N
     N = max(n_clause, class_rows)
@@ -120,15 +133,17 @@ def packed_working_set(*, R: int, tr4: int, n_clause: int, class_rows: int,
         scratch["macc"] = block_b * _METER_LANES * _F32
     return WorkingSet(
         "fused_impact_packed_metered" if metered else "fused_impact_packed",
-        blocks, scratch)
+        blocks, scratch, column_blocks=_ceil_to(N, block_n) // block_n)
 
 
-def mvm_working_set(*, k_rows: int, block_b: int | None = None,
+def mvm_working_set(*, k_rows: int, n_cols: int = 1,
+                    block_b: int | None = None,
                     block_n: int | None = None,
                     block_k: int | None = None) -> WorkingSet:
     """Working set of one staged ``crossbar_mvm`` call over ``k_rows``
-    drive rows (the Fig. 14 per-shard unroll runs one such kernel per
-    crossbar stage; each call's footprint is independent)."""
+    drive rows and ``n_cols`` columns (the Fig. 14 per-shard unroll runs
+    one such kernel per crossbar stage; each call's footprint is
+    independent)."""
     block_b = block_b or _MVM_BLOCK_B
     block_n = block_n or _MVM_BLOCK_N
     block_k = min(block_k or _MVM_BLOCK_K,
@@ -139,7 +154,9 @@ def mvm_working_set(*, k_rows: int, block_b: int | None = None,
         "out": block_b * block_n * _F32,
     }
     scratch = {"acc": block_b * block_n * _F32}
-    return WorkingSet("crossbar_mvm", blocks, scratch)
+    return WorkingSet("crossbar_mvm", blocks, scratch,
+                      literal_chunks=_ceil_to(k_rows, block_k) // block_k,
+                      column_blocks=-(-n_cols // block_n))
 
 
 def ta_feedback_working_set(*, K: int, n_clause: int, batch2: int,
@@ -165,7 +182,9 @@ def ta_feedback_working_set(*, K: int, n_clause: int, batch2: int,
         "excl": block_k * block_n * _F32,
         "out": block_k * block_n * _I32,
     }
-    return WorkingSet("ta_feedback", blocks, {})
+    return WorkingSet("ta_feedback", blocks, {},
+                      literal_chunks=_ceil_to(K, block_k) // block_k,
+                      column_blocks=_ceil_to(n_clause, block_n) // block_n)
 
 
 def session_working_set(session, entry: str,
@@ -175,9 +194,9 @@ def session_working_set(session, entry: str,
     ``InferenceSession._scores_expr`` / ``_metered_expr``:
 
     * reference (oracle) backends run no kernel -> ``None``;
-    * co-resident sessions and ``metering="staged"`` entries ride the
-      staged ``crossbar_mvm`` compositions -> the larger of the clause /
-      class stage calls;
+    * co-resident sessions, sharded topologies and ``metering="staged"``
+      entries ride the staged ``crossbar_mvm`` compositions -> the
+      larger of the clause / class stage calls;
     * ``packing="2bit"`` on the ``pallas-packed`` backend -> the packed
       kernel; on other Pallas backends the session dequantizes outside
       and runs the unpacked kernel;
@@ -207,11 +226,13 @@ def session_working_set(session, entry: str,
     metered_kernel = ((metered_entry and spec.metering == "fused")
                       or backend.name == "pallas-metered")
 
-    if session.coresident is not None or staged:
-        # Staged per-shard unroll: one crossbar_mvm per clause row-shard
-        # (tr drive rows) + one per class row-shard (sr drive rows).
-        clause = mvm_working_set(k_rows=tr)
-        klass = mvm_working_set(k_rows=sr)
+    if (session.coresident is not None or staged
+            or session.plan is not None):
+        # Staged per-shard unroll (also each device's stage of a sharded
+        # grid): one crossbar_mvm per clause row-shard (tr drive rows) +
+        # one per class row-shard (sr drive rows).
+        clause = mvm_working_set(k_rows=tr, n_cols=n_clause)
+        klass = mvm_working_set(k_rows=sr, n_cols=M)
         return clause if clause.total_bytes >= klass.total_bytes else klass
 
     if spec.packing == "2bit" and backend.name == "pallas-packed":
@@ -221,3 +242,49 @@ def session_working_set(session, entry: str,
                                   metered=metered_kernel)
     return fused_working_set(R=R, tr=tr, n_clause=n_clause,
                              class_rows=S * sr, M=M, metered=metered_kernel)
+
+
+@dataclasses.dataclass(frozen=True)
+class KernelPlan:
+    """What one session executable runs on a TPU core: the kernel
+    variant, the system's literal row-shards, the kernel's grid extents
+    along its literal and clause-column axes, and its VMEM bytes per
+    grid step (blocks x``PIPELINE_BUFFERS`` + scratch)."""
+    variant: str
+    row_shards: int
+    literal_chunks: int
+    column_blocks: int
+    vmem_step_bytes: int
+
+
+def session_plan(session, entry: str,
+                 batch: int | None = None) -> KernelPlan | None:
+    """The ``KernelPlan`` of the ``(session, entry)`` executable, from
+    ``session_working_set``; ``None`` where no kernel runs."""
+    ws = session_working_set(session, entry, batch)
+    if ws is None:
+        return None
+    return KernelPlan(variant=ws.variant,
+                      row_shards=int(session.system.clause_i.shape[0]),
+                      literal_chunks=ws.literal_chunks,
+                      column_blocks=ws.column_blocks,
+                      vmem_step_bytes=ws.total_bytes)
+
+
+def refuse_packed_over_budget(session) -> None:
+    """Raise ``ValueError`` if the session would run the packed kernel,
+    which holds every literal row-shard in one block, with a working set
+    over the spec's VMEM budget: the TPU compiler would refuse it later,
+    at the first executable."""
+    budget = session.spec.vmem_budget_bytes or DEFAULT_VMEM_BUDGET_BYTES
+    for entry in ("predict", "infer_with_report"):
+        ws = session_working_set(session, entry)
+        if (ws is not None and ws.variant.startswith("fused_impact_packed")
+                and ws.total_bytes > budget):
+            R = session.system.clause_i.shape[0]
+            raise ValueError(
+                f"packing='2bit' on backend {session.spec.backend!r} runs "
+                f"{ws.variant}, which holds all R={R} literal row-shards "
+                f"in one block: {ws.total_bytes} B of VMEM per grid step, "
+                f"over the budget of {budget} B.  Compile packing='none' "
+                f"(the shard-gridded kernel) or use fewer row-shards.")

@@ -45,6 +45,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from ..analysis import vmem
 from ..kernels import backends
 from ..kernels import packing as packing_mod
 from ..kernels import ref as kernels_ref
@@ -332,7 +333,9 @@ class InferenceSession:
         # not of any call.
         self._packed = (packing_mod.pack_clause_operand(system.clause_i)
                         if spec.packing == "2bit" else None)
+        vmem.refuse_packed_over_budget(self)
         self._exes: dict[tuple[str, int], Any] = {}
+        self._plans: dict[tuple[str, int], vmem.KernelPlan | None] = {}
         self._irs: dict[tuple[str, int], str] = {}
         self._traces: collections.Counter = collections.Counter()
         self._fetches = 0
@@ -399,6 +402,15 @@ class InferenceSession:
         return dict(flops=float(ca.get("flops", 0.0)),
                     bytes_accessed=float(ca.get("bytes accessed", 0.0)))
 
+    def kernel_plan(self, entry: str, batch: int) -> vmem.KernelPlan | None:
+        """The kernel the ``(entry, batch)`` executable runs: variant,
+        literal row-shards, grid extents along the literal and column
+        axes, and VMEM bytes per grid step (``analysis.vmem``); ``None``
+        on a reference backend.  Compiles on demand like every other
+        session access."""
+        self._exe(entry, batch)
+        return self._plans[(entry, batch)]
+
     def ir_text(self, entry: str, batch: int) -> str:
         """Lowered StableHLO of the ``(entry, batch)`` executable — the
         exact artifact handed to XLA, captured at compile time.  Compiles
@@ -412,9 +424,10 @@ class InferenceSession:
         ``analysis.ir_audit``): precision ladder (no f64, no sub-f32
         meters), host isolation (no callbacks/infeed/outfeed), Pallas
         VMEM working set vs ``spec.vmem_budget_bytes``, and executable
-        fingerprints (diffed against ``baselines`` when given).  Audits
-        every compiled executable by default, or one ``(entry, batch)``
-        pair — compiling it on demand."""
+        fingerprints (diffed against ``baselines`` when given), and the
+        kernel plan of each (``kernel_plan``).  Audits every compiled
+        executable by default, or one ``(entry, batch)`` pair — compiling
+        it on demand."""
         from ..analysis import ir_audit as _ir_audit
         if entry is not None and batch is not None:
             self._exe(entry, batch)
@@ -600,6 +613,7 @@ class InferenceSession:
         if exe is None:
             exe = self._compile_entry(entry, batch)
             self._exes[key] = exe
+            self._plans[key] = vmem.session_plan(self, entry, batch)
         return exe
 
     def _compile_entry(self, entry: str, batch: int):

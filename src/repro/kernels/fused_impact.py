@@ -6,18 +6,20 @@ CoTM (include mask + integer weights), this kernel fuses the *physical*
 simulation — per-cell Y-Flash read currents, the CSA threshold, and the
 digital periphery — in one VMEM residency:
 
-    per clause-column chunk n:
-        for each of the R literal row-shards r:
-            I_col[r]  = drive[r] @ I_cell[r][:, n]     # Kirchhoff column sum
-            partial_r = I_col[r] < I_CSA_THRESHOLD     # CSA latch
-        fired   = AND_r partial_r  &  nonempty[n]      # digital AND (Fig. 14)
-        scores += fired @ I_class[n, :]                # class column currents
+    grid (batch block b, clause-column block n, literal row-shard r):
+        I_col    = drive[r, b] @ I_cell[r][:, n]        # Kirchhoff column sum
+        fired[n] = fired[n] & (I_col < I_CSA_THRESHOLD)  # CSA + digital AND
+                   (shard 0 starts from nonempty[n])    # (Fig. 14)
+        after the last shard:
+            scores[b] += fired[n] @ I_class[n, :]       # class column currents
 
-The Boolean clause chunk ``fired`` never leaves VMEM: the (B, n_pad) clause
+The Boolean clause block ``fired`` never leaves VMEM: a (block_b, block_n)
+scratch carries it across the row-shard axis, so the (B, n_pad) clause
 matrix — the largest intermediate of the un-fused path — is never
 materialized in HBM.  The class crossbar's S row-shards are flattened onto
-the clause-chunk axis, so the per-shard ADC + digital add is subsumed by
-the chunk accumulation (exact: the class read is linear in the drive).
+the clause-column axis, so the per-shard ADC + digital add is subsumed by
+the column-block accumulation (exact: the class read is linear in the
+drive).
 
 Layouts (prepared by ``ops.fused_impact``):
   drive   (R, B, tr)   f32   1 - literal, row-shard major; padding rows 0
@@ -26,14 +28,18 @@ Layouts (prepared by ``ops.fused_impact``):
   wcur    (N, M)       f32   class-cell read currents, S shards flattened
   out     (B, M)       f32   class column currents (argmax = prediction)
 
-R stays whole per block (the digital AND needs every shard's partial bit),
-mirroring ``fused_cotm`` keeping K whole; this bounds R*tr at a few
-thousand rows — exactly the regime of a physical crossbar column height.
+Each grid step holds ONE row-shard's (block_b, tr) drive and (tr, block_n)
+currents, so VMEM per step is the same for any R and any N: a CoTM of any
+literal count runs on the paper's 2048-row tiles.  The shard axis is the
+innermost: at R=1 every block index but ccur's stays put across the
+column axis, so the pipeline fetches the drive once per batch block; at
+R>1 it streams the drive once per column block (R*B*tr*4 bytes, n_n
+times) and the currents once per batch block.
 
 ``fused_impact_metered`` is the same datapath with in-kernel energy
 metering: the paper (and IMBUE, arXiv:2305.12914) measure read energy as
 ``E = V_R * I_col * t_read`` summed over the very column currents the
-inference already computes, so the metered kernel folds each chunk's
+inference already computes, so the metered kernel folds each step's
 ``I_col`` into a second VMEM accumulator while the CSA consumes it —
 joules come out of the single fused pass with no staged second pass and
 without ever materializing the (B, n_pad) clause matrix in HBM.
@@ -65,25 +71,111 @@ def _dot_f32(a, b):
         preferred_element_type=jnp.float32)
 
 
-def _fused_impact_kernel(drive_ref, ccur_ref, ne_ref, wcur_ref, out_ref,
-                         acc_ref, *, n_n: int, n_r: int, thresh: float):
-    n = pl.program_id(1)
+#: Lane layout of the metered kernel's (B, METER_LANES) meter output:
+#: lane 0 carries the summed clause-crossbar column currents, lane 1 the
+#: summed class-crossbar column currents.  128 lanes (one VREG row) keep
+#: the output MXU/VPU tile-aligned; the wrapper slices the two live lanes.
+METER_LANE_CLAUSE = 0
+METER_LANE_CLASS = 1
+METER_LANES = 128
 
-    @pl.when(n == 0)
+
+def _fused_impact_kernel(drive_ref, ccur_ref, ne_ref, wcur_ref, *refs,
+                         n_n: int, n_r: int, thresh: float, metered: bool):
+    """One grid step (b, n, r): row-shard r's column currents for column
+    block n, its CSA decisions AND-ed into ``fired_ref`` (the block's 0/1
+    clause bits, carried in VMEM across the shard axis; shard 0 starts
+    from the digital ``nonempty`` mask, so empty and padded columns never
+    fire), and after the last shard the class read of the block.
+
+    ``metered`` adds the in-kernel energy meter: each step's clause
+    column currents are folded into a second VMEM accumulator
+    (``macc_ref``) the moment the CSA consumes them.  The class-current
+    meter needs no extra accumulation at all: the class read is linear,
+    so the summed class column current is exactly the row-sum of the
+    score accumulator — computed once in the epilogue.
+    """
+    if metered:
+        out_ref, meter_ref, acc_ref, fired_ref, macc_ref = refs
+    else:
+        out_ref, acc_ref, fired_ref = refs
+    n, r = pl.program_id(1), pl.program_id(2)
+
+    @pl.when(jnp.logical_and(n == 0, r == 0))
     def _init():
         acc_ref[...] = jnp.zeros_like(acc_ref)
+        if metered:
+            macc_ref[...] = jnp.zeros_like(macc_ref)
 
-    bb = drive_ref.shape[1]
-    bn = ne_ref.shape[1]
-    fired = jnp.broadcast_to(ne_ref[...] != 0, (bb, bn))
-    for r in range(n_r):                       # static unroll over row shards
-        i_col = _dot_f32(drive_ref[r], ccur_ref[r])
-        fired = fired & (i_col < thresh)       # CSA + digital AND, in VMEM
-    acc_ref[...] += _dot_f32(fired.astype(jnp.float32), wcur_ref[...])
+    i_col = _dot_f32(drive_ref[0], ccur_ref[0])   # Kirchhoff column sums
 
-    @pl.when(n == n_n - 1)
+    @pl.when(r == 0)
+    def _first():
+        ne = jnp.broadcast_to(ne_ref[...] != 0, i_col.shape)
+        fired_ref[...] = (ne & (i_col < thresh)).astype(jnp.float32)
+
+    @pl.when(r > 0)
+    def _and():                                   # digital AND (Fig. 14)
+        fired_ref[...] = jnp.where(i_col < thresh, fired_ref[...], 0.0)
+
+    if metered:
+        # Every meter lane accumulates the same per-lane clause current
+        # (a plain VPU broadcast-add — no per-step lane select); the
+        # epilogue picks METER_LANE_CLAUSE.  Padded rows/columns carry
+        # 0 A by the wrapper's neutral padding, so they add exactly zero.
+        macc_ref[...] += i_col.sum(axis=1, keepdims=True)
+
+    @pl.when(r == n_r - 1)
+    def _class():
+        acc_ref[...] += _dot_f32(fired_ref[...], wcur_ref[...])
+
+    @pl.when(jnp.logical_and(n == n_n - 1, r == n_r - 1))
     def _epilogue():
         out_ref[...] = acc_ref[...]
+        if metered:
+            lane = jax.lax.broadcasted_iota(jnp.int32, macc_ref.shape, 1)
+            i_class = acc_ref[...].sum(axis=1, keepdims=True)
+            meter_ref[...] = jnp.where(
+                lane == METER_LANE_CLAUSE, macc_ref[...],
+                jnp.where(lane == METER_LANE_CLASS, i_class, 0.0))
+
+
+def _shard_grid_call(drive, ccur, nonempty, wcur, *, thresh, block_b,
+                     block_n, interpret, metered):
+    """The ``pallas_call`` of both unpacked kernels on the (batch block,
+    column block, literal row-shard) grid; -> a list of outputs."""
+    R, B, tr = drive.shape
+    R2, tr2, N = ccur.shape
+    N2, M = wcur.shape
+    assert R == R2 and tr == tr2 and N == N2 and nonempty.shape == (1, N)
+    assert (B % block_b == 0 and N % block_n == 0 and tr % 128 == 0
+            and M % 128 == 0), (B, R, tr, N, M)
+    n_n = N // block_n
+    lanes = [M, METER_LANES] if metered else [M]
+    scratch = [pltpu.VMEM((block_b, M), jnp.float32),        # class currents
+               pltpu.VMEM((block_b, block_n), jnp.float32)]  # AND-ed bits
+    if metered:                                              # clause meter
+        scratch.append(pltpu.VMEM((block_b, METER_LANES), jnp.float32))
+    return pl.pallas_call(
+        functools.partial(_fused_impact_kernel, n_n=n_n, n_r=R,
+                          thresh=thresh, metered=metered),
+        grid=(B // block_b, n_n, R),
+        in_specs=[
+            pl.BlockSpec((1, block_b, tr), lambda b, n, r: (r, b, 0)),
+            pl.BlockSpec((1, tr, block_n), lambda b, n, r: (r, 0, n)),
+            pl.BlockSpec((1, block_n), lambda b, n, r: (0, n)),
+            pl.BlockSpec((block_n, M), lambda b, n, r: (n, 0)),
+        ],
+        out_specs=[pl.BlockSpec((block_b, m), lambda b, n, r: (b, 0))
+                   for m in lanes],
+        out_shape=[jax.ShapeDtypeStruct((B, m), jnp.float32)
+                   for m in lanes],
+        scratch_shapes=scratch,
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "arbitrary", "arbitrary")),
+        interpret=interpret,
+        name="fused_impact_metered" if metered else "fused_impact",
+    )(drive, ccur, nonempty, wcur)
 
 
 @functools.partial(
@@ -97,84 +189,9 @@ def fused_impact(drive: Array, ccur: Array, nonempty: Array, wcur: Array, *,
     B % block_b == 0, N % block_n == 0, tr % 128 == 0, M % 128 == 0 required
     (``ops.fused_impact`` pads arbitrary shapes and shard layouts).
     """
-    R, B, tr = drive.shape
-    R2, tr2, N = ccur.shape
-    N2, M = wcur.shape
-    assert R == R2 and tr == tr2 and N == N2 and nonempty.shape == (1, N)
-    assert (B % block_b == 0 and N % block_n == 0 and tr % 128 == 0
-            and M % 128 == 0), (B, R, tr, N, M)
-    n_n = N // block_n
-
-    return pl.pallas_call(
-        functools.partial(_fused_impact_kernel, n_n=n_n, n_r=R,
-                          thresh=thresh),
-        grid=(B // block_b, n_n),
-        in_specs=[
-            pl.BlockSpec((R, block_b, tr), lambda b, n: (0, b, 0)),
-            pl.BlockSpec((R, tr, block_n), lambda b, n: (0, 0, n)),
-            pl.BlockSpec((1, block_n), lambda b, n: (0, n)),
-            pl.BlockSpec((block_n, M), lambda b, n: (n, 0)),
-        ],
-        out_specs=pl.BlockSpec((block_b, M), lambda b, n: (b, 0)),
-        out_shape=jax.ShapeDtypeStruct((B, M), jnp.float32),
-        scratch_shapes=[pltpu.VMEM((block_b, M), jnp.float32)],
-        compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("parallel", "arbitrary")),
-        interpret=interpret,
-    )(drive, ccur, nonempty, wcur)
-
-
-#: Lane layout of the metered kernel's (B, METER_LANES) meter output:
-#: lane 0 carries the summed clause-crossbar column currents, lane 1 the
-#: summed class-crossbar column currents.  128 lanes (one VREG row) keep
-#: the output MXU/VPU tile-aligned; the wrapper slices the two live lanes.
-METER_LANE_CLAUSE = 0
-METER_LANE_CLASS = 1
-METER_LANES = 128
-
-
-def _fused_impact_metered_kernel(drive_ref, ccur_ref, ne_ref, wcur_ref,
-                                 out_ref, meter_ref, acc_ref, macc_ref, *,
-                                 n_n: int, n_r: int, thresh: float):
-    """The fused datapath + in-kernel energy meter.
-
-    Identical clause/class compute to ``_fused_impact_kernel``; on top,
-    each chunk's clause column currents are folded into a second VMEM
-    accumulator (``macc_ref``) the moment the CSA consumes them.  The
-    class-current meter needs no extra accumulation at all: the class
-    read is linear, so the summed class column current is exactly the
-    row-sum of the score accumulator — computed once in the epilogue.
-    """
-    n = pl.program_id(1)
-
-    @pl.when(n == 0)
-    def _init():
-        acc_ref[...] = jnp.zeros_like(acc_ref)
-        macc_ref[...] = jnp.zeros_like(macc_ref)
-
-    bb = drive_ref.shape[1]
-    bn = ne_ref.shape[1]
-    fired = jnp.broadcast_to(ne_ref[...] != 0, (bb, bn))
-    i_chunk = jnp.zeros((bb, 1), jnp.float32)
-    for r in range(n_r):                       # static unroll over row shards
-        i_col = _dot_f32(drive_ref[r], ccur_ref[r])
-        fired = fired & (i_col < thresh)       # CSA + digital AND, in VMEM
-        i_chunk += i_col.sum(axis=1, keepdims=True)
-    # Every meter lane accumulates the same per-lane clause current (a
-    # plain VPU broadcast-add — no per-chunk lane select); the epilogue
-    # picks METER_LANE_CLAUSE.  Padded rows/columns carry 0 A by the
-    # wrapper's neutral padding, so they add exactly zero here.
-    macc_ref[...] += i_chunk
-    acc_ref[...] += _dot_f32(fired.astype(jnp.float32), wcur_ref[...])
-
-    @pl.when(n == n_n - 1)
-    def _epilogue():
-        out_ref[...] = acc_ref[...]
-        lane = jax.lax.broadcasted_iota(jnp.int32, macc_ref.shape, 1)
-        i_class = acc_ref[...].sum(axis=1, keepdims=True)
-        meter_ref[...] = jnp.where(
-            lane == METER_LANE_CLAUSE, macc_ref[...],
-            jnp.where(lane == METER_LANE_CLASS, i_class, 0.0))
+    return _shard_grid_call(drive, ccur, nonempty, wcur, thresh=thresh,
+                            block_b=block_b, block_n=block_n,
+                            interpret=interpret, metered=False)[0]
 
 
 @functools.partial(
@@ -193,38 +210,11 @@ def fused_impact_metered(drive: Array, ccur: Array, nonempty: Array,
     backend plumbing (``PallasBackend.fused_impact_metered``) pads inputs
     and slices the live meter lanes back out.
     """
-    R, B, tr = drive.shape
-    R2, tr2, N = ccur.shape
-    N2, M = wcur.shape
-    assert R == R2 and tr == tr2 and N == N2 and nonempty.shape == (1, N)
-    assert (B % block_b == 0 and N % block_n == 0 and tr % 128 == 0
-            and M % 128 == 0), (B, R, tr, N, M)
-    n_n = N // block_n
-
-    return pl.pallas_call(
-        functools.partial(_fused_impact_metered_kernel, n_n=n_n, n_r=R,
-                          thresh=thresh),
-        grid=(B // block_b, n_n),
-        in_specs=[
-            pl.BlockSpec((R, block_b, tr), lambda b, n: (0, b, 0)),
-            pl.BlockSpec((R, tr, block_n), lambda b, n: (0, 0, n)),
-            pl.BlockSpec((1, block_n), lambda b, n: (0, n)),
-            pl.BlockSpec((block_n, M), lambda b, n: (n, 0)),
-        ],
-        out_specs=[
-            pl.BlockSpec((block_b, M), lambda b, n: (b, 0)),
-            pl.BlockSpec((block_b, METER_LANES), lambda b, n: (b, 0)),
-        ],
-        out_shape=[
-            jax.ShapeDtypeStruct((B, M), jnp.float32),
-            jax.ShapeDtypeStruct((B, METER_LANES), jnp.float32),
-        ],
-        scratch_shapes=[pltpu.VMEM((block_b, M), jnp.float32),
-                        pltpu.VMEM((block_b, METER_LANES), jnp.float32)],
-        compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("parallel", "arbitrary")),
-        interpret=interpret,
-    )(drive, ccur, nonempty, wcur)
+    out, meters = _shard_grid_call(drive, ccur, nonempty, wcur,
+                                   thresh=thresh, block_b=block_b,
+                                   block_n=block_n, interpret=interpret,
+                                   metered=True)
+    return out, meters
 
 
 # -- bitplane-packed datapath -------------------------------------------------
@@ -364,8 +354,8 @@ def fused_impact_packed(drive: Array, pbits: Array, levels: Array,
 #   delta   = hi*present - lo*(absent + decay) + excl*inval
 #
 # The whole 2B contraction happens inside one block (like R staying whole
-# in the inference kernels), so each (block_k, block_n) output tile is
-# independent — no cross-chunk accumulator.  f32 MACs are exact for the
+# in the packed inference kernels), so each (block_k, block_n) output
+# tile is independent — no cross-chunk accumulator.  f32 MACs are exact for the
 # integer mask counts involved (< 2**24).  Layouts (prepared by
 # ``backends.PallasBackend.ta_feedback``):
 #

@@ -180,8 +180,9 @@ class IMPACTEngine:
     timeline as Chrome-tracing spans: per continuous-mode ``step``, the
     ``admission`` / ``upload`` / ``sweep`` (``dispatch`` -> ``ready`` ->
     ``fetch``) / ``billing`` / ``release`` regions on the scheduler track
-    (lane ids and occupancy as span args, and on ``sweep`` its count of
-    device->host ``fetches``), mirrored as profiler
+    (lane ids and occupancy as span args; on ``sweep`` its count of
+    device->host ``fetches`` and, from the session's kernel plan, its
+    ``row_shards`` and ``vmem_step_bytes``), mirrored as profiler
     annotations while they run, and the ``queued`` -> ``admitted`` ->
     ``sweep`` -> ``billed`` lifecycle on one track per request, cut from
     the same clock readings the ``RequestRecord`` ledger stores.  The

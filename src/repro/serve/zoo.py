@@ -500,6 +500,10 @@ class ModelZoo:
                         lanes=[i for i, _ in lanes])
             if standby:
                 args["standby_tenant"] = lanes[0][1].tenant.tid
+            plan = session.kernel_plan("infer_step", shape)
+            if plan is not None:
+                args.update(row_shards=plan.row_shards,
+                            vmem_step_bytes=plan.vmem_step_bytes)
             tr.begin("sweep", ts=t0, args=args)
             tr.begin("dispatch", ts=t0)
         if model_ids is not None:

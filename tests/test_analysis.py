@@ -3,6 +3,7 @@ fixture and stays quiet on the shipped tree; the IR audit flags injected
 f64 widening, host callbacks, VMEM-busting budgets and fingerprint
 drift, and passes the real compiled sessions clean.
 """
+import dataclasses
 import importlib.util
 import json
 import pathlib
@@ -260,6 +261,27 @@ def test_vmem_estimates_are_positive_and_ordered():
     assert 0 < mvm.total_bytes < ws.total_bytes
 
 
+@pytest.mark.parametrize("metered", [False, True])
+def test_fused_working_set_is_independent_of_row_shards(metered):
+    """A grid step of the fused kernel holds one literal row-shard: the
+    10,000-literal text CoTM (R=5 shards of 2048 rows, 10,240 clause
+    columns, 2 classes) prices the same per step as one shard, within
+    the default budget; only the grid's shard extent grows."""
+    dims = dict(tr=2048, n_clause=10240, class_rows=10240, M=2,
+                metered=metered)
+    one = vmem.fused_working_set(R=1, **dims)
+    five = vmem.fused_working_set(R=5, **dims)
+    assert one.total_bytes == five.total_bytes
+    assert five.total_bytes <= vmem.DEFAULT_VMEM_BUDGET_BYTES
+    assert (one.literal_chunks, five.literal_chunks) == (1, 5)
+    assert five.column_blocks == 10240 // 256
+    # The packed kernel still holds every shard in one block.
+    p1, p5 = (vmem.packed_working_set(R=r, tr4=512, n_clause=10240,
+                                      class_rows=10240, M=2,
+                                      metered=metered) for r in (1, 5))
+    assert p5.total_bytes > p1.total_bytes and p5.literal_chunks == 1
+
+
 # -- session-level audit -----------------------------------------------------
 
 @pytest.fixture(scope="module")
@@ -288,6 +310,39 @@ def test_session_executables_pass_the_audit(small_system):
     assert "f64" not in ir and "custom_call" not in ir
     # Round-trips through JSON (the check_static report artifact).
     json.dumps(report.to_json())
+
+
+def test_audit_records_kernel_plans(small_system):
+    session = small_system.compile(RuntimeSpec(
+        backend="pallas", metering="fused", batch_sizes=(8,), capacity=8))
+    report = session.audit()
+    assert set(report.plans) == set(report.fingerprints)
+    plan = report.plans["infer_step@8"]
+    assert plan == dataclasses.asdict(session.kernel_plan("infer_step", 8))
+    assert plan["variant"] == "fused_impact_metered"
+    assert plan["row_shards"] == plan["literal_chunks"] == 1
+    assert plan["vmem_step_bytes"] == report.vmem_bytes["infer_step@8"]
+    assert report.to_json()["plans"] == report.plans
+    oracle = small_system.compile(RuntimeSpec(backend="xla",
+                                              batch_sizes=(8,)))
+    assert oracle.kernel_plan("predict", 8) is None
+    assert oracle.audit().plans == {}
+
+
+def test_compile_refuses_over_budget_packed_spec(small_system):
+    """The packed kernel holds every row-shard in one block; a spec
+    whose packed working set exceeds its budget is refused at compile,
+    before the TPU compiler could refuse its first executable.  The
+    unpacked kernel at the same budget compiles, and the audit flags it
+    (``test_vmem_busting_spec_is_flagged``)."""
+    with pytest.raises(ValueError, match="packing='2bit'.*over the budget"):
+        small_system.compile(RuntimeSpec(
+            backend="pallas-packed", packing="2bit",
+            vmem_budget_bytes=4096))
+    small_system.compile(RuntimeSpec(backend="pallas-packed",
+                                     packing="2bit"))
+    small_system.compile(RuntimeSpec(backend="pallas",
+                                     vmem_budget_bytes=4096))
 
 
 def test_vmem_busting_spec_is_flagged(small_system):

@@ -22,14 +22,17 @@ from repro.impact.yflash import I_CSA_THRESHOLD, read_current
 from repro.kernels import backends, ops, ref
 
 # (B, K, n, M, R, tr, C, tc, S, sr) — mix of single-tile, R>1/S>1 shard
-# splits, ragged (non-multiple-of-block) shapes, and unequal clause-axis
-# paddings between the clause tile (C*tc) and class tile (S*sr).
+# splits, ragged (non-multiple-of-block) shapes, unequal clause-axis
+# paddings between the clause tile (C*tc) and class tile (S*sr), and the
+# text CoTM's shape (R=5, C=3, S=3) on 128x128 tiles, where the kernel
+# ANDs five shards' CSA bits across its row-shard grid axis.
 SHARD_SHAPES = [
     (4, 100, 50, 10, 1, 128, 1, 64, 1, 64),
     (37, 300, 77, 3, 2, 150, 3, 30, 5, 16),       # R>1, S>1, ragged
     (8, 520, 500, 10, 3, 200, 2, 256, 1, 2048),   # class pad >> clause pad
     (1, 1568, 500, 10, 1, 2048, 1, 512, 1, 2048), # paper MNIST layout
     (16, 64, 33, 4, 2, 32, 3, 11, 4, 9),          # tiny ragged everything
+    (24, 600, 300, 2, 5, 128, 3, 128, 3, 128),    # R=5 shard grid axis
 ]
 
 

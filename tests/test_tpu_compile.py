@@ -7,8 +7,9 @@ Here each kernel is lowered with ``interpret=False`` and compiled for a
 *described* v5e chip — libtpu compiles for it without one attached — at
 the operand shapes the MNIST paper configuration (K=1568 literals,
 n=500 clauses, m=10 classes on the default 2048x512 tile) produces after
-the backends' neutral padding.  Nothing runs, so these tests say nothing
-about results or times.
+the backends' neutral padding, and the fused kernels also at the
+10,000-literal text CoTM's (K=n=10000, m=2: five row-shards).  Nothing
+runs, so these tests say nothing about results or times.
 
 Only one process at a time may load libtpu, and the test workers import
 every test file: so the topology is described inside a module fixture
@@ -96,6 +97,44 @@ def test_fused_impact_compiles_for_v5e(shape, batch, metered):
         lit, clause_i, nonempty, class_i)
     ws = vmem.fused_working_set(R=1, tr=TILE_ROWS, n_clause=TILE_COLS,
                                 class_rows=CLASS_ROWS, M=M, metered=metered)
+    assert ws.total_bytes <= vmem.DEFAULT_VMEM_BUDGET_BYTES, ws
+
+
+# The 10,000-literal text CoTM (bench/configs/imdb-cotm.json) on the same
+# tiles: R=5 literal row-shards, C=20 clause column tiles, S=5 class shards.
+IMDB_K, IMDB_N, IMDB_M, IMDB_R, IMDB_C, IMDB_S = 10000, 10000, 2, 5, 20, 5
+
+
+@pytest.mark.parametrize("batch", [128, 512])
+@pytest.mark.parametrize("metered", [False, True],
+                         ids=["fused_impact", "fused_impact_metered"])
+def test_fused_impact_compiles_for_v5e_at_imdb_widths(shape, batch,
+                                                      metered):
+    """Each grid step holds one 2048-row shard, so five shards compile
+    within the default VMEM budget (all five in one block did not: 20-30
+    MiB of scoped VMEM against 16)."""
+    lit = shape((batch, IMDB_K), jnp.int8)
+    clause_i = shape((IMDB_R, IMDB_C, TILE_ROWS, TILE_COLS), jnp.float32)
+    nonempty = shape((IMDB_C * TILE_COLS,), jnp.bool_)
+    class_i = shape((IMDB_S, CLASS_ROWS, IMDB_M), jnp.float32)
+    bk = backends.get_backend("pallas")
+    ops_ = jax.eval_shape(
+        lambda *a: bk._fused_impact_operands(*a, block_b=128,
+                                             block_n=256)[:4],
+        lit, clause_i, nonempty, class_i)
+    assert [o.shape for o in ops_] == [(5, batch, 2048), (5, 2048, 10240),
+                                       (1, 10240), (10240, 128)]
+    entry = bk.fused_impact_metered if metered else bk.fused_impact
+    text = _compile_for_chip(
+        lambda *a: entry(*a, thresh=I_CSA_THRESHOLD, interpret=False),
+        lit, clause_i, nonempty, class_i)
+    # The kernel's stable name, which the device trace reports.
+    name = "fused_impact_metered" if metered else "fused_impact"
+    assert f"%{name}." in text
+    ws = vmem.fused_working_set(R=IMDB_R, tr=TILE_ROWS,
+                                n_clause=IMDB_C * TILE_COLS,
+                                class_rows=IMDB_S * CLASS_ROWS, M=IMDB_M,
+                                metered=metered)
     assert ws.total_bytes <= vmem.DEFAULT_VMEM_BUDGET_BYTES, ws
 
 

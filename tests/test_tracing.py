@@ -211,6 +211,35 @@ def test_scheduler_cycle_spans_tile_and_nest(small_system):
             assert _inside(span, sweeps), name
 
 
+def test_sweep_span_carries_kernel_plan():
+    """On a kernel backend each ``sweep`` span carries the session's
+    kernel plan: the literal row-shards (3 here, 48 literals on 16-row
+    tiles) and the kernel's VMEM bytes per grid step."""
+    K, n, m, n_states = 48, 20, 3, 64
+    rng = np.random.default_rng(5)
+    ta = np.where(rng.random((K, n)) < 0.1, n_states + 1, n_states)
+    params = CoTMParams(ta_state=jnp.asarray(ta, jnp.int32),
+                        weights=jnp.asarray(rng.integers(-9, 9, (m, n)),
+                                            jnp.int32))
+    system = build_system(
+        params, CoTMConfig(n_literals=K, n_clauses=n, n_classes=m,
+                           n_states=n_states),
+        jax.random.key(0),
+        IMPACTConfig(max_tile_rows=16, variability=False, finetune=False))
+    session = system.compile(RuntimeSpec(backend="pallas",
+                                         metering="fused", capacity=8))
+    plan = session.kernel_plan("infer_step", 8)
+    assert (plan.row_shards, plan.literal_chunks) == (3, 3)
+    tr = Tracer()
+    eng = IMPACTEngine(session, trace=tr)
+    eng.run(rng.random((12, K)) < 0.5)
+    sweeps = _spans(tr.to_json(), pid=PID_ENGINE, tid=0, name="sweep")
+    assert len(sweeps) == len(eng.batch_stats) >= 2
+    for *_, args in sweeps:
+        assert args["row_shards"] == 3
+        assert args["vmem_step_bytes"] == plan.vmem_step_bytes > 0
+
+
 def test_detached_tracer_records_nothing(small_system):
     """With ``trace=None`` the scheduler records no span: a tracer that
     was attached and then detached gains no event from a burst."""
