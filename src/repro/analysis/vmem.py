@@ -6,7 +6,8 @@ exceed it OOMs at compile time *on the TPU* — which CPU CI, running the
 same kernels in interpret mode, can never see.  This module prices the
 working set STATICALLY, by mirroring the exact padding/tiling math of
 ``kernels.backends`` (``_fused_impact_operands`` /
-``_fused_impact_packed_operands``) and the BlockSpecs of
+``_fused_impact_packed_operands``, ``fused_impact.column_tiling``) and
+the BlockSpecs of
 ``kernels.fused_impact`` / ``kernels.crossbar_mvm``, so a block-shape or
 grid-geometry change that blows VMEM fails the IR-audit gate before any
 TPU exists to OOM (the static half of the ROADMAP's autotuning item).
@@ -34,7 +35,8 @@ from ..kernels.crossbar_mvm import (BLOCK_B as _MVM_BLOCK_B,
                                     BLOCK_N as _MVM_BLOCK_N)
 from ..kernels.fused_impact import (BLOCK_B as _FUSED_BLOCK_B,
                                     BLOCK_N as _FUSED_BLOCK_N,
-                                    METER_LANES as _METER_LANES)
+                                    METER_LANES as _METER_LANES,
+                                    column_tiling as _column_tiling)
 
 #: ~VMEM per TensorCore on current TPUs (v4/v5e: 16 MiB; v5p: ~32).
 DEFAULT_VMEM_BUDGET_BYTES = 16 * 1024 * 1024
@@ -74,19 +76,18 @@ class WorkingSet:
                 + sum(self.scratch.values()))
 
 
-def fused_working_set(*, R: int, tr: int, n_clause: int, class_rows: int,
-                      M: int, metered: bool,
-                      block_b: int | None = None,
+def fused_working_set(*, R: int, C: int, tr: int, tc: int, M: int,
+                      metered: bool, block_b: int | None = None,
                       block_n: int | None = None) -> WorkingSet:
-    """Working set of the fused IMPACT kernel (unpacked f32 operands),
-    mirroring ``PallasBackend._fused_impact_operands`` padding.  A grid
-    step holds one literal row-shard, so the bytes are the same for any
-    ``R``; ``R`` is the extent of the kernel's shard axis."""
+    """Working set of the fused IMPACT kernel (unpacked f32 operands) on
+    an (R, C, tr, tc) clause grid, laid out by ``column_tiling``.  A grid
+    step holds one literal row-shard's block of one clause tile, so the
+    bytes are the same for any ``R`` and ``C``; the grid walks ``R``
+    shards and the grid's C*tc columns.  VMEM holds a block in (8, 128)
+    tiles, so an unaligned ``tr`` is priced at its 128-padded size."""
     block_b = block_b or _FUSED_BLOCK_B
-    block_n = block_n or _FUSED_BLOCK_N
-    N = max(n_clause, class_rows)
-    block_n = min(block_n, max(128, _ceil_to(N, 128)))
-    tr_pad = max(128, _ceil_to(tr, 128))
+    C, tc, block_n = _column_tiling(C, tc, block_n or _FUSED_BLOCK_N)
+    tr_pad = _ceil_to(tr, 128)
     m_pad = _ceil_to(M, 128)
     blocks = {
         "drive": block_b * tr_pad * _F32,
@@ -102,7 +103,7 @@ def fused_working_set(*, R: int, tr: int, n_clause: int, class_rows: int,
         scratch["macc"] = block_b * _METER_LANES * _F32
     return WorkingSet("fused_impact_metered" if metered else "fused_impact",
                       blocks, scratch, literal_chunks=R,
-                      column_blocks=_ceil_to(N, block_n) // block_n)
+                      column_blocks=C * (tc // block_n))
 
 
 def packed_working_set(*, R: int, tr4: int, n_clause: int, class_rows: int,
@@ -240,8 +241,8 @@ def session_working_set(session, entry: str,
         return packed_working_set(R=R, tr4=tr4, n_clause=n_clause,
                                   class_rows=S * sr, M=M,
                                   metered=metered_kernel)
-    return fused_working_set(R=R, tr=tr, n_clause=n_clause,
-                             class_rows=S * sr, M=M, metered=metered_kernel)
+    return fused_working_set(R=R, C=C, tr=tr, tc=tc, M=M,
+                             metered=metered_kernel)
 
 
 @dataclasses.dataclass(frozen=True)

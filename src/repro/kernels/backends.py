@@ -390,38 +390,37 @@ class PallasBackend(Backend):
 
     def _fused_impact_operands(self, literals, clause_i, nonempty, class_i,
                                *, block_b, block_n):
-        """Shared neutral-padding plumbing of the fused IMPACT kernels:
-        -> (drive, ccur, ne, wcur, block_n) in the kernel layouts, with
-        padded rows/columns contributing exactly zero current (floating
-        'Z' literal rows, nonempty=0 clause columns, 0 A class cells) —
-        which is what makes the in-kernel meters exact."""
+        """Operands of the fused IMPACT kernels: -> (drive, ccur, ne,
+        wcur, block_n) in the kernel layouts.  A grid of whole-block
+        tiles goes in as programmed, (R, C, tr, tc), and the kernel walks
+        its C*tc columns only.  Padded batch rows drive 0 V, and padded
+        columns and class lanes hold 0 A, which keeps the in-kernel
+        meters exact."""
         B, K = literals.shape
         R, C, tr, tc = clause_i.shape
         S, sr, M = class_i.shape
-        n_clause = C * tc
-
-        # Unify the clause-column axis of both crossbars: the clause tile
-        # pads n to C*tc, the class tile to S*sr; dead columns (>= n)
-        # fire 0.
-        N = max(n_clause, S * sr)
-        block_n = min(block_n, max(128, -(-N // 128) * 128))
-        tr_pad = max(128, -(-tr // 128) * 128)
+        Ck, tck, block_n = _impact_kernel.column_tiling(C, tc, block_n)
 
         lit = pad_axis(literals.astype(jnp.float32), R * tr, 1, 1)
         drive = (1.0 - lit).reshape(B, R, tr).transpose(1, 0, 2)
-        drive = pad_axis(pad_axis(drive, block_b, 1, 0.0), tr_pad, 2, 0.0)
+        drive = pad_axis(drive, block_b, 1, 0.0)
 
-        ccur = clause_i.astype(jnp.float32).transpose(0, 2, 1, 3)
-        ccur = ccur.reshape(R, tr, n_clause)
-        ccur = pad_axis(pad_axis(ccur, tr_pad, 1, 0.0), block_n, 2, 0.0)
-        if N > n_clause:
-            ccur = pad_axis(ccur, -(-N // block_n) * block_n, 2, 0.0)
+        ccur = clause_i.astype(jnp.float32)
+        ne = nonempty.astype(jnp.int8)
+        if (Ck, tck) != (C, tc):
+            # Tiles that are not a whole number of blocks wide: their
+            # columns end to end, as one tile padded to whole blocks.
+            ccur = ccur.transpose(0, 2, 1, 3).reshape(R, 1, tr, C * tc)
+            ccur = pad_axis(ccur, tck, 3, 0.0)
+            ne = pad_axis(ne, tck, 0, 0)
+        ne = ne.reshape(Ck, 1, tck)
 
-        ne = pad_axis(nonempty.astype(jnp.int8)[None, :],
-                      -(-N // block_n) * block_n, 1, 0)
-
+        # Class row j holds clause column j's weights.  Rows past the
+        # clause grid (S*sr > C*tc) are driven by no clause; clause
+        # columns past the class grid (S*sr < C*tc) drive 0 A rows.
         wcur = class_i.astype(jnp.float32).reshape(S * sr, M)
-        wcur = pad_axis(pad_axis(wcur, ne.shape[1], 0, 0.0), 128, 1, 0.0)
+        wcur = ref.pad_to(wcur, Ck * tck, 0)[:Ck * tck].reshape(Ck, tck, M)
+        wcur = pad_axis(wcur, 128, 2, 0.0)
         return drive, ccur, ne, wcur, block_n
 
     def fused_impact(self, literals, clause_i, nonempty, class_i, *,

@@ -21,15 +21,25 @@ the clause-column axis, so the per-shard ADC + digital add is subsumed by
 the column-block accumulation (exact: the class read is linear in the
 drive).
 
-Layouts (prepared by ``ops.fused_impact``):
-  drive   (R, B, tr)   f32   1 - literal, row-shard major; padding rows 0
-  ccur    (R, tr, N)   f32   clause-cell read currents, columns flattened
-  ne      (1, N)       int8  digital empty-clause mask
-  wcur    (N, M)       f32   class-cell read currents, S shards flattened
-  out     (B, M)       f32   class column currents (argmax = prediction)
+Layouts (prepared by ``PallasBackend._fused_impact_operands``):
+  drive   (R, B, tr)      f32   1 - literal, row-shard major; padding rows 0
+  ccur    (R, C, tr, tc)  f32   clause-cell read currents: the programmed
+                                grid itself, read where it lies (see
+                                ``column_tiling`` for narrow tiles)
+  ne      (C, 1, tc)      int8  digital empty-clause mask, tile-major
+  wcur    (C, tc, M)      f32   class-cell read currents of each clause
+                                column, tile-major
+  out     (B, M)          f32   class column currents (argmax = prediction)
+
+The column axis walks the clause grid's C*tc columns and no further:
+column block n is block ``n % (tc // block_n)`` of clause tile
+``n // (tc // block_n)``, so a grid of whole-block tiles is never
+transposed or padded for the kernel (``column_tiling``).  Class rows
+past the clause grid (a class crossbar wider than C*tc) are driven by no
+clause and are never read.
 
 Each grid step holds ONE row-shard's (block_b, tr) drive and (tr, block_n)
-currents, so VMEM per step is the same for any R and any N: a CoTM of any
+currents, so VMEM per step is the same for any R and any C: a CoTM of any
 literal count runs on the paper's 2048-row tiles.  The shard axis is the
 innermost: at R=1 every block index but ccur's stays put across the
 column axis, so the pipeline fetches the drive once per batch block; at
@@ -57,6 +67,22 @@ Array = jax.Array
 
 BLOCK_B = 128
 BLOCK_N = 256
+
+
+def column_tiling(C: int, tc: int,
+                  block_n: int = BLOCK_N) -> tuple[int, int, int]:
+    """How the fused kernel walks a grid of C clause tiles of ``tc``
+    columns: -> (tiles, tile width, column block width).  The block is
+    ``block_n`` wide, or 128 where the grid has at most 128 columns.
+    Tiles a whole number of blocks wide are read in place; others (test
+    sized grids) are laid end to end as one tile padded to whole blocks.
+    Either way the class read sums the clause columns in the same
+    block-wide chunks, whatever the tile split."""
+    n = C * tc
+    bn = min(block_n, max(128, -(-n // 128) * 128))
+    if tc % bn == 0:
+        return C, tc, bn
+    return 1, -(-n // bn) * bn, bn
 
 
 def _dot_f32(a, b):
@@ -107,11 +133,11 @@ def _fused_impact_kernel(drive_ref, ccur_ref, ne_ref, wcur_ref, *refs,
         if metered:
             macc_ref[...] = jnp.zeros_like(macc_ref)
 
-    i_col = _dot_f32(drive_ref[0], ccur_ref[0])   # Kirchhoff column sums
+    i_col = _dot_f32(drive_ref[0], ccur_ref[0, 0])   # Kirchhoff column sums
 
     @pl.when(r == 0)
     def _first():
-        ne = jnp.broadcast_to(ne_ref[...] != 0, i_col.shape)
+        ne = jnp.broadcast_to(ne_ref[0] != 0, i_col.shape)
         fired_ref[...] = (ne & (i_col < thresh)).astype(jnp.float32)
 
     @pl.when(r > 0)
@@ -121,13 +147,13 @@ def _fused_impact_kernel(drive_ref, ccur_ref, ne_ref, wcur_ref, *refs,
     if metered:
         # Every meter lane accumulates the same per-lane clause current
         # (a plain VPU broadcast-add — no per-step lane select); the
-        # epilogue picks METER_LANE_CLAUSE.  Padded rows/columns carry
-        # 0 A by the wrapper's neutral padding, so they add exactly zero.
+        # epilogue picks METER_LANE_CLAUSE.  Padded batch rows (0 V drive)
+        # and padded columns (0 A cells) add exactly zero.
         macc_ref[...] += i_col.sum(axis=1, keepdims=True)
 
     @pl.when(r == n_r - 1)
     def _class():
-        acc_ref[...] += _dot_f32(fired_ref[...], wcur_ref[...])
+        acc_ref[...] += _dot_f32(fired_ref[...], wcur_ref[0])
 
     @pl.when(jnp.logical_and(n == n_n - 1, r == n_r - 1))
     def _epilogue():
@@ -143,14 +169,17 @@ def _fused_impact_kernel(drive_ref, ccur_ref, ne_ref, wcur_ref, *refs,
 def _shard_grid_call(drive, ccur, nonempty, wcur, *, thresh, block_b,
                      block_n, interpret, metered):
     """The ``pallas_call`` of both unpacked kernels on the (batch block,
-    column block, literal row-shard) grid; -> a list of outputs."""
+    column block, literal row-shard) grid; -> a list of outputs.  Column
+    block n reads block ``n % nb`` of clause tile ``n // nb`` in place."""
     R, B, tr = drive.shape
-    R2, tr2, N = ccur.shape
-    N2, M = wcur.shape
-    assert R == R2 and tr == tr2 and N == N2 and nonempty.shape == (1, N)
-    assert (B % block_b == 0 and N % block_n == 0 and tr % 128 == 0
-            and M % 128 == 0), (B, R, tr, N, M)
-    n_n = N // block_n
+    R2, C, tr2, tc = ccur.shape
+    C2, tc2, M = wcur.shape
+    assert (R == R2 and tr == tr2 and (C, tc) == (C2, tc2)
+            and nonempty.shape == (C, 1, tc)), (drive.shape, ccur.shape)
+    assert (B % block_b == 0 and tc % block_n == 0
+            and M % 128 == 0), (B, R, tr, C, tc, M)
+    nb = tc // block_n
+    n_n = C * nb
     lanes = [M, METER_LANES] if metered else [M]
     scratch = [pltpu.VMEM((block_b, M), jnp.float32),        # class currents
                pltpu.VMEM((block_b, block_n), jnp.float32)]  # AND-ed bits
@@ -162,9 +191,12 @@ def _shard_grid_call(drive, ccur, nonempty, wcur, *, thresh, block_b,
         grid=(B // block_b, n_n, R),
         in_specs=[
             pl.BlockSpec((1, block_b, tr), lambda b, n, r: (r, b, 0)),
-            pl.BlockSpec((1, tr, block_n), lambda b, n, r: (r, 0, n)),
-            pl.BlockSpec((1, block_n), lambda b, n, r: (0, n)),
-            pl.BlockSpec((block_n, M), lambda b, n, r: (n, 0)),
+            pl.BlockSpec((1, 1, tr, block_n),
+                         lambda b, n, r: (r, n // nb, 0, n % nb)),
+            pl.BlockSpec((1, 1, block_n),
+                         lambda b, n, r: (n // nb, 0, n % nb)),
+            pl.BlockSpec((1, block_n, M),
+                         lambda b, n, r: (n // nb, n % nb, 0)),
         ],
         out_specs=[pl.BlockSpec((block_b, m), lambda b, n, r: (b, 0))
                    for m in lanes],
@@ -183,11 +215,12 @@ def _shard_grid_call(drive, ccur, nonempty, wcur, *, thresh, block_b,
 def fused_impact(drive: Array, ccur: Array, nonempty: Array, wcur: Array, *,
                  thresh: float, block_b: int = BLOCK_B,
                  block_n: int = BLOCK_N, interpret: bool = False) -> Array:
-    """drive (R, B, tr) f32, ccur (R, tr, N) f32, nonempty (1, N) int8,
-    wcur (N, M) f32 -> class currents (B, M) f32.
+    """drive (R, B, tr) f32, ccur (R, C, tr, tc) f32, nonempty (C, 1, tc)
+    int8, wcur (C, tc, M) f32 -> class currents (B, M) f32.
 
-    B % block_b == 0, N % block_n == 0, tr % 128 == 0, M % 128 == 0 required
-    (``ops.fused_impact`` pads arbitrary shapes and shard layouts).
+    B % block_b == 0, tc % block_n == 0 and M % 128 == 0 required
+    (``PallasBackend`` pads the batch and the class lanes, and lays the
+    grid out by ``column_tiling``).
     """
     return _shard_grid_call(drive, ccur, nonempty, wcur, thresh=thresh,
                             block_b=block_b, block_n=block_n,
@@ -230,7 +263,9 @@ def fused_impact_metered(drive: Array, ccur: Array, nonempty: Array,
 #                                 literal row 4q+j of shard r; pad rows 0
 #   pbits   (R, tr4, N)     uint8 packed codes, columns flattened
 #   levels  (1, 128)        f32   [i_lcs, i_hcs] in lanes 0/1 (VREG row)
-#   ne / wcur / out               as in the unpacked kernel
+#   ne      (1, N)          int8  digital empty-clause mask, flattened
+#   wcur    (N, M)          f32   class-cell read currents, S shards flattened
+#   out     (B, M)          f32   as in the unpacked kernel
 #
 # Column current = sum_j drive_p[r, j] @ dequant(plane_j), identical MACs
 # to the unpacked kernel but ~4x fewer clause bytes through HBM/VMEM

@@ -182,8 +182,9 @@ class IMPACTEngine:
     ``fetch``) / ``billing`` / ``release`` regions on the scheduler track
     (lane ids and occupancy as span args; on ``sweep`` its count of
     device->host ``fetches`` and, from the session's kernel plan, its
-    ``row_shards`` and ``vmem_step_bytes``), mirrored as profiler
-    annotations while they run, and the ``queued`` -> ``admitted`` ->
+    ``row_shards``, ``column_blocks`` and ``vmem_step_bytes``), mirrored
+    as profiler annotations while they run, and the ``queued`` ->
+    ``admitted`` ->
     ``sweep`` -> ``billed`` lifecycle on one track per request, cut from
     the same clock readings the ``RequestRecord`` ledger stores.  The
     tracer is re-clocked onto the engine's clock so an injected virtual

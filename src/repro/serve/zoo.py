@@ -503,6 +503,7 @@ class ModelZoo:
             plan = session.kernel_plan("infer_step", shape)
             if plan is not None:
                 args.update(row_shards=plan.row_shards,
+                            column_blocks=plan.column_blocks,
                             vmem_step_bytes=plan.vmem_step_bytes)
             tr.begin("sweep", ts=t0, args=args)
             tr.begin("dispatch", ts=t0)

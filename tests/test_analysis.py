@@ -241,10 +241,8 @@ def test_f64_widened_toy_executable_is_flagged():
 # -- the VMEM estimator ------------------------------------------------------
 
 def test_vmem_estimates_are_positive_and_ordered():
-    ws = vmem.fused_working_set(R=1, tr=64, n_clause=32, class_rows=32,
-                                M=4, metered=False)
-    wm = vmem.fused_working_set(R=1, tr=64, n_clause=32, class_rows=32,
-                                M=4, metered=True)
+    ws = vmem.fused_working_set(R=1, C=1, tr=64, tc=32, M=4, metered=False)
+    wm = vmem.fused_working_set(R=1, C=1, tr=64, tc=32, M=4, metered=True)
     assert 0 < ws.total_bytes < vmem.DEFAULT_VMEM_BUDGET_BYTES
     assert wm.total_bytes > ws.total_bytes          # meters cost VMEM
     assert wm.variant == "fused_impact_metered"
@@ -252,8 +250,8 @@ def test_vmem_estimates_are_positive_and_ordered():
     # f32 one (the 1-byte pbits block replaces the 4-byte ccur block); at
     # tiny padded shapes the 4-bitplane drive dominates, so compare at a
     # full 512-row shard (tr4 = 512/4 = 128).
-    big = vmem.fused_working_set(R=1, tr=512, n_clause=512, class_rows=512,
-                                 M=4, metered=False)
+    big = vmem.fused_working_set(R=1, C=1, tr=512, tc=512, M=4,
+                                 metered=False)
     packed = vmem.packed_working_set(R=1, tr4=128, n_clause=512,
                                      class_rows=512, M=4, metered=False)
     assert packed.total_bytes < big.total_bytes     # 2-bit beats f32
@@ -264,11 +262,10 @@ def test_vmem_estimates_are_positive_and_ordered():
 @pytest.mark.parametrize("metered", [False, True])
 def test_fused_working_set_is_independent_of_row_shards(metered):
     """A grid step of the fused kernel holds one literal row-shard: the
-    10,000-literal text CoTM (R=5 shards of 2048 rows, 10,240 clause
-    columns, 2 classes) prices the same per step as one shard, within
-    the default budget; only the grid's shard extent grows."""
-    dims = dict(tr=2048, n_clause=10240, class_rows=10240, M=2,
-                metered=metered)
+    10,000-literal text CoTM (R=5 shards of 2048 rows, 20 clause tiles
+    of 512 columns, 2 classes) prices the same per step as one shard,
+    within the default budget; only the grid's shard extent grows."""
+    dims = dict(C=20, tr=2048, tc=512, M=2, metered=metered)
     one = vmem.fused_working_set(R=1, **dims)
     five = vmem.fused_working_set(R=5, **dims)
     assert one.total_bytes == five.total_bytes
@@ -280,6 +277,26 @@ def test_fused_working_set_is_independent_of_row_shards(metered):
                                       class_rows=10240, M=2,
                                       metered=metered) for r in (1, 5))
     assert p5.total_bytes > p1.total_bytes and p5.literal_chunks == 1
+
+
+@pytest.mark.parametrize("C,tc,tiling,blocks", [
+    (1, 512, (1, 512, 256), 2),      # paper MNIST: one 2048x512 tile
+    (2, 512, (2, 512, 256), 4),      # CIFAR-2: two tiles
+    (20, 512, (20, 512, 256), 40),   # the 10,000-clause text CoTM
+    (1, 128, (1, 128, 128), 1),      # at most 128 columns: 128-wide
+    (3, 384, (1, 1280, 256), 5),     # not whole blocks: end to end
+    (4, 16, (1, 128, 128), 1),       # narrow test tiles: end to end
+])
+def test_fused_column_blocks_walk_the_clause_grid(C, tc, tiling, blocks):
+    """The fused kernel's column axis spans the clause grid's C*tc
+    columns, however wide the class grid is; tiles a whole number of
+    blocks wide are read in place, others are laid end to end."""
+    from repro.kernels.fused_impact import column_tiling
+    assert column_tiling(C, tc) == tiling
+    ws = vmem.fused_working_set(R=1, C=C, tr=2048, tc=tc, M=10,
+                                metered=True)
+    assert ws.column_blocks == blocks
+    assert ws.total_bytes <= vmem.DEFAULT_VMEM_BUDGET_BYTES
 
 
 # -- session-level audit -----------------------------------------------------
@@ -321,6 +338,9 @@ def test_audit_records_kernel_plans(small_system):
     assert plan == dataclasses.asdict(session.kernel_plan("infer_step", 8))
     assert plan["variant"] == "fused_impact_metered"
     assert plan["row_shards"] == plan["literal_chunks"] == 1
+    # The clause grid's 512 columns in 256-wide blocks; the class grid's
+    # 2048 rows do not widen the walk.
+    assert plan["column_blocks"] == 2
     assert plan["vmem_step_bytes"] == report.vmem_bytes["infer_step@8"]
     assert report.to_json()["plans"] == report.plans
     oracle = small_system.compile(RuntimeSpec(backend="xla",

@@ -25,7 +25,11 @@ from repro.kernels import backends, ops, ref
 # splits, ragged (non-multiple-of-block) shapes, unequal clause-axis
 # paddings between the clause tile (C*tc) and class tile (S*sr), and the
 # text CoTM's shape (R=5, C=3, S=3) on 128x128 tiles, where the kernel
-# ANDs five shards' CSA bits across its row-shard grid axis.
+# ANDs five shards' CSA bits across its row-shard grid axis.  The fused
+# kernel reads a grid of whole-block tiles in place: column block n is
+# block n % (tc // block_n) of tile n // (tc // block_n); the last three
+# shapes give tiles two blocks each, with the class grid wider and
+# narrower than C*tc, and 384-wide tiles, which are laid end to end.
 SHARD_SHAPES = [
     (4, 100, 50, 10, 1, 128, 1, 64, 1, 64),
     (37, 300, 77, 3, 2, 150, 3, 30, 5, 16),       # R>1, S>1, ragged
@@ -33,6 +37,9 @@ SHARD_SHAPES = [
     (1, 1568, 500, 10, 1, 2048, 1, 512, 1, 2048), # paper MNIST layout
     (16, 64, 33, 4, 2, 32, 3, 11, 4, 9),          # tiny ragged everything
     (24, 600, 300, 2, 5, 128, 3, 128, 3, 128),    # R=5 shard grid axis
+    (16, 1000, 900, 3, 2, 512, 2, 512, 1, 2048),  # 2 blocks/tile, R, C > 1
+    (8, 700, 900, 3, 3, 256, 2, 512, 3, 320),     # 2 blocks/tile, S*sr<C*tc
+    (8, 200, 700, 3, 1, 256, 2, 384, 1, 704),     # 384-wide: end to end
 ]
 
 
@@ -199,6 +206,44 @@ def test_metered_backend_scores_identical_to_unmetered():
                                     impl="pallas-metered")),
         np.asarray(ops.fused_impact(*args, thresh=I_CSA_THRESHOLD,
                                     impl="pallas")))
+
+
+def test_served_predict_reads_the_grid_the_trainer_wrote():
+    """The kernel reads the programmed grid where it lies, so no copy of
+    it can go stale: after ``OnlineTrainer.update`` re-programs cells,
+    the session compiled before the update serves what the oracle reads
+    off the mutated grid."""
+    from repro.train import OnlineTrainer
+    cfg = CoTMConfig(n_literals=64, n_clauses=40, n_classes=4,
+                     n_states=64, threshold=16, specificity=4.0)
+    x, y = prototype(320, n_classes=4, n_features=32, flip=0.05, seed=3)
+    lits = jnp.asarray(np.concatenate([x, 1 - x], -1).astype(bool))
+    labels = jnp.asarray(y)
+    params = train_epochs(cfg.init(jax.random.key(0)), lits[:128],
+                          labels[:128], jax.random.key(1), cfg, epochs=1,
+                          batch_size=64)
+    system = build_system(params, cfg, jax.random.key(2),
+                          IMPACTConfig(variability=False, finetune=False))
+    session = system.compile(RuntimeSpec(backend="pallas",
+                                         batch_sizes=(64,)))
+    probe = lits[256:]
+    before = np.asarray(session.predict(probe).scores)
+    grid = np.asarray(system.clause_i)
+    trainer = OnlineTrainer(session, params, cfg, key=jax.random.key(3),
+                            variability=False)
+    for step in range(2):
+        trainer.update(lits[128 + 64 * step:192 + 64 * step],
+                       labels[128 + 64 * step:192 + 64 * step])
+    assert not np.array_equal(np.asarray(system.clause_i), grid)
+    got = session.predict(probe)
+    want = ref.fused_impact_ref(probe, system.clause_i,
+                                system._nonempty_eff(), system.class_i,
+                                thresh=I_CSA_THRESHOLD)
+    np.testing.assert_allclose(np.asarray(got.scores), np.asarray(want),
+                               rtol=1e-6)
+    np.testing.assert_array_equal(np.asarray(got.predictions),
+                                  np.asarray(jnp.argmax(got.scores, -1)))
+    assert not np.array_equal(np.asarray(got.scores), before)
 
 
 def test_all_empty_clause_columns():

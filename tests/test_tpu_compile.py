@@ -8,13 +8,17 @@ Here each kernel is lowered with ``interpret=False`` and compiled for a
 the operand shapes the MNIST paper configuration (K=1568 literals,
 n=500 clauses, m=10 classes on the default 2048x512 tile) produces after
 the backends' neutral padding, and the fused kernels also at the
-10,000-literal text CoTM's (K=n=10000, m=2: five row-shards).  Nothing
-runs, so these tests say nothing about results or times.
+10,000-literal text CoTM's (K=n=10000, m=2: five row-shards).  For the
+fused kernels the compiled executable is also read: the programmed
+clause grid must reach the kernel as it lies, with no relayout on the
+way.  Nothing runs, so these tests say nothing about results or times.
 
 Only one process at a time may load libtpu, and the test workers import
 every test file: so the topology is described inside a module fixture
 (never at import), and all of these compiles live in this one file.
 """
+import re
+
 import jax
 import jax.numpy as jnp
 import pytest
@@ -68,6 +72,39 @@ def _compile_for_chip(fn, *args):
     return text
 
 
+def _clause_grid_reaches_kernel(text, dims):
+    """Assert that the compiled executable hands its f32 clause-grid
+    parameter of shape ``dims`` to the Mosaic kernel as it lies.  Every
+    instruction that reads it is the kernel's custom call, a bitcast
+    (same bytes), or a copy that keeps the tiled layout and at most moves
+    the grid to another memory space (XLA stages a grid that fits into
+    VMEM), so no transpose, relayout copy, pad or fusion of it runs."""
+    entry = text[text.index("\nENTRY "):]
+    shape = re.escape("f32[" + ",".join(map(str, dims)) + "]")
+    ((param, layout),) = re.findall(
+        r"(%\S+) = (" + shape + r"\{[^}]*\}) parameter\(", entry)
+    lines = [ln.split(" = ", 1) for ln in entry.splitlines() if " = " in ln]
+    space = re.compile(r"S\(\d+\)")
+
+    def readers(name):
+        use = re.compile(re.escape(name) + r"[,)]")
+        return [(lhs.split()[-1], rhs) for lhs, rhs in lines
+                if use.search(rhs)]
+
+    kernels, todo = 0, readers(param)
+    while todo:
+        name, rhs = todo.pop()
+        if 'custom_call_target="tpu_custom_call"' in rhs:
+            kernels += 1
+            continue
+        moved = re.match(r"\(?(\S+?\})[,)]?.* copy(-start|-done)?\(", rhs)
+        assert (re.match(r"\S+ bitcast\(", rhs) or moved
+                and space.sub("", moved.group(1)) == space.sub("", layout)), (
+            f"the clause grid is relaid out before the kernel: {rhs}")
+        todo += readers(name)
+    assert kernels >= 1, "the clause grid never reaches the kernel"
+
+
 def _system_operands(shape, batch):
     """The session's operands at paper dims, as shapes on one chip."""
     return (shape((batch, K), jnp.int8),
@@ -82,21 +119,15 @@ def _system_operands(shape, batch):
 def test_fused_impact_compiles_for_v5e(shape, batch, metered):
     lit, clause_i, nonempty, class_i = _system_operands(shape, batch)
     bk = backends.get_backend("pallas")
-    # The kernel operands the backend hands Mosaic at these dims: the
-    # clause-column axis unifies to N = max(512, 2048) = 2048.
-    ops_ = jax.eval_shape(
-        lambda *a: bk._fused_impact_operands(*a, block_b=128,
-                                             block_n=256)[:4],
-        lit, clause_i, nonempty, class_i)
-    assert [o.shape for o in ops_] == [(1, batch, 2048), (1, 2048, 2048),
-                                       (1, 2048), (2048, 128)]
-    assert ops_[2].dtype == jnp.int8
     entry = bk.fused_impact_metered if metered else bk.fused_impact
-    _compile_for_chip(
+    text = _compile_for_chip(
         lambda *a: entry(*a, thresh=I_CSA_THRESHOLD, interpret=False),
         lit, clause_i, nonempty, class_i)
-    ws = vmem.fused_working_set(R=1, tr=TILE_ROWS, n_clause=TILE_COLS,
-                                class_rows=CLASS_ROWS, M=M, metered=metered)
+    _clause_grid_reaches_kernel(text, (1, 1, TILE_ROWS, TILE_COLS))
+    ws = vmem.fused_working_set(R=1, C=1, tr=TILE_ROWS, tc=TILE_COLS, M=M,
+                                metered=metered)
+    # The clause grid's 512 columns, not the class grid's 2048 rows.
+    assert ws.column_blocks == 2
     assert ws.total_bytes <= vmem.DEFAULT_VMEM_BUDGET_BYTES, ws
 
 
@@ -112,18 +143,13 @@ def test_fused_impact_compiles_for_v5e_at_imdb_widths(shape, batch,
                                                       metered):
     """Each grid step holds one 2048-row shard, so five shards compile
     within the default VMEM budget (all five in one block did not: 20-30
-    MiB of scoped VMEM against 16)."""
+    MiB of scoped VMEM against 16), and the kernel reads the 0.42 GB
+    (R, C, tr, tc) grid where it is programmed."""
     lit = shape((batch, IMDB_K), jnp.int8)
     clause_i = shape((IMDB_R, IMDB_C, TILE_ROWS, TILE_COLS), jnp.float32)
     nonempty = shape((IMDB_C * TILE_COLS,), jnp.bool_)
     class_i = shape((IMDB_S, CLASS_ROWS, IMDB_M), jnp.float32)
     bk = backends.get_backend("pallas")
-    ops_ = jax.eval_shape(
-        lambda *a: bk._fused_impact_operands(*a, block_b=128,
-                                             block_n=256)[:4],
-        lit, clause_i, nonempty, class_i)
-    assert [o.shape for o in ops_] == [(5, batch, 2048), (5, 2048, 10240),
-                                       (1, 10240), (10240, 128)]
     entry = bk.fused_impact_metered if metered else bk.fused_impact
     text = _compile_for_chip(
         lambda *a: entry(*a, thresh=I_CSA_THRESHOLD, interpret=False),
@@ -131,11 +157,32 @@ def test_fused_impact_compiles_for_v5e_at_imdb_widths(shape, batch,
     # The kernel's stable name, which the device trace reports.
     name = "fused_impact_metered" if metered else "fused_impact"
     assert f"%{name}." in text
-    ws = vmem.fused_working_set(R=IMDB_R, tr=TILE_ROWS,
-                                n_clause=IMDB_C * TILE_COLS,
-                                class_rows=IMDB_S * CLASS_ROWS, M=IMDB_M,
-                                metered=metered)
+    _clause_grid_reaches_kernel(text,
+                                (IMDB_R, IMDB_C, TILE_ROWS, TILE_COLS))
+    ws = vmem.fused_working_set(R=IMDB_R, C=IMDB_C, tr=TILE_ROWS,
+                                tc=TILE_COLS, M=IMDB_M, metered=metered)
+    assert ws.column_blocks == IMDB_C * TILE_COLS // 256
     assert ws.total_bytes <= vmem.DEFAULT_VMEM_BUDGET_BYTES, ws
+
+
+# Tiles whose row count is not a multiple of 128: the README's R=2/S=2
+# split (784-row tiles) served on one chip, and 152x256 tiles.  The
+# blocks then span the tile's full height, which Mosaic takes.
+UNALIGNED = {"tile_rows_784": (1568, 2, 1, 784, 512, 2, 250, 10),
+             "tile_152x256": (300, 2, 3, 152, 256, 1, 768, 3)}
+
+
+@pytest.mark.parametrize("grid", list(UNALIGNED))
+def test_fused_impact_metered_compiles_for_v5e_on_unaligned_rows(shape,
+                                                                 grid):
+    K_, R, C, tr, tc, S, sr, M_ = UNALIGNED[grid]
+    bk = backends.get_backend("pallas")
+    text = _compile_for_chip(
+        lambda *a: bk.fused_impact_metered(*a, thresh=I_CSA_THRESHOLD,
+                                           interpret=False),
+        shape((128, K_), jnp.int8), shape((R, C, tr, tc), jnp.float32),
+        shape((C * tc,), jnp.bool_), shape((S, sr, M_), jnp.float32))
+    _clause_grid_reaches_kernel(text, (R, C, tr, tc))
 
 
 @pytest.mark.parametrize("batch", [128, 512])

@@ -214,7 +214,8 @@ def test_scheduler_cycle_spans_tile_and_nest(small_system):
 def test_sweep_span_carries_kernel_plan():
     """On a kernel backend each ``sweep`` span carries the session's
     kernel plan: the literal row-shards (3 here, 48 literals on 16-row
-    tiles) and the kernel's VMEM bytes per grid step."""
+    tiles), the column blocks the kernel walks (2: one 512-column clause
+    tile in 256-wide blocks) and the kernel's VMEM bytes per grid step."""
     K, n, m, n_states = 48, 20, 3, 64
     rng = np.random.default_rng(5)
     ta = np.where(rng.random((K, n)) < 0.1, n_states + 1, n_states)
@@ -230,6 +231,7 @@ def test_sweep_span_carries_kernel_plan():
                                          metering="fused", capacity=8))
     plan = session.kernel_plan("infer_step", 8)
     assert (plan.row_shards, plan.literal_chunks) == (3, 3)
+    assert plan.column_blocks == 2
     tr = Tracer()
     eng = IMPACTEngine(session, trace=tr)
     eng.run(rng.random((12, K)) < 0.5)
@@ -237,6 +239,7 @@ def test_sweep_span_carries_kernel_plan():
     assert len(sweeps) == len(eng.batch_stats) >= 2
     for *_, args in sweeps:
         assert args["row_shards"] == 3
+        assert args["column_blocks"] == 2
         assert args["vmem_step_bytes"] == plan.vmem_step_bytes > 0
 
 
