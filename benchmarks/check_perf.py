@@ -182,7 +182,7 @@ def check_metered(current: dict, min_fused_ratio: float = 0.25) -> list[str]:
 
 def check_compressed(current: dict, min_bytes_ratio: float = 4.0) -> list[str]:
     """Gate the compressed-datapath sweep: the section is mandatory (the
-    benchmark always produces it), the packed backend must have agreed
+    benchmark always produces it), the packed kernel must have agreed
     on argmax with both the int8 fused kernel and the einsum oracle
     (``parity_ok``), and BOTH byte-traffic ratios must clear the 4x
     floor per batch — XLA ``bytes_accessed`` (what the compiled sweep

@@ -9,7 +9,7 @@ jax-free hygiene CI job via ``--lint-only``.
 
 **Layer 1 — IR audit** (``repro.analysis.ir_audit``, needs jax):
 compiles a deterministic reference system under the representative
-runtime specs (fused, staged, packed, metered, co-resident) and audits
+runtime specs (fused, staged, packed, oracle) and audits
 every executable's lowered StableHLO — precision ladder (no f64, no
 sub-f32 meters), host isolation (no callbacks/infeed/outfeed), Pallas
 VMEM working set vs budget — and diffs each executable's op-histogram
@@ -39,18 +39,17 @@ BASELINES = os.path.join(REPO, "benchmarks", "baselines",
                          "IR_fingerprints.json")
 REPORT = os.path.join(REPO, "artifacts", "STATIC_audit.json")
 
-#: The audited runtime matrix: every kernel-variant family the sessions
-#: can route to (fused / metered-fused / staged oracle / bit-packed /
-#: co-resident), each with one predict shape so the audit stays cheap.
+#: The audited runtime matrix: the kernel-variant families the sessions
+#: route to (fused and metered-fused / staged oracle / bit-packed), and
+#: the einsum oracle, each with one predict shape so the audit stays
+#: cheap.
 AUDIT_SPECS = (
     ("fused", dict(backend="pallas", metering="fused",
                    batch_sizes=(8,), capacity=8)),
     ("staged", dict(backend="pallas", metering="staged",
                     batch_sizes=(8,), capacity=8)),
-    ("packed", dict(backend="pallas-packed", packing="2bit",
+    ("packed", dict(backend="pallas", packing="2bit",
                     batch_sizes=(8,))),
-    ("metered-backend", dict(backend="pallas-metered", metering="fused",
-                             batch_sizes=(8,))),
     ("oracle", dict(backend="xla", batch_sizes=(8,))),
 )
 

@@ -36,7 +36,7 @@ Four measurements:
    unmetered ratio.
 
 4. **Compressed sweep** — the bit-packed datapath: ``predict`` through
-   the ``pallas-packed`` backend (``packing="2bit"`` — 2-bit ternary
+   the ``pallas`` backend with ``packing="2bit"`` (2-bit ternary
    clause codes, four cells per byte, dequantized inside the fused
    kernel) vs the int8-literal/f32-operand fused kernel, with argmax
    parity against the einsum oracle asserted.  The per-batch
@@ -224,7 +224,7 @@ def metered_sweep(system, cfg, *, quick: bool) -> dict:
 
 
 def compressed_sweep(system, cfg, *, quick: bool) -> dict:
-    """The compressed-datapath acceptance sample: ``pallas-packed``
+    """The compressed-datapath acceptance sample: ``packing="2bit"``
     (2-bit ternary clause codes, four cells per byte, in-kernel dequant)
     vs the int8-literal fused kernel, argmax-parity-checked against the
     einsum oracle, with the per-batch byte-traffic record
@@ -242,7 +242,7 @@ def compressed_sweep(system, cfg, *, quick: bool) -> dict:
     sessions = dict(
         int8=system.compile(RuntimeSpec(backend="pallas", metering="off")),
         packed=system.compile(RuntimeSpec(
-            backend="pallas-packed", metering="off", packing="2bit")),
+            backend="pallas", metering="off", packing="2bit")),
         oracle=system.compile(RuntimeSpec(backend="xla", metering="off")))
     results: dict[str, dict] = {}
     cost: dict[str, dict] = {}
@@ -271,7 +271,7 @@ def compressed_sweep(system, cfg, *, quick: bool) -> dict:
     calib = jnp.asarray(rng.random((64, cfg.n_literals)) < 0.95)
     pruned, stats = prune_clauses(system, calib)
     sess_pruned = pruned.compile(RuntimeSpec(
-        backend="pallas-packed", metering="off", packing="2bit"))
+        backend="pallas", metering="off", packing="2bit"))
     sess_oracle = pruned.compile(RuntimeSpec(backend="xla", metering="off"))
     prune_parity = bool(
         (np.asarray(sess_pruned.predict(calib).predictions)

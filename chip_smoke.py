@@ -207,9 +207,8 @@ def compile_sessions(run: Run, dims: Dims, state, interpret: bool):
     specs = {
         "pallas": RuntimeSpec(backend="pallas", metering="fused",
                               capacity=dims.capacity, interpret=interpret),
-        "pallas-packed": RuntimeSpec(backend="pallas-packed",
-                                     packing="2bit", metering="fused",
-                                     interpret=interpret),
+        "packed": RuntimeSpec(backend="pallas", packing="2bit",
+                              metering="fused", interpret=interpret),
     }
     sessions = {}
     for name, spec in specs.items():
@@ -234,7 +233,7 @@ def predict_parity(run: Run, dims: Dims, state, sessions):
     tr = system.clause_i.shape[2]
     packed = packing.pack_clause_operand(system.clause_i)
     clause_ops = {"pallas": system.clause_i,
-                  "pallas-packed": packing.dequant_clause(
+                  "packed": packing.dequant_clause(
                       packed.bits, packed.levels, tr)}
     include = include_mask(state["params"].ta_state, cfg.n_states)
     sw_bits = np.asarray(clause_outputs(lit_te, include))

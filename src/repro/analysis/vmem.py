@@ -111,7 +111,7 @@ def packed_working_set(*, R: int, tr4: int, n_clause: int, class_rows: int,
                        block_b: int | None = None,
                        block_n: int | None = None) -> WorkingSet:
     """Working set of the bitplane-packed fused kernel, mirroring
-    ``PackedPallasBackend._fused_impact_packed_operands`` padding.
+    ``PallasBackend._fused_impact_packed_operands`` padding.
     ``tr4`` is the packed per-shard row count (4 cells/byte).  This kernel
     holds all ``R`` row-shards in one block, so its bytes grow with R."""
     block_b = block_b or _FUSED_BLOCK_B
@@ -190,59 +190,44 @@ def ta_feedback_working_set(*, K: int, n_clause: int, batch2: int,
 
 def session_working_set(session, entry: str,
                         batch: int | None = None) -> WorkingSet | None:
-    """The VMEM working set of the kernel variant the ``(session,
-    entry)`` pair actually lowers to, following the routing of
-    ``InferenceSession._scores_expr`` / ``_metered_expr``:
+    """The VMEM working set of the kernel the ``(session, entry)``
+    executable runs, read off the session's route for the entry
+    (``InferenceSession.routes``):
 
     * reference (oracle) backends run no kernel -> ``None``;
-    * co-resident sessions, sharded topologies and ``metering="staged"``
-      entries ride the staged ``crossbar_mvm`` compositions -> the
-      larger of the clause / class stage calls;
-    * ``packing="2bit"`` on the ``pallas-packed`` backend -> the packed
-      kernel; on other Pallas backends the session dequantizes outside
-      and runs the unpacked kernel;
-    * ``metering="fused"`` entries (and everything on the always-metered
-      ``pallas-metered`` backend) -> the metered kernel variant;
+    * the ``"staged"`` and ``"shard_map"`` routes run the staged
+      ``crossbar_mvm`` compositions -> the larger of the clause / class
+      stage calls;
+    * the ``"packed"`` and ``"fused"`` routes -> that kernel, its
+      metered variant where the entry meters;
     * the ``ta_feedback`` training entry -> the feedback-delta kernel
       (``batch`` is its compiled DOUBLED row count, from
       ``compiled_shapes``).
     """
-    backend = session.backend
-    if getattr(backend, "reference", False):
+    if getattr(session.backend, "reference", False):
         return None
-    spec = session.spec
     sys_ = session.system
     R, C, tr, tc = sys_.clause_i.shape
     S, sr, M = sys_.class_i.shape
-    n_clause = C * tc
 
     if entry == "ta_feedback":
         return ta_feedback_working_set(K=sys_.n_literals,
                                        n_clause=sys_.n_clauses,
                                        batch2=batch or 128)
 
-    metered_entry = (entry in ("infer_step", "infer_with_report")
-                     and spec.metering != "off")
-    staged = metered_entry and spec.metering == "staged"
-    metered_kernel = ((metered_entry and spec.metering == "fused")
-                      or backend.name == "pallas-metered")
-
-    if (session.coresident is not None or staged
-            or session.plan is not None):
+    route, meter = session.routes[entry]
+    if route in ("staged", "shard_map"):
         # Staged per-shard unroll (also each device's stage of a sharded
         # grid): one crossbar_mvm per clause row-shard (tr drive rows) +
         # one per class row-shard (sr drive rows).
-        clause = mvm_working_set(k_rows=tr, n_cols=n_clause)
+        clause = mvm_working_set(k_rows=tr, n_cols=C * tc)
         klass = mvm_working_set(k_rows=sr, n_cols=M)
         return clause if clause.total_bytes >= klass.total_bytes else klass
-
-    if spec.packing == "2bit" and backend.name == "pallas-packed":
+    if route == "packed":
         tr4 = session._packed.bits.shape[2]
-        return packed_working_set(R=R, tr4=tr4, n_clause=n_clause,
-                                  class_rows=S * sr, M=M,
-                                  metered=metered_kernel)
-    return fused_working_set(R=R, C=C, tr=tr, tc=tc, M=M,
-                             metered=metered_kernel)
+        return packed_working_set(R=R, tr4=tr4, n_clause=C * tc,
+                                  class_rows=S * sr, M=M, metered=meter)
+    return fused_working_set(R=R, C=C, tr=tr, tc=tc, M=M, metered=meter)
 
 
 @dataclasses.dataclass(frozen=True)

@@ -46,7 +46,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from ..analysis import vmem
-from ..kernels import backends
+from ..kernels import backends, ops
 from ..kernels import packing as packing_mod
 from ..kernels import ref as kernels_ref
 from ..sharding import crossbar as crossbar_sh
@@ -62,7 +62,7 @@ PRECISIONS = ("float32",)
 #: currents, ``"2bit"`` packs the ternary cells into the
 #: ``kernels.packing`` bitplane layout at session build (compile time)
 #: — the executable's dominant operand shrinks ~16x and unpacking fuses
-#: into the kernel on the packed backends.
+#: into the Pallas kernel.
 PACKINGS = ("none", "2bit")
 
 #: Canonical input dtypes of every session executable.  Callers may pass
@@ -211,8 +211,8 @@ class RuntimeSpec:
     ``packing="2bit"`` compiles the COMPRESSED datapath: the session
     quantizes the clause crossbar to the 2-bit bitplane layout once at
     build time, the executables take the packed codes + dequant levels
-    as operands (~16x smaller than the f32 currents), and the packed
-    backends unpack inside the kernel.  Argmax parity with the unpacked
+    as operands (~16x smaller than the f32 currents), and the Pallas
+    backend unpacks them inside the kernel.  Argmax parity with the unpacked
     path holds on every backend and shard plan (the CSA decision bits
     survive quantization); ``"none"`` (default) is the f32 datapath.
 
@@ -291,9 +291,9 @@ class InferenceSession:
 
     Built by ``IMPACTSystem.compile(spec)`` (which caches sessions per
     spec — compiling the same spec twice returns the same session).  All
-    spec resolution (backend lookup, mesh/shard-plan placement, metering
-    mode) happens here, once; the entry points only look up an
-    executable and run it.
+    spec resolution (backend lookup, mesh/shard-plan placement, each
+    entry's datapath in ``routes``) happens here, once; the entry points
+    only look up an executable and run it.
 
     Device->host traffic is one transfer per call: ``fetch`` brings an
     ``infer_step`` result's predictions and per-lane joules over as one
@@ -333,6 +333,19 @@ class InferenceSession:
         # not of any call.
         self._packed = (packing_mod.pack_clause_operand(system.clause_i)
                         if spec.packing == "2bit" else None)
+        # The datapath of each serving entry, decided here once from the
+        # spec and the system: ``(route, meter)``, where ``meter`` says
+        # whether the entry meters (``predict`` never does) and
+        # ``route`` is ``"shard_map"`` on a mesh plan, ``"staged"`` (the
+        # per-shard oracle) for co-resident sessions and for metered
+        # entries under ``metering="staged"``, else the ``"packed"`` or
+        # ``"fused"`` kernel.  The traced router (``_datapath``) and the
+        # VMEM planner both read it.
+        self.routes = {entry: (self._route(meter), meter)
+                       for entry, meter in (
+                           ("predict", False),
+                           ("infer_step", self.meters_energy),
+                           ("infer_with_report", self.meters_energy))}
         vmem.refuse_packed_over_budget(self)
         self._exes: dict[tuple[str, int], Any] = {}
         self._plans: dict[tuple[str, int], vmem.KernelPlan | None] = {}
@@ -349,6 +362,14 @@ class InferenceSession:
             self._exe("infer_step", spec.capacity)
         for b in spec.batch_sizes:
             self._exe("predict", b)
+
+    def _route(self, meter: bool) -> str:
+        if self.plan is not None:
+            return "shard_map"
+        if self.coresident is not None or (
+                meter and self.spec.metering == "staged"):
+            return "staged"
+        return "packed" if self._packed is not None else "fused"
 
     # -- properties ---------------------------------------------------------
     @property
@@ -633,28 +654,17 @@ class InferenceSession:
             return lowered.compile()
         lit = jax.ShapeDtypeStruct((batch, sys_.n_literals), LITERAL_DTYPE)
         valid = jax.ShapeDtypeStruct((batch,), jnp.bool_)
-        consts = self._operands()
-        if self.coresident is not None:
-            # Co-resident executables take the per-lane tenant selector
-            # as one extra runtime operand, between the masks and the
-            # weight-side constants.
-            mids = jax.ShapeDtypeStruct((batch,), jnp.int32)
-            if entry == "predict":
-                lowered = jax.jit(self._predict_fn).lower(lit, mids, *consts)
-            elif entry == "infer_step":
-                lowered = jax.jit(self._infer_step_fn).lower(
-                    lit, valid, mids, *consts)
-            elif entry == "infer_with_report":
-                lowered = jax.jit(self._report_fn).lower(
-                    lit, valid, mids, *consts)
-            else:
-                raise ValueError(f"unknown entry point {entry!r}")
-        elif entry == "predict":
-            lowered = jax.jit(self._predict_fn).lower(lit, *consts)
+        # Co-resident executables take the per-lane tenant selector as
+        # one extra runtime operand, between the masks and the
+        # weight-side constants.
+        args = ((jax.ShapeDtypeStruct((batch,), jnp.int32),)
+                if self.coresident is not None else ()) + self._operands()
+        if entry == "predict":
+            lowered = jax.jit(self._predict_fn).lower(lit, *args)
         elif entry == "infer_step":
-            lowered = jax.jit(self._infer_step_fn).lower(lit, valid, *consts)
+            lowered = jax.jit(self._infer_step_fn).lower(lit, valid, *args)
         elif entry == "infer_with_report":
-            lowered = jax.jit(self._report_fn).lower(lit, valid, *consts)
+            lowered = jax.jit(self._report_fn).lower(lit, valid, *args)
         else:
             raise ValueError(f"unknown entry point {entry!r}")
         # The lowered StableHLO is the artifact the static IR audit
@@ -665,214 +675,93 @@ class InferenceSession:
 
     # The traced bodies below run ONLY inside ``.lower()`` — the trace
     # counter bumps are python side effects that count compilations.
-    def _scores_expr(self, literals, *operands):
-        if self._packed is not None:
-            bits, levels, nonempty, class_i = operands
-            packed = packing_mod.PackedClause(bits=bits, levels=levels)
-            tr = self.system.clause_i.shape[2]
-            if self.plan is not None:
-                return crossbar_sh.fused_impact_shmap(
-                    literals, None, nonempty, class_i,
-                    thresh=I_CSA_THRESHOLD, mesh=self.mesh,
-                    impl=self.backend.name, interpret=self.spec.interpret,
-                    shard_r=self.plan[0], shard_s=self.plan[1],
-                    packed=packed, packed_tr=tr)
-            return self.backend.fused_impact_packed(
-                literals, packed, nonempty, class_i,
-                thresh=I_CSA_THRESHOLD, tr=tr,
-                interpret=self.spec.interpret)
-        clause_i, nonempty, class_i = operands
-        if self.plan is not None:
-            return crossbar_sh.fused_impact_shmap(
-                literals, clause_i, nonempty, class_i,
-                thresh=I_CSA_THRESHOLD, mesh=self.mesh,
-                impl=self.backend.name, interpret=self.spec.interpret,
-                shard_r=self.plan[0], shard_s=self.plan[1])
-        return self.backend.fused_impact(
-            literals, clause_i, nonempty, class_i,
-            thresh=I_CSA_THRESHOLD, interpret=self.spec.interpret)
+    def _datapath(self, entry, literals, valid, model_ids, *operands):
+        """The ONE traced router: -> (scores (B, m), per-lane summed
+        clause currents (B,), per-lane summed class currents (B,)) of
+        ``entry`` on its route (``self.routes``); the sums are zeros
+        where the entry does not meter.  ``valid`` (B,) bool masks the
+        lanes that bill, ``model_ids`` (B,) int32 is the co-resident
+        tenant selector (None on a single-tenant session).
 
-    def _metered_expr(self, literals, valid, *operands):
-        """Metered core -> (scores (B, m), per-lane summed clause currents
-        (B,), per-lane summed class currents (B,)) — the ONE routing point
-        between the shard_map lowering, the in-kernel fused meters, and
-        the staged per-shard oracle, resolved from the compile-time spec.
-
-        The three lowerings bill identically (pinned by the parity and
-        property suites): per-lane meters are zero on invalid lanes and
-        padding contributes zero current everywhere.
-
-        A ``packing="2bit"`` session meters the QUANTIZED currents (what
-        the packed cells draw): the fused mode rides the packed metered
-        kernel, the staged oracle and the shard_map lowering dequantize
-        the same codes — on an ideal (variability-free) system all of it
-        is bit-identical to the unpacked meters.
+        Every route bills identically (pinned by the parity and property
+        suites): per-lane meters are zero on invalid lanes and padding
+        contributes zero current everywhere.  The fused and packed
+        kernels meter every lane and mask invalid ones after (exact —
+        meters are per-lane); the staged oracle and the shard_map
+        lowering AND ``valid`` into the fired bits before the class
+        drive.  A co-resident lane's fired bits are ANDed with its
+        tenant's clause columns before the class drive on every route,
+        so both per-lane meters are tenant-pure (foreign clause columns
+        draw 0 A).  A ``packing="2bit"`` session meters the QUANTIZED
+        currents (what the packed cells draw): the staged route
+        dequantizes the same codes the packed kernel unpacks.
         """
+        route, meter = self.routes[entry]
+        interpret = self.spec.interpret
+        tr = self.system.clause_i.shape[2]
         if self._packed is not None:
             bits, levels, nonempty, class_i = operands
-            packed = packing_mod.PackedClause(bits=bits, levels=levels)
-            tr = self.system.clause_i.shape[2]
-            if self.plan is not None:
-                return crossbar_sh.fused_impact_shmap(
-                    literals, None, nonempty, class_i,
-                    thresh=I_CSA_THRESHOLD, mesh=self.mesh,
-                    impl=self.backend.name, interpret=self.spec.interpret,
-                    valid=valid, meter=True,
-                    shard_r=self.plan[0], shard_s=self.plan[1],
-                    packed=packed, packed_tr=tr)
-            if self.spec.metering == "fused":
-                scores, i_cl, i_cs = self.backend.fused_impact_packed_metered(
-                    literals, packed, nonempty, class_i,
-                    thresh=I_CSA_THRESHOLD, tr=tr,
-                    interpret=self.spec.interpret)
+            clause = packing_mod.PackedClause(bits=bits, levels=levels)
+        else:
+            clause, nonempty, class_i = operands
+        if route == "staged":
+            if self._packed is not None:
+                clause = packing_mod.dequant_clause(bits, levels, tr)
+            fired, i_clause = self.backend.impact_clause_bits(
+                literals, clause, nonempty, thresh=I_CSA_THRESHOLD,
+                interpret=interpret)
+            if model_ids is not None:
+                fired = jnp.logical_and(fired, self._co_lane_cols(model_ids))
+            if meter:
+                fired = jnp.logical_and(fired, valid[:, None])
+                i_clause = i_clause * valid[:, None, None, None]
+            scores, i_class = self.backend.impact_class_scores(
+                fired, class_i, interpret=interpret)
+            i_cl, i_cs = i_clause.sum(axis=(1, 2, 3)), i_class.sum(axis=(1, 2))
+        else:
+            sharded = route == "shard_map"
+            out = ops.fused_datapath(
+                self.backend, literals, clause, nonempty, class_i,
+                thresh=I_CSA_THRESHOLD, meter=meter, tr=tr,
+                interpret=interpret, mesh=self.mesh,
+                plan=self.plan if sharded else None,
+                valid=valid if sharded and meter else None,
+                lane_cols=(None if model_ids is None
+                           else self._co_lane_cols(model_ids)))
+            scores, i_cl, i_cs = out if meter else (out, None, None)
+            if meter and not sharded:
                 v = valid.astype(scores.dtype)
-                return scores, i_cl * v, i_cs * v
-            # Staged oracle on the dequantized currents.
-            operands = (packing_mod.dequant_clause(bits, levels, tr),
-                        nonempty, class_i)
-        clause_i, nonempty, class_i = operands
-        if self.plan is not None:
-            # On a mesh both metered modes share the shard_map datapath:
-            # its per-device stages materialize the partial currents
-            # anyway, so the meters are psummed from what is already
-            # computed — the same no-second-pass property the fused
-            # kernel gives one device.
-            return crossbar_sh.fused_impact_shmap(
-                literals, clause_i, nonempty, class_i,
-                thresh=I_CSA_THRESHOLD, mesh=self.mesh,
-                impl=self.backend.name, interpret=self.spec.interpret,
-                valid=valid, meter=True,
-                shard_r=self.plan[0], shard_s=self.plan[1])
-        if self.spec.metering == "fused":
-            scores, i_cl, i_cs = self.backend.fused_impact_metered(
-                literals, clause_i, nonempty, class_i,
-                thresh=I_CSA_THRESHOLD, interpret=self.spec.interpret)
-            # Meters are per-lane, so masking AFTER the fused pass is
-            # exact: an invalid lane bills zero without touching any
-            # other lane's currents.
-            v = valid.astype(scores.dtype)
-            return scores, i_cl * v, i_cs * v
-        fired, i_clause = self.backend.impact_clause_bits(
-            literals, clause_i, nonempty, thresh=I_CSA_THRESHOLD,
-            interpret=self.spec.interpret)
-        fired = jnp.logical_and(fired, valid[:, None])
-        i_clause = i_clause * valid[:, None, None, None]
-        scores, i_class = self.backend.impact_class_scores(
-            fired, class_i, interpret=self.spec.interpret)
-        return scores, i_clause.sum(axis=(1, 2, 3)), i_class.sum(axis=(1, 2))
+                i_cl, i_cs = i_cl * v, i_cs * v
+        if not meter:
+            i_cl = i_cs = jnp.zeros((literals.shape[0],), jnp.float32)
+        return scores, i_cl, i_cs
 
-    # -- co-resident traced expressions -------------------------------------
+    def _split(self, args):
+        """Entry args after the masks -> (model_ids or None, operands):
+        co-resident executables take the tenant selector first."""
+        if self.coresident is None:
+            return None, args
+        return args[0], args[1:]
+
     def _co_lane_cols(self, model_ids):
         """(B, n) per-lane clause-column ownership mask (the CSA gating
         step of co-residency — see ``kernels.ref.coresident_lane_mask``)."""
         return kernels_ref.coresident_lane_mask(
             model_ids, self._clause_spans, self.system.n_clauses)
 
-    def _co_pred(self, scores, model_ids):
-        """Tenant-LOCAL argmax: restrict each lane's argmax to its own
-        class span and rebase to span-local indices, so a co-resident
-        lane predicts exactly what a standalone single-tenant session
-        would."""
+    def _argmax(self, scores, model_ids):
+        """Predictions; on a co-resident session tenant-LOCAL: each
+        lane's argmax is restricted to its own class span and rebased to
+        span-local indices, so a co-resident lane predicts exactly what a
+        standalone single-tenant session would."""
+        if model_ids is None:
+            return jnp.argmax(scores, axis=-1)
         lo = self._class_spans[model_ids, 0]
         hi = self._class_spans[model_ids, 1]
         col = jnp.arange(scores.shape[1], dtype=jnp.int32)[None, :]
         mask = jnp.logical_and(col >= lo[:, None], col < hi[:, None])
         masked = jnp.where(mask, scores, -jnp.inf)
         return jnp.argmax(masked, axis=-1).astype(jnp.int32) - lo
-
-    def _co_scores_expr(self, literals, model_ids, *operands):
-        """Co-resident twin of ``_scores_expr``: the same three routings
-        (shard_map / packed / single-device) through the co-resident
-        registry primitives, which gate fired bits to each lane's own
-        clause-column span before the class stage."""
-        if self._packed is not None:
-            bits, levels, nonempty, class_i = operands
-            packed = packing_mod.PackedClause(bits=bits, levels=levels)
-            tr = self.system.clause_i.shape[2]
-            if self.plan is not None:
-                return crossbar_sh.fused_impact_shmap(
-                    literals, None, nonempty, class_i,
-                    thresh=I_CSA_THRESHOLD, mesh=self.mesh,
-                    impl=self.backend.name, interpret=self.spec.interpret,
-                    shard_r=self.plan[0], shard_s=self.plan[1],
-                    packed=packed, packed_tr=tr,
-                    lane_cols=self._co_lane_cols(model_ids))
-            return self.backend.fused_impact_coresident_packed(
-                literals, packed, nonempty, class_i, model_ids,
-                self._clause_spans, thresh=I_CSA_THRESHOLD, tr=tr,
-                interpret=self.spec.interpret)
-        clause_i, nonempty, class_i = operands
-        if self.plan is not None:
-            return crossbar_sh.fused_impact_shmap(
-                literals, clause_i, nonempty, class_i,
-                thresh=I_CSA_THRESHOLD, mesh=self.mesh,
-                impl=self.backend.name, interpret=self.spec.interpret,
-                shard_r=self.plan[0], shard_s=self.plan[1],
-                lane_cols=self._co_lane_cols(model_ids))
-        return self.backend.fused_impact_coresident(
-            literals, clause_i, nonempty, class_i, model_ids,
-            self._clause_spans, thresh=I_CSA_THRESHOLD,
-            interpret=self.spec.interpret)
-
-    def _co_metered_expr(self, literals, valid, model_ids, *operands):
-        """Metered co-resident core, mirroring ``_metered_expr``'s
-        routing.  Both metering modes bill identically here: on a mesh
-        the shard_map lowering meters the partial stages it materializes
-        anyway (the lane mask rides ``lane_cols``); off-mesh the fused
-        mode runs the co-resident registry primitive and masks invalid
-        lanes after (exact — meters are per-lane), while the staged
-        oracle masks fired bits before the class drive.  Valid lanes see
-        the identical composition either way, and both per-lane meters
-        are tenant-pure (foreign clause columns draw 0 A; the lane mask
-        runs before the class drive)."""
-        if self._packed is not None:
-            bits, levels, nonempty, class_i = operands
-            packed = packing_mod.PackedClause(bits=bits, levels=levels)
-            tr = self.system.clause_i.shape[2]
-            if self.plan is not None:
-                return crossbar_sh.fused_impact_shmap(
-                    literals, None, nonempty, class_i,
-                    thresh=I_CSA_THRESHOLD, mesh=self.mesh,
-                    impl=self.backend.name, interpret=self.spec.interpret,
-                    valid=valid, meter=True,
-                    shard_r=self.plan[0], shard_s=self.plan[1],
-                    packed=packed, packed_tr=tr,
-                    lane_cols=self._co_lane_cols(model_ids))
-            if self.spec.metering == "fused":
-                scores, i_cl, i_cs = (
-                    self.backend.fused_impact_coresident_packed_metered(
-                        literals, packed, nonempty, class_i, model_ids,
-                        self._clause_spans, thresh=I_CSA_THRESHOLD, tr=tr,
-                        interpret=self.spec.interpret))
-                v = valid.astype(scores.dtype)
-                return scores, i_cl * v, i_cs * v
-            operands = (packing_mod.dequant_clause(bits, levels, tr),
-                        nonempty, class_i)
-        clause_i, nonempty, class_i = operands
-        if self.plan is not None:
-            return crossbar_sh.fused_impact_shmap(
-                literals, clause_i, nonempty, class_i,
-                thresh=I_CSA_THRESHOLD, mesh=self.mesh,
-                impl=self.backend.name, interpret=self.spec.interpret,
-                valid=valid, meter=True,
-                shard_r=self.plan[0], shard_s=self.plan[1],
-                lane_cols=self._co_lane_cols(model_ids))
-        if self.spec.metering == "fused":
-            scores, i_cl, i_cs = self.backend.fused_impact_coresident_metered(
-                literals, clause_i, nonempty, class_i, model_ids,
-                self._clause_spans, thresh=I_CSA_THRESHOLD,
-                interpret=self.spec.interpret)
-            v = valid.astype(scores.dtype)
-            return scores, i_cl * v, i_cs * v
-        fired, i_clause = self.backend.impact_clause_bits(
-            literals, clause_i, nonempty, thresh=I_CSA_THRESHOLD,
-            interpret=self.spec.interpret)
-        fired = jnp.logical_and(fired, self._co_lane_cols(model_ids))
-        fired = jnp.logical_and(fired, valid[:, None])
-        i_clause = i_clause * valid[:, None, None, None]
-        scores, i_class = self.backend.impact_class_scores(
-            fired, class_i, interpret=self.spec.interpret)
-        return scores, i_clause.sum(axis=(1, 2, 3)), i_class.sum(axis=(1, 2))
 
     def _ta_feedback_fn(self, lit2, fired2, sel, match, hi, lo, include):
         self._traces["ta_feedback"] += 1
@@ -882,45 +771,26 @@ class InferenceSession:
 
     def _predict_fn(self, literals, *args):
         self._traces["predict"] += 1
-        if self.coresident is not None:
-            model_ids, *operands = args
-            scores = self._co_scores_expr(literals, model_ids, *operands)
-            return self._co_pred(scores, model_ids), scores
-        scores = self._scores_expr(literals, *args)
-        return jnp.argmax(scores, axis=-1), scores
+        model_ids, operands = self._split(args)
+        scores, _, _ = self._datapath("predict", literals, None, model_ids,
+                                      *operands)
+        return self._argmax(scores, model_ids), scores
 
     def _infer_step_fn(self, literals, valid, *args):
+        """Per-lane (predictions, clause joules, class joules) of one
+        sweep, and the three stacked for one transfer; invalid lanes
+        predict -1 and bill zero."""
         self._traces["infer_step"] += 1
-        preds, e_cl, e_cs = self._step_lanes(literals, valid.astype(bool),
-                                             *args)
+        valid = valid.astype(bool)
+        model_ids, operands = self._split(args)
+        scores, i_cl, i_cs = self._datapath("infer_step", literals, valid,
+                                            model_ids, *operands)
+        e_cl, e_cs = (energy_mod.per_lane_read_energy(i_cl, i_cs)
+                      if self.meters_energy else (i_cl, i_cs))
+        preds = jnp.where(valid, self._argmax(scores, model_ids), -1)
         # float32 holds every prediction -1 .. m-1 exactly.
         host = jnp.stack([preds.astype(jnp.float32), e_cl, e_cs])
         return preds, e_cl, e_cs, host
-
-    def _step_lanes(self, literals, valid, *args):
-        """Per-lane (predictions, clause joules, class joules) of one
-        sweep; invalid lanes predict -1 and bill zero."""
-        if self.coresident is not None:
-            model_ids, *operands = args
-            if not self.meters_energy:
-                scores = self._co_scores_expr(literals, model_ids, *operands)
-                zeros = jnp.zeros((literals.shape[0],), jnp.float32)
-                return (jnp.where(valid, self._co_pred(scores, model_ids),
-                                  -1), zeros, zeros)
-            scores, i_cl, i_cs = self._co_metered_expr(
-                literals, valid, model_ids, *operands)
-            e_cl, e_cs = energy_mod.per_lane_read_energy(i_cl, i_cs)
-            return (jnp.where(valid, self._co_pred(scores, model_ids), -1),
-                    e_cl, e_cs)
-        if not self.meters_energy:
-            scores = self._scores_expr(literals, *args)
-            zeros = jnp.zeros((literals.shape[0],), jnp.float32)
-            return (jnp.where(valid, jnp.argmax(scores, axis=-1), -1),
-                    zeros, zeros)
-        scores, i_cl, i_cs = self._metered_expr(literals, valid, *args)
-        e_cl, e_cs = energy_mod.per_lane_read_energy(i_cl, i_cs)
-        return (jnp.where(valid, jnp.argmax(scores, axis=-1), -1),
-                e_cl, e_cs)
 
     def _report_fn(self, literals, valid, *args):
         self._traces["infer_with_report"] += 1
@@ -935,20 +805,15 @@ class InferenceSession:
         """(predictions, batch-summed clause current, batch-summed class
         current) of one metered batch."""
         valid = valid.astype(bool)
-        if self.coresident is not None:
-            model_ids, *operands = args
-            scores, i_cl_lane, i_cs_lane = self._co_metered_expr(
-                literals, valid, model_ids, *operands)
-            return (jnp.where(valid, self._co_pred(scores, model_ids), -1),
-                    i_cl_lane.sum(), i_cs_lane.sum())
-        scores, i_cl_lane, i_cs_lane = self._metered_expr(
-            literals, valid, *args)
+        model_ids, operands = self._split(args)
+        scores, i_cl, i_cs = self._datapath("infer_with_report", literals,
+                                            valid, model_ids, *operands)
         # Sentinel invalid lanes like infer_step does: the staged and
         # fused lowerings see different scores on an excluded lane (one
         # zeroes its clause drive, the other doesn't), so its argmax is
         # meaningless — mask it instead of leaking a mode-dependent value.
-        return (jnp.where(valid, jnp.argmax(scores, axis=-1), -1),
-                i_cl_lane.sum(), i_cs_lane.sum())
+        return (jnp.where(valid, self._argmax(scores, model_ids), -1),
+                i_cl.sum(), i_cs.sum())
 
     def __repr__(self) -> str:
         return (f"InferenceSession(backend={self.spec.backend!r}, "

@@ -1,22 +1,24 @@
 """Pluggable inference-backend registry.
 
 A *backend* is one lowering of the crossbar primitives the IMPACT
-runtime is built from — the Pallas kernels, the pure-einsum oracles, or
-(future) a TPU-native / metered-fused lowering.  Dispatch used to be an
-``if impl == "xla"`` string switch copy-pasted into every jitted entry
-point; it now lives here, so a new backend slots in by registering an
-object instead of touching call sites — ``MeteredPallasBackend``
-(``"pallas-metered"``, the always-metered fused lowering) is the first
-backend that arrived purely through this seam:
+runtime is built from: ``"pallas"``, the Pallas kernels, and ``"xla"``,
+the pure-einsum oracles.  Dispatch used to be an ``if impl == "xla"``
+string switch copy-pasted into every jitted entry point; it now lives
+here, so a new backend slots in by registering an object instead of
+touching call sites:
 
     class MyLowering(PallasBackend):
         name = "pallas-mine"
         ...
     register_backend(MyLowering())
 
+A backend is a lowering, not a datapath: which kernel variant runs
+(metered or not, packed or not) is the ``RuntimeSpec``'s ``metering``
+and ``packing`` fields, routed by ``ops.fused_datapath``.
+
 Every backend also lowers ``fused_impact_metered`` — inference plus the
 per-lane read-current meters (the Table 4 energy accounting) in one
-call: the Pallas backends accumulate the meters inside the fused
+call: the Pallas backend accumulates the meters inside the fused
 kernel's VMEM residency, the reference backend uses the whole-array
 metered oracle, and the base class composes the staged per-shard
 primitives so any third backend meters correctly out of the box.
@@ -148,14 +150,6 @@ class Backend:
         raise NotImplementedError
 
     # -- bitplane-packed datapath (kernels.packing layout) -----------------
-    def pack_clause_operand(self, clause_i: Array, *,
-                            split: float | None = None,
-                            ) -> packing.PackedClause:
-        """Quantize a clause-current operand to the 2-bit packed layout.
-        ``split=None`` classifies HCS/LCS at the device-population
-        midpoint (``packing.population_split``)."""
-        return packing.pack_clause_operand(clause_i, split=split)
-
     def fused_impact_packed(self, literals: Array,
                             packed: packing.PackedClause, nonempty: Array,
                             class_i: Array, *, thresh: float, tr: int,
@@ -163,17 +157,8 @@ class Backend:
                             block_b: int = 128, block_n: int = 256) -> Array:
         """``fused_impact`` on a packed clause operand.  ``tr`` is the
         UNPACKED per-shard row count (not recoverable from the packed
-        bits — the shard row mapping needs it).
-
-        Default composition: dequantize and delegate, so every
-        registered backend accepts ``RuntimeSpec(packing="2bit")`` out of
-        the box; ``PackedPallasBackend`` overrides with the kernel that
-        unpacks in VMEM and never materializes the f32 operand.
-        """
-        clause_i = packing.dequant_clause(packed.bits, packed.levels, tr)
-        return self.fused_impact(literals, clause_i, nonempty, class_i,
-                                 thresh=thresh, interpret=interpret,
-                                 block_b=block_b, block_n=block_n)
+        bits — the shard row mapping needs it)."""
+        raise NotImplementedError
 
     def fused_impact_packed_metered(self, literals: Array,
                                     packed: packing.PackedClause,
@@ -185,93 +170,7 @@ class Backend:
         """Metered packed datapath; meters bill the QUANTIZED currents
         (what the packed cells draw), same triple as
         ``fused_impact_metered``."""
-        clause_i = packing.dequant_clause(packed.bits, packed.levels, tr)
-        return self.fused_impact_metered(literals, clause_i, nonempty,
-                                         class_i, thresh=thresh,
-                                         interpret=interpret,
-                                         block_b=block_b, block_n=block_n)
-
-    # -- crossbar co-residency (block-diagonal multi-tenant grids) ---------
-    def fused_impact_coresident(self, literals: Array, clause_i: Array,
-                                nonempty: Array, class_i: Array,
-                                model_ids: Array, clause_spans: Array, *,
-                                thresh: float,
-                                interpret: bool | None = None,
-                                block_b: int = 128,
-                                block_n: int = 256) -> Array:
-        """``fused_impact`` on a block-diagonal co-resident grid with a
-        per-lane tenant mask (``model_ids`` (B,) int32 indexing
-        ``clause_spans`` (T, 2) ``[lo, hi)`` clause-column spans).
-
-        A lane drives only its own tenant's literal rows, so foreign
-        clause columns draw exactly 0 A — but 0 A is below the CSA
-        threshold, so foreign nonempty columns would spuriously fire.
-        The mask, applied between the clause and class stages, gates
-        those bits off; with off-block cells at 0 A this makes
-        cross-tenant leakage exactly zero by construction (see
-        ``ref.coresident_lane_mask``).
-
-        Default composition from the staged primitives, so every
-        registered backend serves co-resident sweeps (the Pallas
-        backends ride their ``crossbar_mvm`` kernels through it); the
-        einsum oracle is ``ref.fused_impact_coresident_ref``.
-        """
-        fired, _ = self.impact_clause_bits(
-            literals, clause_i, nonempty, thresh=thresh, interpret=interpret)
-        fired = jnp.logical_and(
-            fired, ref.coresident_lane_mask(model_ids, clause_spans,
-                                            fired.shape[1]))
-        scores, _ = self.impact_class_scores(fired, class_i,
-                                             interpret=interpret)
-        return scores
-
-    def fused_impact_coresident_metered(
-            self, literals: Array, clause_i: Array, nonempty: Array,
-            class_i: Array, model_ids: Array, clause_spans: Array, *,
-            thresh: float, interpret: bool | None = None,
-            block_b: int = 128, block_n: int = 256,
-            ) -> tuple[Array, Array, Array]:
-        """Metered co-resident sweep, same triple as
-        ``fused_impact_metered``.  Both per-lane meters are tenant-pure:
-        the clause meter because foreign columns draw 0 A, the class
-        meter because the lane mask runs before the class drive."""
-        fired, i_col = self.impact_clause_bits(
-            literals, clause_i, nonempty, thresh=thresh, interpret=interpret)
-        fired = jnp.logical_and(
-            fired, ref.coresident_lane_mask(model_ids, clause_spans,
-                                            fired.shape[1]))
-        scores, i_cls = self.impact_class_scores(fired, class_i,
-                                                 interpret=interpret)
-        return scores, i_col.sum(axis=(1, 2, 3)), i_cls.sum(axis=(1, 2))
-
-    def fused_impact_coresident_packed(
-            self, literals: Array, packed: packing.PackedClause,
-            nonempty: Array, class_i: Array, model_ids: Array,
-            clause_spans: Array, *, thresh: float, tr: int,
-            interpret: bool | None = None, block_b: int = 128,
-            block_n: int = 256) -> Array:
-        """Co-resident sweep on a 2-bit packed clause operand:
-        dequantize and delegate, so ``packing="2bit"`` composes with
-        co-residency on every backend."""
-        clause_i = packing.dequant_clause(packed.bits, packed.levels, tr)
-        return self.fused_impact_coresident(
-            literals, clause_i, nonempty, class_i, model_ids, clause_spans,
-            thresh=thresh, interpret=interpret, block_b=block_b,
-            block_n=block_n)
-
-    def fused_impact_coresident_packed_metered(
-            self, literals: Array, packed: packing.PackedClause,
-            nonempty: Array, class_i: Array, model_ids: Array,
-            clause_spans: Array, *, thresh: float, tr: int,
-            interpret: bool | None = None, block_b: int = 128,
-            block_n: int = 256) -> tuple[Array, Array, Array]:
-        """Metered packed co-resident sweep (meters bill the quantized
-        currents, like ``fused_impact_packed_metered``)."""
-        clause_i = packing.dequant_clause(packed.bits, packed.levels, tr)
-        return self.fused_impact_coresident_metered(
-            literals, clause_i, nonempty, class_i, model_ids, clause_spans,
-            thresh=thresh, interpret=interpret, block_b=block_b,
-            block_n=block_n)
+        raise NotImplementedError
 
     # -- online training (arXiv:2408.09456 in-array TA updates) ------------
     def ta_feedback(self, lit2: Array, fired2: Array, sel: Array,
@@ -335,7 +234,11 @@ class Backend:
 
 class PallasBackend(Backend):
     """The production lowering: Pallas TPU kernels (interpret mode
-    off-TPU), with the neutral-padding plumbing around each one."""
+    off-TPU), with the neutral-padding plumbing around each one.
+
+    On a packed clause operand (``kernels.packing`` 2-bit layout) the
+    fused kernel unpacks the bitplanes in VMEM, so the f32 clause-current
+    operand never exists in HBM."""
 
     name = "pallas"
 
@@ -454,6 +357,74 @@ class PallasBackend(Backend):
                 meters[:B, _impact_kernel.METER_LANE_CLAUSE],
                 meters[:B, _impact_kernel.METER_LANE_CLASS])
 
+    def _fused_impact_packed_operands(self, literals, packed, nonempty,
+                                      class_i, *, tr, block_b, block_n):
+        """Neutral-padding plumbing for the packed kernel layouts:
+        -> (drive_p, pbits, levels, ne, wcur, block_n).  Padding packs to
+        CODE_DEAD (0 A) and pads drive with 0, so padded rows/columns
+        contribute exactly zero current — the meters stay exact."""
+        B, K = literals.shape
+        R, C, tr4, tc = packed.bits.shape
+        S, sr, M = class_i.shape
+        n_clause = C * tc
+
+        N = max(n_clause, S * sr)
+        block_n = min(block_n, max(128, -(-N // 128) * 128))
+        tr4_pad = max(128, -(-tr4 // 128) * 128)
+
+        # Bitplane-major drive: drive_p[r, j, b, q] = 1 - lit[b, r*tr+4q+j].
+        lit = pad_axis(literals.astype(jnp.float32), R * tr, 1, 1)
+        drive = (1.0 - lit).reshape(B, R, tr)
+        drive = pad_axis(drive, packing.CELLS_PER_BYTE * tr4, 2, 0.0)
+        drive = drive.reshape(B, R, tr4, packing.CELLS_PER_BYTE)
+        drive = drive.transpose(1, 3, 0, 2)         # (R, 4, B, tr4)
+        drive = pad_axis(pad_axis(drive, block_b, 2, 0.0), tr4_pad, 3, 0.0)
+
+        pbits = packed.bits.transpose(0, 2, 1, 3).reshape(R, tr4, n_clause)
+        pbits = pad_axis(pad_axis(pbits, tr4_pad, 1, 0), block_n, 2, 0)
+        if N > n_clause:
+            pbits = pad_axis(pbits, -(-N // block_n) * block_n, 2, 0)
+
+        levels = jnp.zeros((1, 128), jnp.float32)
+        levels = levels.at[0, :2].set(packed.levels.astype(jnp.float32))
+
+        ne = pad_axis(nonempty.astype(jnp.int8)[None, :],
+                      -(-N // block_n) * block_n, 1, 0)
+
+        wcur = class_i.astype(jnp.float32).reshape(S * sr, M)
+        wcur = pad_axis(pad_axis(wcur, ne.shape[1], 0, 0.0), 128, 1, 0.0)
+        return drive, pbits, levels, ne, wcur, block_n
+
+    def fused_impact_packed(self, literals, packed, nonempty, class_i, *,
+                            thresh, tr, interpret=None, block_b=128,
+                            block_n=256):
+        B, M = literals.shape[0], class_i.shape[2]
+        interpret = self.resolve_interpret(interpret)
+        drive, pbits, levels, ne, wcur, block_n = (
+            self._fused_impact_packed_operands(
+                literals, packed, nonempty, class_i, tr=tr,
+                block_b=block_b, block_n=block_n))
+        out = _impact_kernel.fused_impact_packed(
+            drive, pbits, levels, ne, wcur, thresh=thresh, block_b=block_b,
+            block_n=block_n, interpret=interpret)
+        return out[:B, :M]
+
+    def fused_impact_packed_metered(self, literals, packed, nonempty,
+                                    class_i, *, thresh, tr, interpret=None,
+                                    block_b=128, block_n=256):
+        B, M = literals.shape[0], class_i.shape[2]
+        interpret = self.resolve_interpret(interpret)
+        drive, pbits, levels, ne, wcur, block_n = (
+            self._fused_impact_packed_operands(
+                literals, packed, nonempty, class_i, tr=tr,
+                block_b=block_b, block_n=block_n))
+        out, meters = _impact_kernel.fused_impact_packed_metered(
+            drive, pbits, levels, ne, wcur, thresh=thresh, block_b=block_b,
+            block_n=block_n, interpret=interpret)
+        return (out[:B, :M],
+                meters[:B, _impact_kernel.METER_LANE_CLAUSE],
+                meters[:B, _impact_kernel.METER_LANE_CLASS])
+
     def ta_feedback(self, lit2, fired2, sel, match, hi, lo, include, *,
                     interpret=None, block_k=128, block_n=128):
         B2, K = lit2.shape
@@ -564,132 +535,6 @@ class XLABackend(Backend):
             thresh=thresh, tr=tr)
 
 
-class MeteredPallasBackend(PallasBackend):
-    """The always-metered Pallas lowering: every fused inference runs the
-    metered kernel, scores-only callers just drop the meters.
-
-    ``RuntimeSpec(backend="pallas", metering="fused")`` already reaches
-    the metered kernel through ``PallasBackend.fused_impact_metered``;
-    this registered variant exists so the *unmetered* entry points
-    (``predict``, benchmark sweeps) can ride the metered kernel too —
-    the one-to-one A/B that prices the in-kernel meter on the identical
-    call path (``benchmarks/impact_throughput.py`` records it as the
-    ``metered_fused`` sample), and the registry's proof that a new
-    lowering slots in by registration alone.
-    """
-
-    name = "pallas-metered"
-
-    def fused_impact(self, literals, clause_i, nonempty, class_i, *,
-                     thresh, interpret=None, block_b=128, block_n=256):
-        scores, _, _ = self.fused_impact_metered(
-            literals, clause_i, nonempty, class_i, thresh=thresh,
-            interpret=interpret, block_b=block_b, block_n=block_n)
-        return scores
-
-
-class PackedPallasBackend(PallasBackend):
-    """The compressed lowering: the fused kernel consumes bitplane-packed
-    clause bits (``kernels.packing`` 2-bit layout) and unpacks them in
-    VMEM — the f32 clause-current operand never exists in HBM, so the
-    dominant sweep operand shrinks ~16x (f32 cell currents -> 2-bit
-    codes) and total sweep input bytes drop well past 4x.
-
-    Sessions built with ``RuntimeSpec(packing="2bit")`` pack ONCE at
-    compile time and feed ``fused_impact_packed`` directly; the plain
-    ``fused_impact`` entry points pack in-trace (constant-folded under
-    jit for weight operands), so this backend is also a drop-in registry
-    key for ``ops.*(impl="pallas-packed")``.
-    """
-
-    name = "pallas-packed"
-
-    def _fused_impact_packed_operands(self, literals, packed, nonempty,
-                                      class_i, *, tr, block_b, block_n):
-        """Neutral-padding plumbing for the packed kernel layouts:
-        -> (drive_p, pbits, levels, ne, wcur, block_n).  Padding packs to
-        CODE_DEAD (0 A) and pads drive with 0, so padded rows/columns
-        contribute exactly zero current — the meters stay exact."""
-        B, K = literals.shape
-        R, C, tr4, tc = packed.bits.shape
-        S, sr, M = class_i.shape
-        n_clause = C * tc
-
-        N = max(n_clause, S * sr)
-        block_n = min(block_n, max(128, -(-N // 128) * 128))
-        tr4_pad = max(128, -(-tr4 // 128) * 128)
-
-        # Bitplane-major drive: drive_p[r, j, b, q] = 1 - lit[b, r*tr+4q+j].
-        lit = pad_axis(literals.astype(jnp.float32), R * tr, 1, 1)
-        drive = (1.0 - lit).reshape(B, R, tr)
-        drive = pad_axis(drive, packing.CELLS_PER_BYTE * tr4, 2, 0.0)
-        drive = drive.reshape(B, R, tr4, packing.CELLS_PER_BYTE)
-        drive = drive.transpose(1, 3, 0, 2)         # (R, 4, B, tr4)
-        drive = pad_axis(pad_axis(drive, block_b, 2, 0.0), tr4_pad, 3, 0.0)
-
-        pbits = packed.bits.transpose(0, 2, 1, 3).reshape(R, tr4, n_clause)
-        pbits = pad_axis(pad_axis(pbits, tr4_pad, 1, 0), block_n, 2, 0)
-        if N > n_clause:
-            pbits = pad_axis(pbits, -(-N // block_n) * block_n, 2, 0)
-
-        levels = jnp.zeros((1, 128), jnp.float32)
-        levels = levels.at[0, :2].set(packed.levels.astype(jnp.float32))
-
-        ne = pad_axis(nonempty.astype(jnp.int8)[None, :],
-                      -(-N // block_n) * block_n, 1, 0)
-
-        wcur = class_i.astype(jnp.float32).reshape(S * sr, M)
-        wcur = pad_axis(pad_axis(wcur, ne.shape[1], 0, 0.0), 128, 1, 0.0)
-        return drive, pbits, levels, ne, wcur, block_n
-
-    def fused_impact_packed(self, literals, packed, nonempty, class_i, *,
-                            thresh, tr, interpret=None, block_b=128,
-                            block_n=256):
-        B, M = literals.shape[0], class_i.shape[2]
-        interpret = self.resolve_interpret(interpret)
-        drive, pbits, levels, ne, wcur, block_n = (
-            self._fused_impact_packed_operands(
-                literals, packed, nonempty, class_i, tr=tr,
-                block_b=block_b, block_n=block_n))
-        out = _impact_kernel.fused_impact_packed(
-            drive, pbits, levels, ne, wcur, thresh=thresh, block_b=block_b,
-            block_n=block_n, interpret=interpret)
-        return out[:B, :M]
-
-    def fused_impact_packed_metered(self, literals, packed, nonempty,
-                                    class_i, *, thresh, tr, interpret=None,
-                                    block_b=128, block_n=256):
-        B, M = literals.shape[0], class_i.shape[2]
-        interpret = self.resolve_interpret(interpret)
-        drive, pbits, levels, ne, wcur, block_n = (
-            self._fused_impact_packed_operands(
-                literals, packed, nonempty, class_i, tr=tr,
-                block_b=block_b, block_n=block_n))
-        out, meters = _impact_kernel.fused_impact_packed_metered(
-            drive, pbits, levels, ne, wcur, thresh=thresh, block_b=block_b,
-            block_n=block_n, interpret=interpret)
-        return (out[:B, :M],
-                meters[:B, _impact_kernel.METER_LANE_CLAUSE],
-                meters[:B, _impact_kernel.METER_LANE_CLASS])
-
-    def fused_impact(self, literals, clause_i, nonempty, class_i, *,
-                     thresh, interpret=None, block_b=128, block_n=256):
-        packed = self.pack_clause_operand(clause_i)
-        return self.fused_impact_packed(
-            literals, packed, nonempty, class_i, thresh=thresh,
-            tr=clause_i.shape[2], interpret=interpret, block_b=block_b,
-            block_n=block_n)
-
-    def fused_impact_metered(self, literals, clause_i, nonempty, class_i,
-                             *, thresh, interpret=None, block_b=128,
-                             block_n=256):
-        packed = self.pack_clause_operand(clause_i)
-        return self.fused_impact_packed_metered(
-            literals, packed, nonempty, class_i, thresh=thresh,
-            tr=clause_i.shape[2], interpret=interpret, block_b=block_b,
-            block_n=block_n)
-
-
 # -- registry ---------------------------------------------------------------
 
 _REGISTRY: dict[str, Backend] = {}
@@ -704,18 +549,14 @@ _REGISTRY: dict[str, Backend] = {}
 REQUIRED_PRIMITIVES: tuple[str, ...] = (
     "resolve_interpret", "clause_eval", "class_sum",
     "fused_cotm", "fused_impact", "fused_impact_metered",
-    "crossbar_mvm", "pack_clause_operand",
-    "fused_impact_packed", "fused_impact_packed_metered",
-    "fused_impact_coresident", "fused_impact_coresident_metered",
-    "fused_impact_coresident_packed",
-    "fused_impact_coresident_packed_metered",
+    "crossbar_mvm", "fused_impact_packed", "fused_impact_packed_metered",
     "impact_clause_bits", "impact_class_scores", "ta_feedback",
 )
 
 
 def register_backend(backend: Backend, *, overwrite: bool = False) -> Backend:
     """Register a backend under ``backend.name``.  Registering is how a
-    new lowering (TPU-native, metered-fused, ...) plugs into every entry
+    new lowering (TPU-native, ...) plugs into every entry
     point — ``RuntimeSpec(backend=<name>)`` and ``ops.*(impl=<name>)``
     resolve through here, so no call site changes."""
     if not backend.name:
@@ -761,5 +602,3 @@ def available_backends() -> tuple[str, ...]:
 
 register_backend(PallasBackend())
 register_backend(XLABackend())
-register_backend(MeteredPallasBackend())
-register_backend(PackedPallasBackend())

@@ -257,7 +257,8 @@ def fused_impact_metered(drive: Array, ccur: Array, nonempty: Array,
 # more bytes than the information content.  The packed kernels consume
 # the ``kernels.packing`` layout instead: 2-bit codes, four literal rows
 # per byte, unpacked INSIDE the kernel — the f32 cell-current operand
-# never exists in HBM.  Layouts (prepared by ``ops.fused_impact_packed``):
+# never exists in HBM.  Layouts (prepared by
+# ``PallasBackend._fused_impact_packed_operands``):
 #
 #   drive_p (R, 4, B, tr4)  f32   bitplane-major drive: plane j row q is
 #                                 literal row 4q+j of shard r; pad rows 0
@@ -349,8 +350,8 @@ def fused_impact_packed(drive: Array, pbits: Array, levels: Array,
     f32, nonempty (1, N) int8, wcur (N, M) f32 -> class currents (B, M).
 
     Same alignment contract as ``fused_impact`` with ``tr4`` (the packed
-    row count) in place of ``tr``; ``ops.fused_impact_packed`` pads
-    arbitrary shapes.
+    row count) in place of ``tr``; ``PallasBackend`` pads arbitrary
+    shapes.
     """
     R, B, N, M = _check_packed_shapes(drive, pbits, levels, nonempty, wcur,
                                       block_b, block_n)

@@ -14,13 +14,14 @@ hypothesis-generated inputs.
 (``sharding.crossbar``) when a mesh with a usable ``model`` axis is
 passed — including the asymmetric R-only / S-only plans where the
 non-dividing operand is replicated — falling back to the single-device
-backend otherwise, so callers can pass a mesh unconditionally.
+backend otherwise, so callers can pass a mesh unconditionally.  That
+routing is ``fused_datapath``, which the compiled session calls too.
 """
 from __future__ import annotations
 
 import jax
 
-from . import backends
+from . import backends, packing
 from .backends import pad_axis as _pad_axis  # noqa: F401  (legacy import path)
 
 Array = jax.Array
@@ -67,16 +68,23 @@ def fused_cotm(literals: Array, include: Array, weights: Array,
         block_b=block_b, block_n=block_n)
 
 
-def fused_impact(literals: Array, clause_i: Array, nonempty: Array,
+def fused_impact(literals: Array, clause_i, nonempty: Array,
                  class_i: Array, *, thresh: float, impl: str = "pallas",
                  interpret: bool | None = None, block_b: int = 128,
-                 block_n: int = 256, mesh=None, meter: bool = False):
+                 block_n: int = 256, mesh=None, meter: bool = False,
+                 tr: int | None = None):
     """Fused analog IMPACT inference: literals -> class currents (B, M) f32.
 
     literals (B, K) bool/{0,1}; clause_i (R, C, tr, tc) f32 per-cell clause
     crossbar read currents in the ``IMPACTSystem`` shard layout; nonempty
     (C*tc,) digital mask; class_i (S, sr, M) f32 class crossbar currents.
     ``thresh`` is the CSA decision current (``yflash.I_CSA_THRESHOLD``).
+
+    ``clause_i`` may instead be a ``kernels.packing.PackedClause`` —
+    2-bit codes ``(R, C, ceil(tr/4), tc)`` uint8 plus the ``(2,)``
+    dequant levels — and then ``tr`` is the UNPACKED per-shard row count
+    (the packed bits alone cannot recover it): the backend's packed
+    kernel runs, and the meters bill the quantized currents.
 
     ``meter=True`` additionally returns the per-lane energy meters —
     ``(scores, summed clause-crossbar column currents (B,), summed
@@ -98,63 +106,53 @@ def fused_impact(literals: Array, clause_i: Array, nonempty: Array,
     floating row contributes no current), padded clause columns carry
     nonempty=0, padded class rows carry 0 S conductance.
     """
-    R, C, tr, tc = clause_i.shape
-    S = class_i.shape[0]
+    packed = isinstance(clause_i, packing.PackedClause)
+    R, C, _, tc = (clause_i.bits if packed else clause_i).shape
     assert nonempty.shape == (C * tc,), (nonempty.shape, C * tc)
+    plan = None
     if mesh is not None:
         from ..sharding import crossbar as _crossbar  # lazy: avoids cycle
-        plan = _crossbar.shard_plan(mesh, R, S)
-        if plan is not None:
-            return _crossbar.fused_impact_shmap(
-                literals, clause_i, nonempty, class_i, thresh=thresh,
-                mesh=mesh, impl=impl, interpret=interpret, meter=meter,
-                shard_r=plan[0], shard_s=plan[1])
-    backend = backends.get_backend(impl)
-    if meter:
-        return backend.fused_impact_metered(
-            literals, clause_i, nonempty, class_i, thresh=thresh,
-            interpret=interpret, block_b=block_b, block_n=block_n)
-    return backend.fused_impact(
-        literals, clause_i, nonempty, class_i, thresh=thresh,
-        interpret=interpret, block_b=block_b, block_n=block_n)
+        plan = _crossbar.shard_plan(mesh, R, class_i.shape[0])
+    return fused_datapath(
+        backends.get_backend(impl), literals, clause_i, nonempty, class_i,
+        thresh=thresh, meter=meter, tr=tr, interpret=interpret, mesh=mesh,
+        plan=plan, block_b=block_b, block_n=block_n)
 
 
-def fused_impact_packed(literals: Array, packed, nonempty: Array,
-                        class_i: Array, *, thresh: float, tr: int,
-                        impl: str = "pallas-packed",
-                        interpret: bool | None = None, block_b: int = 128,
-                        block_n: int = 256, mesh=None, meter: bool = False):
-    """``fused_impact`` on a bitplane-packed clause operand.
+def fused_datapath(backend: backends.Backend, literals: Array, clause,
+                   nonempty: Array, class_i: Array, *, thresh: float,
+                   meter: bool, tr: int | None = None,
+                   interpret: bool | None = None, mesh=None, plan=None,
+                   valid: Array | None = None,
+                   lane_cols: Array | None = None, block_b: int = 128,
+                   block_n: int = 256):
+    """The one mesh / backend / meter routing of the fused datapath,
+    shared by ``fused_impact`` and the compiled session.
 
-    ``packed`` is a ``kernels.packing.PackedClause`` — 2-bit codes
-    ``(R, C, ceil(tr/4), tc)`` uint8 plus the ``(2,)`` dequant levels —
-    and ``tr`` is the UNPACKED per-shard row count (the packed bits
-    alone cannot recover it).  Routing mirrors ``fused_impact``: a mesh
-    with a usable ``model`` axis rides the same psum lowering
-    (``sharding.crossbar`` unpacks bitplanes per shard), otherwise the
-    backend's packed kernel runs; ``meter=True`` returns the metered
-    triple billed on the quantized currents.
-    """
-    R, C, tr4, tc = packed.bits.shape
-    S = class_i.shape[0]
-    assert nonempty.shape == (C * tc,), (nonempty.shape, C * tc)
-    if mesh is not None:
+    A ``plan`` (``sharding.crossbar.shard_plan`` on ``mesh``) runs the
+    ``shard_map`` lowering, which also takes the ``valid`` lane mask and
+    the co-residency ``lane_cols``; otherwise the backend's kernel runs,
+    the packed one when ``clause`` is a ``PackedClause`` (``tr`` its
+    unpacked row count).  -> scores, or the metered triple when
+    ``meter``."""
+    packed = isinstance(clause, packing.PackedClause)
+    if plan is not None:
         from ..sharding import crossbar as _crossbar  # lazy: avoids cycle
-        plan = _crossbar.shard_plan(mesh, R, S)
-        if plan is not None:
-            return _crossbar.fused_impact_shmap(
-                literals, None, nonempty, class_i, thresh=thresh,
-                mesh=mesh, impl=impl, interpret=interpret, meter=meter,
-                shard_r=plan[0], shard_s=plan[1], packed=packed,
-                packed_tr=tr)
-    backend = backends.get_backend(impl)
-    if meter:
-        return backend.fused_impact_packed_metered(
-            literals, packed, nonempty, class_i, thresh=thresh, tr=tr,
-            interpret=interpret, block_b=block_b, block_n=block_n)
-    return backend.fused_impact_packed(
-        literals, packed, nonempty, class_i, thresh=thresh, tr=tr,
-        interpret=interpret, block_b=block_b, block_n=block_n)
+        return _crossbar.fused_impact_shmap(
+            literals, None if packed else clause, nonempty, class_i,
+            thresh=thresh, mesh=mesh, impl=backend.name,
+            interpret=interpret, valid=valid, meter=meter,
+            shard_r=plan[0], shard_s=plan[1],
+            packed=clause if packed else None,
+            packed_tr=tr if packed else None, lane_cols=lane_cols)
+    if packed:
+        fn = (backend.fused_impact_packed_metered if meter
+              else backend.fused_impact_packed)
+        return fn(literals, clause, nonempty, class_i, thresh=thresh, tr=tr,
+                  interpret=interpret, block_b=block_b, block_n=block_n)
+    fn = backend.fused_impact_metered if meter else backend.fused_impact
+    return fn(literals, clause, nonempty, class_i, thresh=thresh,
+              interpret=interpret, block_b=block_b, block_n=block_n)
 
 
 def crossbar_mvm(drive: Array, g: Array, *, v_read: float = 2.0,

@@ -148,9 +148,8 @@ def pack_clause_operand(clause_i, *, split=None) -> PackedClause:
 
     Returns :class:`PackedClause` with ``bits`` of shape
     ``(R, C, ceil(tr/4), tc)`` — the row axis packed 4:1 — and the two
-    dequant levels.  Traceable: a ``PackedPallasBackend`` can pack
-    inside jit, and an ``InferenceSession`` packs concretely at compile
-    time.
+    dequant levels.  Traceable: it can pack inside jit, and an
+    ``InferenceSession`` packs concretely at compile time.
     """
     clause_i = jnp.asarray(clause_i, jnp.float32)
     r, c, tr, tc = clause_i.shape

@@ -357,12 +357,43 @@ def test_compile_refuses_over_budget_packed_spec(small_system):
     (``test_vmem_busting_spec_is_flagged``)."""
     with pytest.raises(ValueError, match="packing='2bit'.*over the budget"):
         small_system.compile(RuntimeSpec(
-            backend="pallas-packed", packing="2bit",
+            backend="pallas", packing="2bit",
             vmem_budget_bytes=4096))
-    small_system.compile(RuntimeSpec(backend="pallas-packed",
-                                     packing="2bit"))
+    small_system.compile(RuntimeSpec(backend="pallas", packing="2bit"))
     small_system.compile(RuntimeSpec(backend="pallas",
                                      vmem_budget_bytes=4096))
+
+
+def test_session_route_is_the_kernel_plan(small_system):
+    """The session decides each entry's datapath once and the planner
+    reads that decision: for every audited spec and a co-resident
+    session, each entry's kernel plan names the kernel its route runs."""
+    from repro.impact import build_coresident
+    kernel = {("fused", False): "fused_impact",
+              ("fused", True): "fused_impact_metered",
+              ("packed", False): "fused_impact_packed",
+              ("packed", True): "fused_impact_packed_metered",
+              ("staged", False): "crossbar_mvm",
+              ("staged", True): "crossbar_mvm"}
+    sessions = {tag: small_system.compile(RuntimeSpec(**kw))
+                for tag, kw in _load_check_static().AUDIT_SPECS}
+    combined, plan = build_coresident([small_system, small_system])
+    sessions["coresident"] = combined.compile(RuntimeSpec(
+        backend="pallas", metering="fused", coresident=plan))
+    assert sessions["fused"].routes == {
+        "predict": ("fused", False), "infer_step": ("fused", True),
+        "infer_with_report": ("fused", True)}
+    assert sessions["staged"].routes["infer_step"] == ("staged", True)
+    assert sessions["packed"].routes["predict"] == ("packed", False)
+    assert {r for r, _ in sessions["coresident"].routes.values()} \
+        == {"staged"}
+    for tag, session in sessions.items():
+        for entry, route in session.routes.items():
+            got = session.kernel_plan(entry, 8)
+            if session.backend.reference:
+                assert got is None, (tag, entry)
+            else:
+                assert got.variant == kernel[route], (tag, entry, route)
 
 
 def test_vmem_busting_spec_is_flagged(small_system):

@@ -121,7 +121,7 @@ def test_prune_stacks_with_packing():
     lit, sys_ = _calib_system(seed=4)
     pruned, _ = prune_clauses(sys_, lit)
     np.testing.assert_array_equal(
-        np.asarray(pruned.compile(RuntimeSpec(backend="pallas-packed",
+        np.asarray(pruned.compile(RuntimeSpec(backend="pallas",
                                               packing="2bit"))
                    .predict(lit).predictions),
         np.asarray(sys_.compile(RuntimeSpec(backend="xla"))

@@ -310,7 +310,7 @@ def test_packed_sessions_across_shard_plans(shard):
     valid = np.zeros((B,), bool)
     valid[:6] = True
     single = sys_.compile(RuntimeSpec(
-        backend="pallas-packed", packing="2bit", metering="fused",
+        backend="pallas", packing="2bit", metering="fused",
         capacity=B)).infer_step(buf, valid)
     sess = sys_.compile(RuntimeSpec(
         backend="xla", packing="2bit", metering="fused", capacity=B,
@@ -440,7 +440,7 @@ SMOKE = textwrap.dedent("""
 
     # packed (2-bit) operands ride the same psum lowering: sharded packed
     # session == single-device packed kernel, preds and lane bills alike
-    pk_one = base.compile(RuntimeSpec(backend="pallas-packed",
+    pk_one = base.compile(RuntimeSpec(backend="pallas",
                                       packing="2bit", metering="fused",
                                       capacity=16)).infer_step(buf, vd)
     pk_mesh = base.compile(RuntimeSpec(backend="xla", packing="2bit",

@@ -153,19 +153,21 @@ def test_fused_metered_matches_staged_and_oracle(B, K, n, M, R, tr, C, tc,
 def test_packed_backend_argmax_parity_with_int8(B, K, n, M, R, tr, C, tc,
                                                 S, sr):
     """The compressed-datapath acceptance sweep: ``packing="2bit"``
-    through the ``pallas-packed`` backend agrees on argmax with the int8
+    through the Pallas packed kernel agrees on argmax with the int8
     fused kernel AND the einsum oracle across every shard layout — the
     quantized clause operand preserves all CSA decisions."""
     lit, sys_ = _make_system(B, K, n, M, R, tr, C, tc, S, sr, seed=41)
     preds = {}
     for backend, packing in (("pallas", "none"),
-                             ("pallas-packed", "2bit"),
+                             ("pallas", "2bit"),
                              ("xla", "none")):
         sess = sys_.compile(RuntimeSpec(backend=backend, packing=packing,
                                         metering="off"))
-        preds[backend] = np.asarray(sess.predict(lit).predictions)
-    np.testing.assert_array_equal(preds["pallas-packed"], preds["pallas"])
-    np.testing.assert_array_equal(preds["pallas-packed"], preds["xla"])
+        preds[backend, packing] = np.asarray(sess.predict(lit).predictions)
+    np.testing.assert_array_equal(preds["pallas", "2bit"],
+                                  preds["pallas", "none"])
+    np.testing.assert_array_equal(preds["pallas", "2bit"],
+                                  preds["xla", "none"])
 
 
 def test_packed_session_fused_metering_matches_staged():
@@ -178,7 +180,7 @@ def test_packed_session_fused_metering_matches_staged():
     valid = np.zeros((16,), bool)
     valid[:11] = True
     sessions = {
-        mode: sys_.compile(RuntimeSpec(backend="pallas-packed",
+        mode: sys_.compile(RuntimeSpec(backend="pallas",
                                        packing="2bit", metering=mode,
                                        capacity=16))
         for mode in ("fused", "staged")}
@@ -196,14 +198,15 @@ def test_packed_session_fused_metering_matches_staged():
 
 
 def test_metered_backend_scores_identical_to_unmetered():
-    """The registered ``pallas-metered`` lowering is the SAME datapath
-    with meters riding along: plain fused_impact scores through it are
-    bit-identical to the unmetered kernel."""
+    """The metered kernel is the SAME datapath with meters riding
+    along: its scores are bit-identical to the unmetered kernel's on the
+    same backend."""
     lit, sys_ = _make_system(16, 100, 50, 10, 2, 64, 1, 64, 2, 32, seed=8)
     args = (lit, sys_.clause_i, sys_.nonempty, sys_.class_i)
+    scores, _, _ = ops.fused_impact(*args, thresh=I_CSA_THRESHOLD,
+                                    impl="pallas", meter=True)
     np.testing.assert_array_equal(
-        np.asarray(ops.fused_impact(*args, thresh=I_CSA_THRESHOLD,
-                                    impl="pallas-metered")),
+        np.asarray(scores),
         np.asarray(ops.fused_impact(*args, thresh=I_CSA_THRESHOLD,
                                     impl="pallas")))
 

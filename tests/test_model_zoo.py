@@ -101,7 +101,7 @@ def test_coresident_plan_validates_spans():
 # -- co-resident session parity ----------------------------------------------
 
 @pytest.mark.parametrize("backend,packing", [
-    ("xla", "none"), ("pallas", "none"), ("pallas-packed", "2bit")])
+    ("xla", "none"), ("pallas", "none"), ("pallas", "2bit")])
 def test_coresident_session_matches_standalone(backend, packing):
     systems = member_systems(3)
     combined, plan = build_coresident(systems)

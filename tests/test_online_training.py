@@ -55,9 +55,8 @@ def test_ta_feedback_session_parity_sweep(shape, packing):
     B2, K, n, M, R, tr, C, tc, S, sr = shape
     _, sys_ = _make_system(4, K, n, M, R, tr, C, tc, S, sr, seed=B2)
     ops = _feedback_operands(np.random.default_rng(B2), B2, K, n)
-    backend = "pallas-packed" if packing == "2bit" else "pallas"
     oracle = sys_.compile(RuntimeSpec(backend="xla")).ta_feedback(*ops)
-    kernel = sys_.compile(RuntimeSpec(backend=backend, packing=packing,
+    kernel = sys_.compile(RuntimeSpec(backend="pallas", packing=packing,
                                       interpret=True)).ta_feedback(*ops)
     np.testing.assert_array_equal(np.asarray(kernel), np.asarray(oracle))
     assert kernel.dtype == jnp.int32
@@ -202,7 +201,7 @@ def test_trainer_rejects_packed_and_coresident_sessions():
     from repro.impact import build_coresident
     cfg, (tr_l, tr_y), _ = _prototype_problem()
     params, system, _ = _deployed(cfg, tr_l, tr_y)
-    packed = system.compile(RuntimeSpec(backend="pallas-packed",
+    packed = system.compile(RuntimeSpec(backend="pallas",
                                         packing="2bit", interpret=True))
     with pytest.raises(ValueError, match="unpacked"):
         OnlineTrainer(packed, params, cfg, key=jax.random.key(0))

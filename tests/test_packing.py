@@ -16,7 +16,7 @@ import pytest
 from _hypothesis_compat import given, settings, strategies as st
 
 from repro.impact.yflash import I_CSA_THRESHOLD
-from repro.kernels import backends, ops, packing, ref
+from repro.kernels import ops, packing, ref
 
 from test_fused_impact import SHARD_SHAPES, _make_system
 
@@ -93,8 +93,7 @@ def test_pack_clause_operand_roundtrip(tr):
     """(R, C, tr, tc) operand packs 4:1 on the row axis and dequants back
     to the class-mean currents with codes preserved exactly."""
     lit, sys_ = _make_system(4, 100, 50, 10, 2, tr, 2, 32, 1, 64, seed=5)
-    packed = backends.get_backend("pallas-packed") \
-        .pack_clause_operand(sys_.clause_i)
+    packed = packing.pack_clause_operand(sys_.clause_i)
     R, C, _, tc = sys_.clause_i.shape
     assert packed.bits.shape == (R, C, packing.packed_rows(tr), tc)
     assert packed.bits.dtype == jnp.uint8
@@ -137,8 +136,8 @@ def test_packed_kernel_matches_packed_oracle(B, K, n, M, R, tr, C, tc,
     want = ref.fused_impact_packed_ref(lit, packed.bits, packed.levels,
                                        sys_.nonempty, sys_.class_i,
                                        thresh=I_CSA_THRESHOLD, tr=tr)
-    got = ops.fused_impact_packed(lit, packed, sys_.nonempty, sys_.class_i,
-                                  thresh=I_CSA_THRESHOLD, tr=tr)
+    got = ops.fused_impact(lit, packed, sys_.nonempty, sys_.class_i,
+                           thresh=I_CSA_THRESHOLD, tr=tr)
     np.testing.assert_allclose(np.asarray(got), np.asarray(want), rtol=1e-6)
     np.testing.assert_array_equal(np.asarray(jnp.argmax(got, -1)),
                                   np.asarray(jnp.argmax(want, -1)))
@@ -154,11 +153,10 @@ def test_packed_metered_matches_packed_oracle(B, K, n, M, R, tr, C, tc,
     want = ref.fused_impact_packed_metered_ref(
         lit, packed.bits, packed.levels, sys_.nonempty, sys_.class_i,
         thresh=I_CSA_THRESHOLD, tr=tr)
-    got = ops.fused_impact_packed(lit, packed, sys_.nonempty, sys_.class_i,
-                                  thresh=I_CSA_THRESHOLD, tr=tr, meter=True)
-    plain = ops.fused_impact_packed(lit, packed, sys_.nonempty,
-                                    sys_.class_i, thresh=I_CSA_THRESHOLD,
-                                    tr=tr)
+    got = ops.fused_impact(lit, packed, sys_.nonempty, sys_.class_i,
+                           thresh=I_CSA_THRESHOLD, tr=tr, meter=True)
+    plain = ops.fused_impact(lit, packed, sys_.nonempty, sys_.class_i,
+                             thresh=I_CSA_THRESHOLD, tr=tr)
     np.testing.assert_array_equal(np.asarray(jnp.argmax(got[0], -1)),
                                   np.asarray(jnp.argmax(want[0], -1)))
     np.testing.assert_allclose(np.asarray(got[0]), np.asarray(plain),
@@ -170,17 +168,15 @@ def test_packed_metered_matches_packed_oracle(B, K, n, M, R, tr, C, tc,
 
 
 def test_every_backend_serves_the_packed_operand():
-    """The base-class default (dequant + delegate) makes packing a spec
-    value every registered backend accepts — xla, pallas, and the packed
-    kernel all agree on argmax."""
+    """Packing is a clause-operand type every registered backend accepts:
+    the xla oracle and the pallas packed kernel agree on argmax."""
     B, K, n, M, R, tr, C, tc, S, sr = SHARD_SHAPES[1]
     lit, sys_ = _make_system(B, K, n, M, R, tr, C, tc, S, sr, seed=37)
     packed = packing.pack_clause_operand(sys_.clause_i)
     preds = {}
-    for impl in ("xla", "pallas", "pallas-packed"):
-        scores = ops.fused_impact_packed(
+    for impl in ("xla", "pallas"):
+        scores = ops.fused_impact(
             lit, packed, sys_.nonempty, sys_.class_i,
             thresh=I_CSA_THRESHOLD, tr=tr, impl=impl)
         preds[impl] = np.asarray(jnp.argmax(scores, -1))
-    np.testing.assert_array_equal(preds["pallas-packed"], preds["xla"])
     np.testing.assert_array_equal(preds["pallas"], preds["xla"])

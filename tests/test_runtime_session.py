@@ -52,12 +52,12 @@ def coresident_system(small_system):
 # -- backend registry --------------------------------------------------------
 
 def test_registry_contents_and_errors():
-    assert {"pallas", "xla", "pallas-metered"} \
-        <= set(backends.available_backends())
+    # The spec's metering and packing fields choose the kernel variant,
+    # so the registry holds one kernel lowering and one oracle.
+    assert set(backends.available_backends()) == {"pallas", "xla"}
     assert backends.get_backend("xla").reference
     assert not backends.get_backend("pallas").reference
-    assert not backends.get_backend("pallas-metered").reference
-    assert isinstance(backends.get_backend("pallas-metered"),
+    assert isinstance(backends.get_backend("pallas"),
                       backends.PallasBackend)
     with pytest.raises(ValueError, match="unknown backend"):
         backends.get_backend("mythical")
@@ -158,7 +158,7 @@ def test_packed_session_parity_and_input_bytes(small_system):
     surfaces in repr for debuggability."""
     system, lits = small_system
     base = system.compile(RuntimeSpec(backend="pallas", metering="off"))
-    packed = system.compile(RuntimeSpec(backend="pallas-packed",
+    packed = system.compile(RuntimeSpec(backend="pallas",
                                         packing="2bit", metering="off"))
     np.testing.assert_array_equal(
         np.asarray(packed.predict(lits[:16]).predictions),
@@ -170,26 +170,25 @@ def test_packed_session_parity_and_input_bytes(small_system):
 
 
 def test_packing_is_backend_agnostic(small_system):
-    """packing='2bit' is a spec value, not a pallas-packed privilege: the
-    base-class dequant fallback serves it on every backend, and all
-    backends agree on argmax (they consume the same quantized operand,
-    so scores differ only by float association)."""
+    """packing='2bit' is a spec value every registered backend serves —
+    the Pallas packed kernel and the einsum oracle — and they agree on
+    argmax (they consume the same quantized operand, so scores differ
+    only by float association)."""
     system, lits = small_system
     preds = {
         impl: np.asarray(
             system.compile(RuntimeSpec(backend=impl, packing="2bit",
                                        metering="off"))
             .predict(lits[:16]).predictions)
-        for impl in ("xla", "pallas", "pallas-packed")}
+        for impl in ("xla", "pallas")}
     np.testing.assert_array_equal(preds["xla"], preds["pallas"])
-    np.testing.assert_array_equal(preds["xla"], preds["pallas-packed"])
 
 
 def test_packed_session_metered_report(small_system):
     """Metering on a packed session works end to end and bills positive
     joules (the quantized currents, not zeros)."""
     system, lits = small_system
-    rep = system.compile(RuntimeSpec(backend="pallas-packed",
+    rep = system.compile(RuntimeSpec(backend="pallas",
                                      packing="2bit", metering="fused")) \
         .infer_with_report(lits[:8]).report
     assert rep.read_energy_j > 0

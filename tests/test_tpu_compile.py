@@ -195,7 +195,7 @@ def test_fused_impact_packed_compiles_for_v5e(shape, batch, metered):
     packed = packing.PackedClause(
         bits=shape((1, 1, tr4, TILE_COLS), jnp.uint8),
         levels=shape((2,), jnp.float32))
-    bk = backends.get_backend("pallas-packed")
+    bk = backends.get_backend("pallas")
     ops_ = jax.eval_shape(
         lambda *a: bk._fused_impact_packed_operands(
             *a, tr=TILE_ROWS, block_b=128, block_n=256)[:5],
