@@ -26,7 +26,11 @@ that ``IMPACTSystem.compile(spec)`` resolves ONCE into an immutable
 * what the host needs of a call crosses to it in ONE device->host
   transfer (``InferenceSession.fetch`` for ``infer_step``, the joules
   vector inside ``infer_with_report``), counted by
-  ``session.fetch_count`` as compiles are by ``trace_count``.
+  ``session.fetch_count`` as compiles are by ``trace_count``;
+* ``infer_with_report`` takes its literal rows bit-packed, 32 to a word
+  (``pack_literals_host``), and unpacks them inside the executable, so
+  a host batch crosses host->device in 1/8 of its int8 bytes
+  (``session.packed_uploads``, ``session.literal_upload_bytes``).
 
 The legacy per-call kwargs (``impl=``, ``mesh=``, ``meter=``,
 ``meter_energy=``) keep working through thin shims on ``IMPACTSystem``
@@ -69,6 +73,51 @@ PACKINGS = ("none", "2bit")
 #: bool / int / float {0,1} literals; the session casts ONCE before the
 #: executable so AOT avals never fragment by caller dtype.
 LITERAL_DTYPE = jnp.int8
+
+#: ``infer_with_report`` takes its literal rows bit-packed: bit ``k % 32``
+#: of word ``k // 32`` is literal ``k``, and the bits past the last
+#: literal are 0.  A host batch crosses to the device as these words (8x
+#: fewer bytes than int8 rows) and the executable unpacks them.
+LITERAL_WORD_DTYPE = jnp.uint32
+LITERALS_PER_WORD = 32
+
+
+def literal_words(n_literals: int) -> int:
+    """Words per bit-packed literal row."""
+    return -(-n_literals // LITERALS_PER_WORD)
+
+
+def pack_literals_host(literals) -> np.ndarray:
+    """(B, K) {0,1} literal rows on the host -> (B, ``literal_words(K)``)
+    uint32 words, in one ``np.packbits`` pass (any non-zero is a 1)."""
+    lits = np.asarray(literals)
+    if lits.dtype.kind not in "biu":
+        lits = lits != 0
+    B, K = lits.shape
+    out = np.zeros((B, 4 * literal_words(K)), np.uint8)
+    out[:, :-(-K // 8)] = np.packbits(lits, axis=1, bitorder="little")
+    return out.view("<u4")
+
+
+def pack_literals(literals: Array) -> Array:
+    """``pack_literals_host`` traced, for a batch already on the device."""
+    B, K = literals.shape
+    W = literal_words(K)
+    bits = jnp.pad((literals != 0).astype(LITERAL_WORD_DTYPE),
+                   ((0, 0), (0, W * LITERALS_PER_WORD - K)))
+    shifts = jnp.arange(LITERALS_PER_WORD, dtype=LITERAL_WORD_DTYPE)
+    return jnp.sum(bits.reshape(B, W, LITERALS_PER_WORD) << shifts, axis=2,
+                   dtype=LITERAL_WORD_DTYPE)
+
+
+def unpack_literals(words: Array, n_literals: int) -> Array:
+    """(B, W) bit-packed words -> (B, ``n_literals``) ``LITERAL_DTYPE``
+    rows, traced: the inverse of ``pack_literals`` on {0,1} rows."""
+    B, W = words.shape
+    shifts = jnp.arange(LITERALS_PER_WORD, dtype=LITERAL_WORD_DTYPE)
+    bits = (words[:, :, None] >> shifts) & 1
+    return bits.reshape(B, W * LITERALS_PER_WORD)[:, :n_literals].astype(
+        LITERAL_DTYPE)
 
 
 class SpecDeprecationWarning(DeprecationWarning):
@@ -299,6 +348,11 @@ class InferenceSession:
     ``infer_step`` result's predictions and per-lane joules over as one
     buffer, and ``infer_with_report`` fetches its two joule figures as
     one vector.  ``fetch_count`` counts these transfers.
+
+    Host->device, ``infer_with_report`` ships a host batch as bit-packed
+    words (``packed_uploads`` counts such calls) and packs a batch that
+    is already on the device there; ``literal_upload_bytes`` counts the
+    literal bytes every entry shipped from the host.
     """
 
     def __init__(self, system, spec: RuntimeSpec):
@@ -352,6 +406,11 @@ class InferenceSession:
         self._irs: dict[tuple[str, int], str] = {}
         self._traces: collections.Counter = collections.Counter()
         self._fetches = 0
+        self._packed_uploads = 0
+        self._literal_upload_bytes = 0
+        # Packers of device-resident ``infer_with_report`` batches, one
+        # per (batch, dtype).
+        self._packers: dict[tuple[int, Any], Any] = {}
         # All-valid masks of ``infer_with_report(valid=None)``, made on
         # the device once per batch size.
         self._all_valid: dict[int, Array] = {}
@@ -393,6 +452,19 @@ class InferenceSession:
         ``infer_with_report``: one per call on the results this session
         made."""
         return self._fetches
+
+    @property
+    def packed_uploads(self) -> int:
+        """``infer_with_report`` calls whose host literals crossed to the
+        device bit-packed."""
+        return self._packed_uploads
+
+    @property
+    def literal_upload_bytes(self) -> int:
+        """Bytes of literal operands shipped host->device by every entry:
+        packed words for ``infer_with_report``, int8 rows otherwise.  A
+        batch already on the device ships nothing."""
+        return self._literal_upload_bytes
 
     def compiled_shapes(self, entry: str | None = None) -> list[tuple]:
         return sorted(k for k in self._exes
@@ -540,14 +612,18 @@ class InferenceSession:
         per-shard path under ``"staged"`` (same joules either way).
         ``valid`` (B,) bool marks real lanes in a padded batch; padding
         lanes are excluded from the energy/ops/datapoint accounting and
-        predict the sentinel -1 (same contract as ``infer_step``)."""
+        predict the sentinel -1 (same contract as ``infer_step``).
+
+        The literals reach the executable bit-packed (``literal_words``):
+        a host batch is packed on the host and uploaded as words, a
+        ``jax.Array`` is packed where it lies."""
         if not self.meters_energy:
             raise RuntimeError(
                 "this session was compiled with metering='off' — "
                 "infer_with_report needs RuntimeSpec(metering='fused') "
                 "(single-pass, serving speed) or 'staged' (the oracle)")
-        lits = self._lits(literals)
-        B = lits.shape[0]
+        words = self._words(literals)
+        B = words.shape[0]
         if valid is None:
             v = self._all_valid.get(B)
             if v is None:
@@ -560,9 +636,9 @@ class InferenceSession:
         mids = self._model_ids(model_ids, B)
         exe = self._exe("infer_with_report", B)
         if mids is None:
-            preds, joules = exe(lits, v, *self._operands())
+            preds, joules = exe(words, v, *self._operands())
         else:
-            preds, joules = exe(lits, v, mids, *self._operands())
+            preds, joules = exe(words, v, mids, *self._operands())
         sys_ = self.system
         e_clause, e_class = (float(j) for j in self._to_host(joules))
         ops_xp = n_dp * (sys_.n_literals * sys_.n_clauses
@@ -602,7 +678,34 @@ class InferenceSession:
         return np.asarray(x)
 
     def _lits(self, literals) -> Array:
-        return jnp.asarray(literals, LITERAL_DTYPE)
+        lits = jnp.asarray(literals, LITERAL_DTYPE)
+        if not isinstance(literals, jax.Array):
+            self._literal_upload_bytes += lits.nbytes
+        return lits
+
+    def _words(self, literals) -> Array:
+        """(B, K) literal rows -> the bit-packed (B, W) words of
+        ``infer_with_report``: packed on the host and uploaded, or, for a
+        ``jax.Array``, packed on the device by a packer compiled once per
+        (batch, dtype)."""
+        on_device = isinstance(literals, jax.Array)
+        if not on_device:
+            literals = np.asarray(literals)
+        K = self.system.n_literals
+        if literals.ndim != 2 or literals.shape[1] != K:
+            raise ValueError(f"literal rows of shape {literals.shape} do "
+                             f"not have this system's K={K}")
+        if on_device:
+            key = (literals.shape[0], literals.dtype)
+            packer = self._packers.get(key)
+            if packer is None:
+                packer = self._packers[key] = jax.jit(
+                    self._pack_fn).lower(literals).compile()
+            return packer(literals)
+        words = pack_literals_host(literals)
+        self._packed_uploads += 1
+        self._literal_upload_bytes += words.nbytes
+        return jax.device_put(words)
 
     def _operands(self) -> tuple[Array, ...]:
         """The weight-side executable operands: ``(clause_i, nonempty,
@@ -619,7 +722,12 @@ class InferenceSession:
         arrays per sweep (the HBM-resident operand footprint the sweep
         must stream).  Independent of XLA's ``cost_analysis`` counters —
         this is the layout-level number the packing gate compares."""
-        n = batch * self.system.n_literals * jnp.dtype(LITERAL_DTYPE).itemsize
+        K = self.system.n_literals
+        if entry == "infer_with_report":
+            n = (batch * literal_words(K)
+                 * jnp.dtype(LITERAL_WORD_DTYPE).itemsize)
+        else:
+            n = batch * K * jnp.dtype(LITERAL_DTYPE).itemsize
         if entry != "predict":
             n += batch * jnp.dtype(jnp.bool_).itemsize      # valid mask
         if self.coresident is not None:
@@ -664,7 +772,9 @@ class InferenceSession:
         elif entry == "infer_step":
             lowered = jax.jit(self._infer_step_fn).lower(lit, valid, *args)
         elif entry == "infer_with_report":
-            lowered = jax.jit(self._report_fn).lower(lit, valid, *args)
+            words = jax.ShapeDtypeStruct(
+                (batch, literal_words(sys_.n_literals)), LITERAL_WORD_DTYPE)
+            lowered = jax.jit(self._report_fn).lower(words, valid, *args)
         else:
             raise ValueError(f"unknown entry point {entry!r}")
         # The lowered StableHLO is the artifact the static IR audit
@@ -792,8 +902,15 @@ class InferenceSession:
         host = jnp.stack([preds.astype(jnp.float32), e_cl, e_cs])
         return preds, e_cl, e_cs, host
 
-    def _report_fn(self, literals, valid, *args):
+    def _pack_fn(self, literals):
+        self._traces["pack_literals"] += 1
+        return pack_literals(literals)
+
+    def _report_fn(self, words, valid, *args):
+        """The metered batch from bit-packed literal words, unpacked
+        here, ahead of the route, into the int8 rows every route reads."""
         self._traces["infer_with_report"] += 1
+        literals = unpack_literals(words, self.system.n_literals)
         preds, i_cl, i_cs = self._report_sums(literals, valid, *args)
         # E = V_R * I * t_read, the same float32 products in the same
         # order as the per-lane meters.  XLA folds V_READ * T_READ into

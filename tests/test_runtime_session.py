@@ -13,6 +13,7 @@ import pytest
 from repro.impact import (IMPACTConfig, InferenceResult, InferenceSession,
                           RuntimeSpec, SpecDeprecationWarning, Topology,
                           build_coresident, build_system)
+from repro.impact import runtime as rt
 from repro.impact.yflash import T_READ, V_READ
 from repro.core import CoTMConfig
 from repro.core.cotm import CoTMParams
@@ -414,6 +415,164 @@ def test_report_without_valid_uploads_no_mask(small_system):
                            match="Disallowed host-to-device"):
             sess.infer_with_report(rows, valid=np.ones((8,), bool))
     assert again == first and again.datapoints == 8
+
+
+# -- bit-packed literal upload -----------------------------------------------
+
+@pytest.fixture(scope="module")
+def r5_system():
+    """K=597 literals (not a multiple of 8) on 128x128 tiles: R=5 literal
+    row-shards, as the R=5 grids of test_fused_impact's SHARD_SHAPES."""
+    K, n, m, n_states = 597, 300, 2, 64
+    cfg = CoTMConfig(n_literals=K, n_clauses=n, n_classes=m,
+                     n_states=n_states)
+    rng = np.random.default_rng(5)
+    ta = np.where(rng.random((K, n)) < 0.01, n_states + 1, n_states)
+    w = rng.integers(-20, 20, (m, n))
+    params = CoTMParams(ta_state=jnp.asarray(ta, jnp.int32),
+                        weights=jnp.asarray(w, jnp.int32))
+    system = build_system(params, cfg, jax.random.key(5), IMPACTConfig(
+        max_tile_rows=128, max_tile_cols=128, max_class_rows=128,
+        variability=False, finetune=False))
+    assert system.clause_i.shape[0] == 5
+    return system, rng.random((512, K)) < 0.5
+
+
+@pytest.mark.parametrize("K", [1, 7, 8, 31, 32, 33, 64, 597])
+def test_literal_packing_round_trip(K):
+    """Bit k % 32 of word k // 32 is literal k, the bits past K are 0, the
+    host and device packers agree, and the traced unpack gives back the
+    int8 rows, whatever {0,1} dtype the rows came in."""
+    rng = np.random.default_rng(K)
+    rows = rng.random((6, K)) < 0.5
+    rows[0] = True
+    words = rt.pack_literals_host(rows)
+    assert words.dtype == np.uint32
+    assert words.shape == (6, rt.literal_words(K)) == (6, -(-K // 32))
+    k = np.arange(K)
+    np.testing.assert_array_equal((words[:, k // 32] >> (k % 32)) & 1, rows)
+    # An all-ones row sets exactly K bits: the padding bits are 0.
+    assert sum(int(w).bit_count() for w in words[0]) == K
+    for dt in (np.int8, np.int32, np.float32):
+        np.testing.assert_array_equal(
+            rt.pack_literals_host(rows.astype(dt)), words)
+        np.testing.assert_array_equal(
+            np.asarray(jax.jit(rt.pack_literals)(jnp.asarray(rows, dt))),
+            words)
+    back = jax.jit(rt.unpack_literals, static_argnums=1)(
+        jnp.asarray(words), K)
+    assert back.dtype == jnp.int8
+    np.testing.assert_array_equal(np.asarray(back), rows.astype(np.int8))
+
+
+def _parity_session(small_system, r5_system, coresident_system, grid,
+                    route):
+    """(session, (8, K) bool rows, model_ids kwargs) for one parity case."""
+    spec = RuntimeSpec(backend="pallas",
+                       metering="staged" if route == "staged" else "fused",
+                       packing="2bit" if route == "2bit" else "none")
+    if route == "coresident":
+        combined, plan, buf, mids = coresident_system
+        sess = combined.compile(RuntimeSpec(backend="pallas",
+                                            coresident=plan))
+        return sess, buf.astype(bool), dict(model_ids=mids)
+    system, lits = small_system if grid == "k64" else r5_system
+    return system.compile(spec), np.asarray(lits[:8], bool), {}
+
+
+PARITY = pytest.mark.parametrize(
+    "grid,route,dtype",
+    [(g, r, d) for g, routes in (("k64", ("fused", "staged", "2bit",
+                                          "coresident")),
+                                 ("k597", ("fused", "staged", "2bit")))
+     for r in routes for d in ("bool", "int8", "float32", "device")])
+
+
+@PARITY
+def test_packed_report_matches_the_int8_path(
+        small_system, r5_system, coresident_system, grid, route, dtype):
+    """``infer_with_report`` on bit-packed words gives the predictions and
+    both joule figures of the same executable body fed int8 rows, bit for
+    bit: host rows as bool, int8 or float32, and a device array, with
+    padding lanes in the batch, on every route."""
+    sess, rows, kw = _parity_session(small_system, r5_system,
+                                     coresident_system, grid, route)
+    assert sess.routes["infer_with_report"][0] == (
+        "staged" if route in ("staged", "coresident")
+        else "packed" if route == "2bit" else "fused")
+    valid = np.array([1, 1, 0, 1, 1, 0, 1, 0], bool)
+    mids = ((jnp.asarray(kw["model_ids"]),) if kw else ())
+    preds, i_cl, i_cs = jax.jit(sess._report_sums)(
+        jnp.asarray(rows, jnp.int8), jnp.asarray(valid), *mids,
+        *sess._operands())
+    literals = (jnp.asarray(rows) if dtype == "device"
+                else rows.astype(dtype))
+    uploads = sess.packed_uploads
+    rep = sess.infer_with_report(literals, valid, **kw)
+    assert sess.packed_uploads == uploads + (dtype != "device")
+    np.testing.assert_array_equal(np.asarray(rep.predictions),
+                                  np.asarray(preds))
+    assert (np.asarray(rep.predictions)[~valid] == -1).all()
+    assert rep.report.clause_energy_j == float(V_READ * i_cl * T_READ)
+    assert rep.report.class_energy_j == float(V_READ * i_cs * T_READ)
+    assert rep.report.clause_energy_j > 0 and rep.report.datapoints == 5
+
+
+@pytest.mark.parametrize("grid", ["k64", "k597"])
+def test_literal_upload_counters(small_system, r5_system, grid):
+    """One host (512, K) report call uploads ceil(K/32) words a row and
+    counts one packed upload; ``input_bytes`` prices the same words.  A
+    device batch uploads nothing, through either entry, and a host batch
+    through ``infer_step`` ships its int8 rows."""
+    system, lits = small_system if grid == "k64" else r5_system
+    lits = np.resize(lits, (512, system.n_literals))
+    K = system.n_literals
+    sess = system.compile(RuntimeSpec(backend="xla", metering="fused",
+                                      capacity=512))
+    uploads, shipped = sess.packed_uploads, sess.literal_upload_bytes
+    sess.infer_with_report(lits)
+    assert sess.packed_uploads == uploads + 1
+    words = 512 * -(-K // 32) * 4
+    assert sess.literal_upload_bytes == shipped + words
+    operands = sum(op.size * op.dtype.itemsize for op in sess._operands())
+    assert sess.input_bytes("infer_with_report", 512) == (
+        words + 512 + operands)
+    assert sess.input_bytes("infer_step", 512) == 512 * K + 512 + operands
+
+    on_device = jnp.asarray(lits, jnp.int8)
+    valid = np.ones((512,), bool)
+    uploads, shipped = sess.packed_uploads, sess.literal_upload_bytes
+    sess.infer_step(on_device, valid)
+    sess.infer_with_report(on_device)
+    assert (sess.packed_uploads, sess.literal_upload_bytes) == (
+        uploads, shipped)
+    sess.infer_step(lits, valid)
+    assert sess.packed_uploads == uploads
+    assert sess.literal_upload_bytes == shipped + 512 * K
+
+
+def test_device_batches_pack_once_per_shape(small_system):
+    """A device batch is packed where it lies by a packer compiled once
+    per (batch, dtype): the report executable keeps its one (entry,
+    batch) key, and a repeat call compiles nothing."""
+    system, lits = small_system
+    sess = system.compile(RuntimeSpec(backend="xla", metering="fused",
+                                      batch_sizes=(3,)))
+    rows = jnp.asarray(lits[:8])
+    sess.infer_with_report(rows)
+    traces = sess.trace_count
+    again = sess.infer_with_report(rows)
+    assert sess.trace_count == traces
+    assert sess.compiled_shapes("infer_with_report") == [
+        ("infer_with_report", 8)]
+    host = sess.infer_with_report(np.asarray(lits[:8]))
+    assert sess.trace_count == traces
+    np.testing.assert_array_equal(np.asarray(again.predictions),
+                                  np.asarray(host.predictions))
+    assert again.report == host.report
+    for bad in (lits[:8, :-1], jnp.asarray(lits[:8, :-1]), lits[0]):
+        with pytest.raises(ValueError, match="K=64"):
+            sess.infer_with_report(bad)
 
 
 # -- deprecation shims: old kwargs forward, warn, and agree exactly ----------

@@ -165,6 +165,53 @@ def test_fused_impact_compiles_for_v5e_at_imdb_widths(shape, batch,
     assert ws.total_bytes <= vmem.DEFAULT_VMEM_BUDGET_BYTES, ws
 
 
+def test_packed_report_compiles_for_v5e_at_imdb_widths(shape):
+    """The session's 512-row ``infer_with_report`` executable on the text
+    CoTM's grid takes the literals as (512, 313) uint32 words, and every
+    value between that parameter and the kernel's drive operand (the
+    unpacked int8 rows among them) stays in VMEM, memory space S(1): the
+    unpack adds no (512, 10000) buffer in HBM."""
+    from repro.impact import IMPACTConfig, RuntimeSpec
+    from repro.impact.pipeline import IMPACTSystem
+
+    grid = shape((IMDB_R, IMDB_C, TILE_ROWS, TILE_COLS), jnp.float32)
+    classes = shape((IMDB_S, CLASS_ROWS, IMDB_M), jnp.float32)
+    system = IMPACTSystem(
+        clause_g=grid, nonempty=shape((IMDB_C * TILE_COLS,), jnp.bool_),
+        class_g=classes, clause_i=grid, class_i=classes,
+        n_literals=IMDB_K, n_clauses=IMDB_N, n_classes=IMDB_M,
+        cfg=IMPACTConfig(variability=False, finetune=False),
+        encode_stats={})
+    session = system.compile(RuntimeSpec(backend="pallas", metering="fused",
+                                         interpret=False))
+    text = session._exe("infer_with_report", 512).as_text()
+    assert "%fused_impact_metered." in text
+    entry = text[text.index("\nENTRY "):]
+    (words,) = re.findall(r"(%\S+) = u32\[512,313\]\{[^}]*\} parameter\(0\)",
+                          entry)
+    assert not re.search(r"s8\[512,10000\]\{[^}]*\} parameter\(", entry)
+    lines = [ln.split(" = ", 1) for ln in entry.splitlines() if " = " in ln]
+
+    def readers(name):
+        use = re.compile(re.escape(name) + r"[,)]")
+        return [(lhs.split()[-1], rhs) for lhs, rhs in lines
+                if use.search(rhs)]
+
+    drives, todo = 0, [(words, r) for r in readers(words)]
+    while todo:
+        src, (name, rhs) = todo.pop()
+        if 'custom_call_target="tpu_custom_call"' in rhs:
+            assert f"custom-call({src}," in rhs, (
+                f"the words reach the kernel other than as its drive: "
+                f"{rhs[:160]}")
+            drives += 1
+            continue
+        assert "S(1)}" in rhs.split(" ", 1)[0], (
+            f"the literals pass through HBM before the kernel: {rhs[:160]}")
+        todo += [(name, r) for r in readers(name)]
+    assert drives == 1, "the words never reach the kernel's drive operand"
+
+
 # Tiles whose row count is not a multiple of 128: the README's R=2/S=2
 # split (784-row tiles) served on one chip, and 152x256 tiles.  The
 # blocks then span the tile's full height, which Mosaic takes.
